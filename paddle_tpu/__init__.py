@@ -10,10 +10,6 @@ ZeRO/EP + SPMD auto-parallel) expressed as shardings over a
 
 from __future__ import annotations
 
-from .core import jax_compat as _jax_compat
-
-_jax_compat.install()  # before anything touches jax.shard_map/set_mesh
-
 from .core import (
     OP_REGISTRY,
     Parameter,

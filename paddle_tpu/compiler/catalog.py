@@ -3,8 +3,8 @@
 Each template is ``(name, matcher)``; a matcher inspects one equation
 of a :class:`~.fusion_pass.Graph` (the anchor — a primitive that only
 occurs inside its chain: ``rsqrt`` for the norms, ``tanh`` for
-approximate gelu, ``pjit[silu]`` for swiglu, the flash
-``custom_vjp_call_jaxpr`` for rope+attention) and walks
+approximate gelu, ``jit[silu]`` for swiglu, the flash
+``custom_vjp_call`` for rope+attention) and walks
 producers/consumers to the full chain.  It returns a list of candidate
 :class:`~.fusion_pass.Site` objects in preference order (e.g. the
 residual+norm epilogue first, norm-only as fallback) or None; the pass
@@ -35,7 +35,7 @@ import re
 
 import jax
 import jax.numpy as jnp
-from jax import core as jcore
+from jax.extend.core import Var
 
 from .fusion_pass import Graph, Site, lit_scalar, source_hash_mod
 
@@ -113,7 +113,7 @@ def _norm_tail(g: Graph, y1_var, x_dtype, want_beta: bool, cons: set):
         other = eqn.invars[0] if eqn.invars[1] is cur else eqn.invars[1]
         root, peeled = g.peel(other)
         av = _aval(root)
-        if (isinstance(root, jcore.Var) and av is not None
+        if (isinstance(root, Var) and av is not None
                 and av.shape == (h,)):
             return root, peeled
         return None, None
@@ -161,7 +161,7 @@ def _residual_candidates(g: Graph, x_atom, with_bias: bool):
         for inner_at, b_at in (xeqn.invars, xeqn.invars[::-1]):
             b_root, peeled = g.peel(b_at)
             bav = _aval(b_root)
-            if (not isinstance(b_root, jcore.Var) or bav is None
+            if (not isinstance(b_root, Var) or bav is None
                     or bav.shape != (av.shape[-1],)):
                 continue
             ii, ieqn = g.producer(inner_at)
@@ -221,12 +221,12 @@ def _norm_sites(g: Graph, i, eqn, norm: str):
         cons.update(p2)
         cons.update((di, ri, mi))
     else:
-        # stat = var(x32, -1, keepdims): jnp.var traces as pjit[_var]
+        # stat = var(x32, -1, keepdims): jnp.var traces as jit[_var]
         # applied to (x32, ddof-literal); any ddof other than 0 is a
         # different statistic and must not match
         root, peeled = g.peel(stat_at)
         vi, veqn = g.producer(root)
-        if (veqn is None or veqn.primitive.name != "pjit"
+        if (veqn is None or veqn.primitive.name != "jit"
                 or veqn.params.get("name") != "_var"
                 or not veqn.invars
                 or any(lit_scalar(a) != 0.0 for a in veqn.invars[1:])):
@@ -334,10 +334,10 @@ def _strip_addrs(s: str) -> str:
 
 
 def _flash_probe_str(avals) -> str:
-    """Printed fun_jaxpr of ``flash_attention_raw(q, k, v, causal=True)``
+    """Printed call_jaxpr of ``flash_attention_raw(q, k, v, causal=True)``
     at the given avals (addresses stripped), '' when the geometry is
     unsupported.  A candidate custom_vjp equation is flash — with the
-    same causal mask and default scale baked in — iff its fun_jaxpr
+    same causal mask and default scale baked in — iff its call_jaxpr
     prints identically; any other custom_vjp (fused_ce, quant matmuls,
     a non-causal flash) differs structurally."""
     key = tuple((tuple(a.shape), str(a.dtype)) for a in avals)
@@ -352,8 +352,8 @@ def _flash_probe_str(avals) -> str:
                 lambda q, k, v: flash_attention_raw(q, k, v, causal=True))(
                 *[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in avals])
             for e in jx.jaxpr.eqns:
-                if e.primitive.name == "custom_vjp_call_jaxpr":
-                    out = _strip_addrs(str(e.params["fun_jaxpr"]))
+                if e.primitive.name == "custom_vjp_call":
+                    out = _strip_addrs(str(e.params["call_jaxpr"]))
                     break
         except Exception:  # noqa: BLE001 -- unprobeable: just no match
             out = ""
@@ -363,7 +363,7 @@ def _flash_probe_str(avals) -> str:
 
 def _is_flash_eqn(eqn):
     """(q, k, v) atoms when the equation is the flash custom_vjp."""
-    if eqn.primitive.name != "custom_vjp_call_jaxpr":
+    if eqn.primitive.name != "custom_vjp_call":
         return None
     ncon = eqn.params.get("num_consts", 0)
     prim_in = list(eqn.invars[ncon:])
@@ -373,19 +373,29 @@ def _is_flash_eqn(eqn):
     if any(av.ndim != 4 for av in avals):
         return None
     probe = _flash_probe_str(avals)
-    if not probe or _strip_addrs(str(eqn.params["fun_jaxpr"])) != probe:
+    if not probe or _strip_addrs(str(eqn.params["call_jaxpr"])) != probe:
         return None
     return prim_in
 
 
 def _half_slice(g: Graph, atom, lo: bool):
-    """The producing ``slice`` eqn splitting the last axis at d/2."""
+    """The equation producing ``atom`` as the lower (``lo``) or upper
+    half of the last axis: one output of ``jnp.split(x, 2, -1)``'s
+    ``split``, or an explicit ``slice`` at d/2."""
     i, eqn = g.producer(atom)
-    if eqn is None or eqn.primitive.name != "slice":
+    if eqn is None:
         return None
     src = eqn.invars[0]
     shape = src.aval.shape
     d = shape[-1]
+    if eqn.primitive.name == "split":
+        if (eqn.params["axis"] != len(shape) - 1
+                or tuple(eqn.params["sizes"]) != (d // 2, d - d // 2)
+                or atom is not eqn.outvars[0 if lo else 1]):
+            return None
+        return i, src
+    if eqn.primitive.name != "slice":
+        return None
     start = tuple(eqn.params["start_indices"])
     limit = tuple(eqn.params["limit_indices"])
     strides = eqn.params.get("strides")
@@ -419,7 +429,7 @@ def _table_mul(g: Graph, atom, cons: set):
             si, src = hs
             root, _peeled = g.peel(tab_at)
             av = _aval(root)
-            if (not isinstance(root, jcore.Var) or av is None
+            if (not isinstance(root, Var) or av is None
                     or av.dtype != jnp.float32):
                 continue
             cons.update((mi, si))
@@ -494,8 +504,8 @@ def match_rope_attention(g: Graph, i, eqn):
     if prim_in is None:
         return None
     q_at, k_at, v_at = prim_in
-    qc = _rope_chain(g, q_at) if isinstance(q_at, jcore.Var) else None
-    kc = _rope_chain(g, k_at) if isinstance(k_at, jcore.Var) else None
+    qc = _rope_chain(g, q_at) if isinstance(q_at, Var) else None
+    kc = _rope_chain(g, k_at) if isinstance(k_at, Var) else None
     if qc is not None and kc is not None and (
             qc["cos_root"] is not kc["cos_root"]
             or qc["sin_root"] is not kc["sin_root"]):
@@ -609,7 +619,7 @@ def match_bias_gelu(g: Graph, i, eqn):
         b_root, peeled = g.peel(b_at)
         bav = _aval(b_root)
         hav = _aval(h_at)
-        if (isinstance(b_root, jcore.Var) and bav is not None
+        if (isinstance(b_root, Var) and bav is not None
                 and bav.shape == (x_av.shape[-1],)
                 and hav is not None and hav.shape == x_av.shape
                 and hav.dtype == x_av.dtype):
@@ -641,7 +651,7 @@ def match_bias_gelu(g: Graph, i, eqn):
 # ---------------------------------------------------------------------------
 
 def match_swiglu(g: Graph, i, eqn):
-    if (eqn.primitive.name != "pjit" or eqn.params.get("name") != "silu"
+    if (eqn.primitive.name != "jit" or eqn.params.get("name") != "silu"
             or len(eqn.invars) != 1 or len(eqn.outvars) != 1):
         return None
     body = eqn.params["jaxpr"].jaxpr

@@ -2,7 +2,7 @@
 
 The mini-CINN core (ROADMAP item 3).  ``plan_closed`` walks a traced
 program's jaxpr — recursing through scan bodies, remat wrappers, and
-annotation-free pjit calls — and asks every catalog template
+annotation-free nested jit calls — and asks every catalog template
 (catalog.py) whether it recognizes a fusable chain anchored at each
 equation.  Matches become :class:`Site` records: the set of equations
 the fused kernel replaces, the jaxpr variables it must bind, and a
@@ -15,14 +15,14 @@ matcher bug can therefore cost a fusion opportunity, never correctness.
 
 ``eval_fused`` re-traces the program from the planned jaxpr: unmatched
 equations re-bind through ``primitive.get_bind_params`` (the
-eval_jaxpr idiom — custom_vjp calls, pjit, sharding constraints all
+eval_jaxpr idiom — custom_vjp calls, nested jit, sharding constraints all
 pass through untouched, so gradients and partitioning survive), matched
 chains are skipped, and each site's trigger equation emits the fused
 kernel call instead.  Because this happens *inside* the enclosing
 trace, the surrounding jit simply sees a jaxpr with fused calls — grad,
 vmap and sharding compose as if the model had been hand-wired.
 
-Scan/remat/pjit equations whose bodies contain matches are re-wrapped
+Scan/remat/jit equations whose bodies contain matches are re-wrapped
 (``lax.scan`` / ``jax.checkpoint`` with the original static params /
 inlined) around a fused evaluation of their body jaxpr; bodies with no
 matches re-bind untouched.
@@ -36,8 +36,9 @@ import re
 from typing import Any, Callable, Sequence
 
 import jax
-from jax import core as jcore
 from jax import lax
+from jax._src.core import DropVar
+from jax.extend.core import Literal, Var
 
 _TRANSPARENT = ("broadcast_in_dim", "reshape", "convert_element_type")
 
@@ -58,14 +59,14 @@ class Graph:
             for v in eqn.outvars:
                 self.defs[v] = i
             for a in eqn.invars:
-                if isinstance(a, jcore.Var):
+                if isinstance(a, Var):
                     self.uses.setdefault(a, []).append(i)
-        self.outvars = {v for v in jaxpr.outvars if isinstance(v, jcore.Var)}
+        self.outvars = {v for v in jaxpr.outvars if isinstance(v, Var)}
 
     def producer(self, atom):
         """(eqn_index, eqn) defining ``atom``, or (None, None) for
         invars/constvars/literals."""
-        if isinstance(atom, jcore.Var) and atom in self.defs:
+        if isinstance(atom, Var) and atom in self.defs:
             i = self.defs[atom]
             return i, self.jaxpr.eqns[i]
         return None, None
@@ -113,7 +114,7 @@ class Graph:
 
 def lit_scalar(atom):
     """Python float of a scalar (or size-1) literal atom, else None."""
-    if isinstance(atom, jcore.Literal):
+    if isinstance(atom, Literal):
         try:
             return float(atom.val)
         except (TypeError, ValueError):
@@ -240,7 +241,7 @@ def _validate(g: Graph, site: Site) -> bool:
         if i < 0 or i >= len(g.jaxpr.eqns):
             return False
         for v in g.jaxpr.eqns[i].outvars:
-            if isinstance(v, jcore.DropVar):
+            if isinstance(v, DropVar):
                 continue
             produced.add(v)
             if v in bound:
@@ -256,7 +257,7 @@ def _validate(g: Graph, site: Site) -> bool:
         return False
     # inputs must come from outside the replaced region
     for a in site.inputs:
-        if isinstance(a, jcore.Var) and g.defs.get(a) in cons:
+        if isinstance(a, Var) and g.defs.get(a) in cons:
             return False
     return True
 
@@ -267,8 +268,8 @@ def _validate(g: Graph, site: Site) -> bool:
 
 def _sub_jaxpr(eqn):
     """(open_jaxpr, consts) of a rebuildable higher-order eqn, else
-    (None, None).  pjit only when every sharding is unspecified —
-    inlining an annotated pjit would drop its partitioning."""
+    (None, None).  A nested jit only when every sharding is unspecified —
+    inlining an annotated jit would drop its partitioning."""
     name = eqn.primitive.name
     p = eqn.params
     if name == "scan":
@@ -276,7 +277,7 @@ def _sub_jaxpr(eqn):
         return closed.jaxpr, closed.consts
     if name == "remat2":
         return p["jaxpr"], []
-    if name == "pjit":
+    if name == "jit":
         shardings = list(p.get("in_shardings", ())) + \
             list(p.get("out_shardings", ()))
         if all(type(s).__name__ == "UnspecifiedValue" for s in shardings):
@@ -346,10 +347,10 @@ def _eval(jaxpr, consts, plan: Plan, args: list):
     env: dict[Any, Any] = {}
 
     def read(a):
-        return a.val if isinstance(a, jcore.Literal) else env[a]
+        return a.val if isinstance(a, Literal) else env[a]
 
     def write(v, val):
-        if not isinstance(v, jcore.DropVar):
+        if not isinstance(v, DropVar):
             env[v] = val
 
     for v, c in zip(jaxpr.constvars, consts):
@@ -417,7 +418,7 @@ def _eval_higher_order(eqn, invals, sub_plan: Plan):
 
         return jax.checkpoint(run, policy=p.get("policy"),
                               prevent_cse=p.get("prevent_cse", True))(*invals)
-    if name == "pjit":
+    if name == "jit":
         closed = p["jaxpr"]
         return _eval(closed.jaxpr, closed.consts, sub_plan, invals)
     raise NotImplementedError(f"fusion rewrite inside '{name}'")

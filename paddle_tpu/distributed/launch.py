@@ -56,6 +56,15 @@ def _free_port() -> int:
     return port
 
 
+def _host_has_tpu() -> bool:
+    """TPU chips attached to this host, by their device nodes — probed
+    without importing jax, because the launcher must never take the
+    chips its ranks need (a chip belongs to one process at a time)."""
+    import glob
+
+    return bool(glob.glob("/dev/accel[0-9]*") or glob.glob("/dev/vfio/[0-9]*"))
+
+
 def _pump(stream, sink, prefix: str):
     for line in iter(stream.readline, b""):
         sink.write(f"{prefix}{line.decode(errors='replace')}")
@@ -79,6 +88,16 @@ def launch_procs(script: str, script_args, nprocs: int,
     the first non-zero exit code, 0 if all succeeded."""
     if nnodes > 1 and not master:
         raise ValueError("multi-node launch requires an explicit --master")
+    rank_env = {**os.environ, **(env_extra or {})}
+    if (nprocs > 1 and rank_env.get("JAX_PLATFORMS") != "cpu"
+            and _host_has_tpu()):
+        raise RuntimeError(
+            f"launch_procs(nprocs={nprocs}) on a TPU host: one process "
+            f"drives all local chips, and local ranks carry no chip "
+            f"assignment, so every rank would claim every chip and all "
+            f"but the first would fail or hang. Use nprocs=1 (multi-host: "
+            f"one process per host), or JAX_PLATFORMS=cpu for a CPU "
+            f"rehearsal.")
     master = master or f"127.0.0.1:{_free_port()}"
     world = nnodes * nprocs
     procs, pumps, logs = [], [], []

@@ -35,6 +35,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.sharding import PartitionSpec as P
 
 __all__ = ["GPTConfig", "init_params", "model_apply", "loss_fn", "GPT",
            "gpt_presets"]
@@ -163,6 +164,42 @@ def _layer_norm(x, g, b, eps):
     return (y * g.astype(jnp.float32) + b.astype(jnp.float32)).astype(x.dtype)
 
 
+def _per_shard(kernel, args, in_specs, out_spec, out_shape):
+    """Call a Pallas attention entry once per (dp, mp) shard of the
+    ambient mesh: batch over "dp", heads over "mp".
+
+    A Mosaic kernel has no GSPMD partitioning rule: bare inside a jit
+    partitioned over several devices, lowering refuses it ("wrap the
+    call in a shard_map"), and interpret mode hides that — there the
+    kernel is plain HLO.  The shard_map makes EVERY remaining mesh axis
+    manual (Mosaic also refuses a partially-auto region, size-1 axes
+    included); axes already manual (the pipeline region's "pp") are
+    skipped.  Specs name "dp"/"mp" only and follow the repo's placement
+    rule (distributed/placement.py): a dim an axis does not divide stays
+    replicated over it, as it is in the surrounding program.  On a
+    one-device mesh, or with no mesh, the kernel is called directly."""
+    from ..distributed.placement import sanitize_spec
+
+    am = jax.sharding.get_abstract_mesh()
+    if am.empty or am.size == 1:
+        return kernel(*args)
+    auto = set(am.axis_names) - set(am.manual_axes)
+
+    def local(spec, shape):
+        # (sanitize_spec leaves each entry a single axis name or None)
+        return P(*(a if a in auto else None
+                   for a in sanitize_spec(spec, shape, am)))
+
+    return jax.shard_map(
+        kernel, in_specs=tuple(local(s, a.shape)
+                               for s, a in zip(in_specs, args)),
+        out_specs=local(out_spec, out_shape), axis_names=auto,
+        check_vma=False)(*args)
+
+
+_BTHD = P("dp", None, "mp", None)      # [B, T, nH, dH]
+
+
 def _attention(q, k, v, cfg: GPTConfig):
     # q,k,v: [B, T, nH, dH]
     if cfg.ring_axis:
@@ -180,7 +217,9 @@ def _attention(q, k, v, cfg: GPTConfig):
         # flash_attention takes [B, T, nH, dH] (it handles the head-major
         # transpose internally, ops/pallas/flash_attention.py:_flash_fwd)
         if supported(q.shape, q.dtype):
-            return flash_attention_raw(q, k, v, causal=True)
+            return _per_shard(
+                functools.partial(flash_attention_raw, causal=True),
+                (q, k, v), (_BTHD,) * 3, _BTHD, q.shape)
     # XLA fallback: fp32 logits, causal mask
     scale = 1.0 / math.sqrt(cfg.head_dim)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k,
@@ -190,6 +229,25 @@ def _attention(q, k, v, cfg: GPTConfig):
     logits = jnp.where(mask, logits, -1e30)
     probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
+
+
+def _flash_qkv(qkv, cfg: GPTConfig):
+    """The fused-qkv flash entry on [B, T, 3H] -> [B, T, nH, dH].  Per
+    shard the kernel sees the same [q | k | v] lane layout with nH/mp
+    heads: the free [B, T, 3, nH, dH] view shards over its head axis."""
+    from ..ops.pallas.flash_attention import flash_attention_qkv_raw
+
+    B, T, _ = qkv.shape
+    nH, dH = cfg.n_heads, cfg.head_dim
+
+    def kernel(x):                       # [b, T, 3, nH_local, dH]
+        b, t, _, nh, _ = x.shape
+        return flash_attention_qkv_raw(x.reshape(b, t, 3 * nh * dH), nh,
+                                       causal=True)
+
+    return _per_shard(kernel, (qkv.reshape(B, T, 3, nH, dH),),
+                      (P("dp", None, None, "mp", None),), _BTHD,
+                      (B, T, nH, dH))
 
 
 def block_apply(bp: dict, x, cfg: GPTConfig, sp_constraint=None):
@@ -207,14 +265,12 @@ def block_apply(bp: dict, x, cfg: GPTConfig, sp_constraint=None):
     qkv = qkv + bp["qkv_b"].astype(cfg.dtype)
     o = None
     if cfg.use_flash and not cfg.ring_axis:
-        from ..ops.pallas.flash_attention import (flash_attention_qkv_raw,
-                                                 flash_qkv_supported)
+        from ..ops.pallas.flash_attention import flash_qkv_supported
 
         if flash_qkv_supported(qkv.shape, cfg.n_heads, qkv.dtype):
             # fused entry: kernels read q/k/v from the projection output
             # through lane-offset views — no 3-way split copies
-            o = flash_attention_qkv_raw(qkv, cfg.n_heads,
-                                        causal=True).reshape(B, T, H)
+            o = _flash_qkv(qkv, cfg).reshape(B, T, H)
     if o is None:
         q, k, v = jnp.split(qkv, 3, axis=-1)
         q = q.reshape(B, T, cfg.n_heads, cfg.head_dim)
@@ -428,25 +484,22 @@ def loss_fn(params, tokens, labels, cfg: GPTConfig, sp_constraint=None,
     replaces the chunked scan: profiling showed the scan spending
     ~44 ms/step at 350m/b8 materializing fp32 logit chunks — the fused
     kernel streams vocab tiles through VMEM instead (the reference's
-    c_softmax_with_cross_entropy kernel role). Single-program path only:
-    under mp-sharding GSPMD handles the chunked expression better, so the
-    fused kernel is gated to unsharded/dp-only runs via
-    FLAGS_use_fused_ce."""
+    c_softmax_with_cross_entropy kernel role). One-device programs only:
+    under a mesh GSPMD handles the chunked expression better."""
     if loss_chunk:
         hidden, aux = model_apply(params, tokens, cfg, sp_constraint,
                                   blocks_fn, return_hidden=True,
                                   emb_constraint=emb_constraint)
         head = (params["wte"].T if cfg.tie_embeddings else params["head_w"])
         from ..core.flags import GLOBAL_FLAGS
+        from ..ops.pallas.flash_attention import single_device_program
         from ..ops.pallas.fused_ce import fused_ce_supported, fused_softmax_ce
 
         B, T = tokens.shape
-        # single-device only: pallas custom calls have no GSPMD
-        # partitioning rule, so under dp>1 the kernel would force an
-        # all-gather/replication (or fail to partition) where the chunked
-        # expression shards cleanly
+        # one-device programs only (see single_device_program): under a
+        # mesh the chunked expression shards cleanly, the kernel cannot
         use_fused = (jax.default_backend() == "tpu"
-                     and len(jax.devices()) == 1
+                     and single_device_program()
                      and sp_constraint is None and blocks_fn is None
                      and fused_ce_supported(B * T, cfg.hidden,
                                             cfg.vocab_size)

@@ -283,8 +283,8 @@ def paged_decode_attention_dma(q, k_pages, v_pages, block_table,
         grid=(B,),
         in_specs=[
             pl.BlockSpec((None, nh, d), lambda b, bt, sl: (b, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),     # k_pages stay in HBM
-            pl.BlockSpec(memory_space=pltpu.ANY),     # v_pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),     # k_pages stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),     # v_pages stay in HBM
         ],
         out_specs=pl.BlockSpec((None, nh, d), lambda b, bt, sl: (b, 0, 0)),
         scratch_shapes=[
@@ -302,6 +302,7 @@ def paged_decode_attention_dma(q, k_pages, v_pages, block_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, d), q.dtype),
         interpret=_interpret_mode(),
+        name="paged_decode_dma",
     )(bt_flat, seq_lens.astype(jnp.int32), q, k_pages, v_pages)
 
 
@@ -452,6 +453,7 @@ def paged_decode_attention_mxu(q, kt_pages, v_pages, block_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, d), q.dtype),
         interpret=_interpret_mode(),
+        name="paged_decode_mxu",
     )(bt_flat, seq_lens.astype(jnp.int32), q,
       *([kt_pages] * k_per), *([v_pages] * k_per))
 
@@ -504,6 +506,7 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_table,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nh, d), q.dtype),
         interpret=_interpret_mode(),
+        name="paged_decode",
     )(bt_flat, seq_lens.astype(jnp.int32), q,
       *([k_pages] * k_per), *([v_pages] * k_per))
 
@@ -599,5 +602,6 @@ def decode_attention(q, cache_k, cache_v, pos, sm_scale: float,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, nKV, G, d), q.dtype),
         interpret=_interpret_mode(),
+        name="decode_attention",
     )(jnp.asarray(pos, jnp.int32).reshape(1), *operands)
     return out.reshape(B, nH, d)

@@ -37,26 +37,67 @@ from ...core.dispatch import op
 # fwd and bwd kernels accumulate every dot in fp32.
 ACCUM_DTYPE = "float32"
 
-_INTERPRET = None  # resolved lazily: True on CPU backend (tests), False on TPU
+_INTERPRET = None  # resolved lazily: True on the CPU backend, False on TPU
 
 
 def _interpret_mode() -> bool:
+    """Pallas interpret mode iff the backend is the CPU (tests, lint).
+    Every kernel file imports this one gate.  A backend that is neither
+    cpu nor tpu is an error: interpreting there would report the
+    interpreter's numbers under a device's name."""
     global _INTERPRET
     if _INTERPRET is None:
-        _INTERPRET = jax.default_backend() != "tpu"
+        backend = jax.default_backend()
+        if backend not in ("cpu", "tpu"):
+            raise RuntimeError(
+                f"paddle_tpu Pallas kernels run compiled on 'tpu' and "
+                f"interpreted on 'cpu'; backend is '{backend}'")
+        _INTERPRET = backend == "cpu"
     return _INTERPRET
 
 
 def _tpu_params(n_parallel: int):
-    """CompilerParams marking leading grid dims parallel so Mosaic pipelines
-    across grid steps (the kernels are otherwise latency-bound per program:
-    measured ~60us/program on v5e regardless of block size)."""
+    """CompilerParams marking the leading ``n_parallel`` grid dims
+    parallel and the ONE remaining dim arbitrary, so Mosaic pipelines
+    across grid steps.  Every caller's grid rank is n_parallel + 1
+    (Mosaic rejects a dimension_semantics tuple of any other length)."""
     if _interpret_mode():
         return None
     from jax.experimental.pallas import tpu as pltpu
 
     return pltpu.CompilerParams(
         dimension_semantics=("parallel",) * n_parallel + ("arbitrary",))
+
+
+def single_device_program() -> bool:
+    """True when the program being traced runs on one device: no ambient
+    mesh (a plain jit) or an ambient mesh of one device.  A pallas
+    custom call has no GSPMD partitioning rule, so kernels that are not
+    wrapped in a shard_map gate on this — the mesh the step was built on
+    (parallel/train_step.py sets it), never the host's device count."""
+    am = jax.sharding.get_abstract_mesh()
+    return am.empty or am.size == 1
+
+
+# Row-block heights for the row-streaming epilogue kernels
+# (fused_norm_epilogue / fused_bias_act): 256 first — the hand default
+# wherever it fits — then larger, then the smaller blocks wide rows need
+# (one [bt, width] block must fit VMEM whole; 16 is the bf16 sublane
+# tile).  Row counts stay 256-aligned whatever block is picked.
+_ROW_BLOCKS = (256, 512, 1024, 128, 64, 32, 16)
+# VMEM cap for one row block's working set (operands and results double
+# buffered plus the kernel's fp32 temporaries) of the ~16 MB per core.
+_ROW_BLOCK_VMEM = 8 * 2 ** 20
+
+
+def _row_blocks(n: int, row_bytes: int) -> list[int]:
+    """Up to three row-block candidates, preferred first, for ``n``
+    (256-aligned) rows whose working set is ``row_bytes`` per row; empty
+    when the kernel cannot take the shape."""
+    if n % _ROW_BLOCKS[0]:
+        return []
+    return [bt for bt in _ROW_BLOCKS
+            if n % bt == 0 and bt * row_bytes <= _ROW_BLOCK_VMEM][:3]
 
 
 # Default tile-size caps. Measured on v5e at GPT-350M shapes (B8 S1024 H16
@@ -718,6 +759,7 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, with_lse: bool = False,
             out_shape=out_shapes if with_lse else out_shapes[0],
             interpret=_interpret_mode(),
             compiler_params=_tpu_params(2),
+            name="flash_fwd",
         )(qf, kf, vf)
         if with_lse:
             out, lse = res
@@ -757,6 +799,7 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float, with_lse: bool = False,
         out_shape=out_shapes if with_lse else out_shapes[0],
         interpret=_interpret_mode(),
         compiler_params=_tpu_params(2),
+        name="flash_fwd_t",
     )(qt, kt, vt)
     if with_lse:
         out, lse = res
@@ -853,6 +896,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                                                    dtype),
                     interpret=_interpret_mode(),
                     compiler_params=_tpu_params(2),
+                    name="flash_bwd_dqkv",
                 )(qf, qf, qf, qf, qf, qf, dof, dof, lse, delta, lse,
                   delta)
                 return dqkv4.reshape(b, s, 3 * h * d)
@@ -885,6 +929,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             out_shape=jax.ShapeDtypeStruct((b, s, h * d), dtype),
             interpret=_interpret_mode(),
             compiler_params=_tpu_params(2),
+            name="flash_bwd_dq",
         )(qf, kf, vf, dof, lse, delta)
 
         dk, dv = pl.pallas_call(
@@ -898,6 +943,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                        jax.ShapeDtypeStruct((b, s, h * d), dtype)],
             interpret=_interpret_mode(),
             compiler_params=_tpu_params(2),
+            name="flash_bwd_dkv",
         )(qf, kf, vf, dof, lse, delta)
         if fused:
             return jnp.concatenate([dq, dk, dv], axis=-1)
@@ -931,6 +977,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         out_shape=jax.ShapeDtypeStruct((b, h, s, d), q.dtype),
         interpret=_interpret_mode(),
         compiler_params=_tpu_params(2),
+        name="flash_bwd_dq_t",
     )(qt, kt, vt, dot_, lse, delta)
 
     full_pack = lambda ib, ih, ik: (ib, ih, 0, 0)
@@ -953,6 +1000,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
                    jax.ShapeDtypeStruct((b, h, s, d), v.dtype)],
         interpret=_interpret_mode(),
         compiler_params=_tpu_params(2),
+        name="flash_bwd_dkv_t",
     )(qt, kt, vt, dot_, lse, delta)
 
     return (jnp.swapaxes(dq, 1, 2), jnp.swapaxes(dk, 1, 2),

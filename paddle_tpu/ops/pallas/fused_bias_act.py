@@ -23,9 +23,9 @@ through ``jax.vjp`` of the reference composition, so gradients are
 bitwise the unfused graph's gradients.
 
 Single-program gate: like fused_ce.py, pallas custom calls have no GSPMD
-partitioning rule, so the kernel arm is restricted to single-device
-traces; multichip programs keep the unfused composition (which shards
-cleanly).
+partitioning rule, so the kernel arm is restricted to programs built on
+a one-device mesh; multichip programs keep the unfused composition
+(which shards cleanly).
 """
 
 from __future__ import annotations
@@ -36,40 +36,38 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .flash_attention import _interpret_mode, _tpu_params
+from .flash_attention import (_interpret_mode, _row_blocks, _tpu_params,
+                              single_device_program)
 
 __all__ = ["fused_bias_gelu", "fused_swiglu", "fused_bias_act_supported"]
 
-# VMEM cap for one row block: two operands in, one out (input dtype,
-# double buffered) + ~2 fp32 temporaries of the block.
-_VMEM_BUDGET = 8 * 2 ** 20
-_BT_CANDIDATES = (256, 512, 1024)
-
-
-def _bt_fits(bt: int, f: int, itemsize: int) -> bool:
-    return bt * f * (6 * itemsize + 8) <= _VMEM_BUDGET
+def _bt_candidates(n: int, f: int, itemsize: int) -> list[int]:
+    # per row: two operands in, one out (input dtype, double buffered) +
+    # ~2 fp32 temporaries
+    return _row_blocks(n, f * (6 * itemsize + 8))
 
 
 def fused_bias_act_supported(n: int, f: int, dtype) -> bool:
-    """Gate: lane-aligned ffn width, row count tiling the smallest
-    block, a VMEM-feasible block, and a single-device trace (no GSPMD
-    partitioning rule for pallas custom calls — same gate as
-    fused_ce.py)."""
+    """Gate: lane-aligned ffn width, a row block that tiles the row
+    count and keeps one [bt, f] block set VMEM-feasible, and a
+    single-device program: a pallas custom call has no GSPMD
+    partitioning rule, so under a mesh of more than one device the
+    unfused composition (which shards cleanly) stays.  The mesh is the
+    ambient one the step was built on (train_step sets it), never the
+    host's device count."""
     dt = jnp.dtype(dtype)
-    try:
-        single = len(jax.devices()) == 1
-    except Exception:  # noqa: BLE001 -- no backend: stay off
-        single = False
-    return (f % 128 == 0 and n > 0 and n % _BT_CANDIDATES[0] == 0
+    return (f % 128 == 0 and n > 0
             and dt in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-            and _bt_fits(_BT_CANDIDATES[0], f, dt.itemsize)
-            and single)
+            and bool(_bt_candidates(n, f, dt.itemsize))
+            and single_device_program())
 
 
 def _rp(v):
-    """The one narrowing XLA never removes (see fused_norm_epilogue.py):
-    pins bf16 values to the bf16 grid inside the fused body."""
-    if v.dtype == jnp.bfloat16:
+    """Interpret mode only (the body is then compiled by XLA:CPU): the
+    one narrowing XLA never removes (see fused_norm_epilogue.py) pins
+    bf16 values to the bf16 grid inside the fused body.  Mosaic has no
+    lowering for it and does no convert-pair simplification."""
+    if v.dtype == jnp.bfloat16 and _interpret_mode():
         return lax.reduce_precision(v, 8, 7)
     return v
 
@@ -100,7 +98,7 @@ def _swiglu_kernel(g_ref, u_ref, y_ref):
     y_ref[...] = _rp(h * u_ref[...])
 
 
-def _act_call(kernel, ops, specs, n, f, dtype, bt):
+def _act_call(kernel, ops, specs, n, f, dtype, bt, name):
     import jax.experimental.pallas as pl
 
     row = pl.BlockSpec((bt, f), lambda i: (i, 0))
@@ -112,6 +110,7 @@ def _act_call(kernel, ops, specs, n, f, dtype, bt):
         out_shape=jax.ShapeDtypeStruct((n, f), dtype),
         interpret=_interpret_mode(),
         compiler_params=_tpu_params(0),
+        name=name,
     )(*ops)
 
 
@@ -122,7 +121,7 @@ def _bias_gelu_call(x, bias, *, bt):
     row = pl.BlockSpec((bt, f), lambda i: (i, 0))
     vec = pl.BlockSpec((1, f), lambda i: (0, 0))
     return _act_call(_bias_gelu_kernel, [x, bias.reshape(1, f)],
-                     [row, vec], n, f, x.dtype, bt)
+                     [row, vec], n, f, x.dtype, bt, "fused_bias_gelu")
 
 
 def _swiglu_call(gate, up, *, bt):
@@ -131,7 +130,7 @@ def _swiglu_call(gate, up, *, bt):
     n, f = gate.shape
     row = pl.BlockSpec((bt, f), lambda i: (i, 0))
     return _act_call(_swiglu_kernel, [gate, up], [row, row], n, f,
-                     gate.dtype, bt)
+                     gate.dtype, bt, "fused_swiglu")
 
 
 _SRC = None
@@ -148,13 +147,11 @@ def _autotune_source() -> str:
 
 
 def _tuned_bt(kernel_name: str, n: int, f: int, dtype, call) -> int:
-    """Row-block size via the autotune registry; candidates[0] (256) is
-    the hand default, so no-sweep backends behave exactly as before."""
+    """Row-block size via the autotune registry; candidates[0] (256
+    wherever it fits) is the hand default no-sweep backends use."""
     from . import autotune
 
-    itemsize = jnp.dtype(dtype).itemsize
-    cands = [bt for bt in _BT_CANDIDATES
-             if n % bt == 0 and _bt_fits(bt, f, itemsize)]
+    cands = _bt_candidates(n, f, jnp.dtype(dtype).itemsize)
     if not cands:
         return 0
 

@@ -228,6 +228,7 @@ def _fused_ce_fwd(x, head, labels, bv: int = 0):
         scratch_shapes=[pltpu.VMEM((8, bt), jnp.float32)] * 3,
         interpret=_interpret_mode(),
         compiler_params=_tpu_params(1),
+        name="fused_ce_fwd",
     )(x, headp, lab2)
     return nll[:, 0, :].reshape(N), lse[:, 0, :].reshape(N)
 
@@ -262,6 +263,7 @@ def _fused_ce_bwd(x, head, labels, lse, g, bv: int = 0):
         out_shape=jax.ShapeDtypeStruct((N, H), jnp.float32),
         interpret=_interpret_mode(),
         compiler_params=_tpu_params(1),
+        name="fused_ce_bwd_dx",
     )(headp, x, lab2, lse2, g2)
 
     dh = pl.pallas_call(
@@ -278,6 +280,7 @@ def _fused_ce_bwd(x, head, labels, lse, g, bv: int = 0):
         out_shape=jax.ShapeDtypeStruct((H, n_v * bv), jnp.float32),
         interpret=_interpret_mode(),
         compiler_params=_tpu_params(1),
+        name="fused_ce_bwd_dh",
     )(x, headp, lab2, lse2, g2)
 
     return dx.astype(x.dtype), dh[:, :V].astype(head.dtype)

@@ -40,27 +40,23 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from .flash_attention import _interpret_mode, _tpu_params
+from .flash_attention import _interpret_mode, _row_blocks, _tpu_params
 
 __all__ = ["fused_norm_epilogue", "fused_norm_epilogue_supported"]
 
-# VMEM cap for one row block: x/sub in, r/y out (input dtype, double
-# buffered) + ~3 fp32 temporaries of the block.
-_VMEM_BUDGET = 8 * 2 ** 20
-_BT_CANDIDATES = (256, 512, 1024)
-
-
-def _bt_fits(bt: int, h: int, itemsize: int) -> bool:
-    return bt * h * (8 * itemsize + 12) <= _VMEM_BUDGET
+def _bt_candidates(n: int, h: int, itemsize: int) -> list[int]:
+    # per row: x/sub in, r/y out (input dtype, double buffered) + ~3 fp32
+    # temporaries
+    return _row_blocks(n, h * (8 * itemsize + 12))
 
 
 def fused_norm_epilogue_supported(n: int, h: int, dtype) -> bool:
-    """Gate: lane-aligned hidden, row count tiling the smallest block,
-    and a VMEM-feasible block."""
+    """Gate: lane-aligned hidden and a row block that tiles the row
+    count and keeps one [bt, h] block set VMEM-feasible."""
     dt = jnp.dtype(dtype)
-    return (h % 128 == 0 and n > 0 and n % _BT_CANDIDATES[0] == 0
+    return (h % 128 == 0 and n > 0
             and dt in (jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.float32))
-            and _bt_fits(_BT_CANDIDATES[0], h, dt.itemsize))
+            and bool(_bt_candidates(n, h, dt.itemsize)))
 
 
 def _norm_ref(r, gain, beta, norm: str, eps: float, act, one=None):
@@ -103,7 +99,15 @@ def _epilogue_xla(x, sub, bias, gain, beta, norm, eps, act):
     return r, _norm_ref(r, gain, beta, norm, eps, act)
 
 
-def _epilogue_kernel(*refs, norm, eps, act, has_sub, has_bias, has_beta):
+def _epilogue_kernel(*refs, norm, eps, act, has_sub, has_bias, has_beta,
+                     exact):
+    """``exact`` (interpret mode, i.e. the body is compiled by XLA:CPU)
+    adds the two guards that pin the body bitwise to the eager op-by-op
+    graph.  Mosaic lowers neither ``reduce_precision`` nor
+    ``optimization_barrier`` and needs neither: it does not run XLA's
+    convert-pair simplification, and what it contracts is its own
+    business — the compiled kernel is held to a tolerance on the chip
+    (chip_smoke.py), not to bits."""
     dtype = refs[0].dtype
     # XLA fuses the whole kernel body and would elide the bf16 rounding
     # between the adds and the fp32 norm (convert-pair simplification),
@@ -111,7 +115,7 @@ def _epilogue_kernel(*refs, norm, eps, act, has_sub, has_bias, has_beta):
     # reduce_precision is the one narrowing XLA never removes, so each
     # add rounds exactly like its eager counterpart and r32 lands on the
     # bf16 grid — the later astype round-trips are then value-exact.
-    if dtype == jnp.bfloat16:
+    if exact and dtype == jnp.bfloat16:
         rp = lambda v: lax.reduce_precision(v, 8, 7)  # noqa: E731
     else:
         rp = lambda v: v                              # noqa: E731
@@ -130,13 +134,15 @@ def _epilogue_kernel(*refs, norm, eps, act, has_sub, has_bias, has_beta):
     beta = one = None
     if has_beta:
         beta = refs[idx][0, :]
-        # the barrier keeps the 1.0 runtime-opaque even when the operand
-        # is a compile-time constant (it always is under jit: the ones
-        # array is created inside this traced call) — without it XLA
-        # folds the *one mul away and fma contraction skips the product
-        # rounding (see _norm_ref)
-        one = lax.optimization_barrier(refs[idx + 1][0, 0])
-        idx += 2
+        idx += 1
+        if exact:
+            # the barrier keeps the 1.0 runtime-opaque even when the
+            # operand is a compile-time constant (it always is under jit:
+            # the ones array is created inside this traced call) —
+            # without it XLA folds the *one mul away and fma contraction
+            # skips the product rounding (see _norm_ref)
+            one = lax.optimization_barrier(refs[idx][0, 0])
+            idx += 1
     r_ref, y_ref = refs[idx], refs[idx + 1]
     r = acc.astype(dtype)
     r_ref[...] = r
@@ -147,6 +153,7 @@ def _epilogue_call(x, sub, bias, gain, beta, *, norm, eps, act, bt):
     import jax.experimental.pallas as pl
 
     N, H = x.shape
+    exact = _interpret_mode()
     row = pl.BlockSpec((bt, H), lambda i: (i, 0))
     vec = pl.BlockSpec((1, H), lambda i: (0, 0))
     ops, specs = [x], [row]
@@ -161,19 +168,21 @@ def _epilogue_call(x, sub, bias, gain, beta, *, norm, eps, act, bt):
     if beta is not None:
         ops.append(beta.reshape(1, H))
         specs.append(vec)
-        # runtime-opaque 1.0 (see _norm_ref docstring)
-        ops.append(jnp.ones((1, 1), jnp.float32))
-        specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
+        if exact:
+            # runtime-opaque 1.0 (see _norm_ref docstring)
+            ops.append(jnp.ones((1, 1), jnp.float32))
+            specs.append(pl.BlockSpec((1, 1), lambda i: (0, 0)))
     return pl.pallas_call(
         functools.partial(_epilogue_kernel, norm=norm, eps=eps, act=act,
                           has_sub=sub is not None, has_bias=bias is not None,
-                          has_beta=beta is not None),
+                          has_beta=beta is not None, exact=exact),
         grid=(N // bt,),
         in_specs=specs,
         out_specs=[row, row],
         out_shape=[jax.ShapeDtypeStruct((N, H), x.dtype)] * 2,
-        interpret=_interpret_mode(),
+        interpret=exact,
         compiler_params=_tpu_params(0),
+        name=f"fused_{norm}_epilogue",
     )(*ops)
 
 
@@ -190,13 +199,11 @@ def _autotune_source() -> str:
 
 
 def _tuned_bt(n: int, h: int, dtype, norm: str) -> int:
-    """Row-block size via the autotune registry; candidates[0] (256) is
-    the hand default, so no-sweep backends behave exactly as before."""
+    """Row-block size via the autotune registry; candidates[0] (256
+    wherever it fits) is the hand default no-sweep backends use."""
     from . import autotune
 
-    itemsize = jnp.dtype(dtype).itemsize
-    cands = [bt for bt in _BT_CANDIDATES
-             if n % bt == 0 and _bt_fits(bt, h, itemsize)]
+    cands = _bt_candidates(n, h, jnp.dtype(dtype).itemsize)
     if not cands:
         return 0
 

@@ -101,24 +101,34 @@ def _rope_pullback(dy, cos_f, sin_sgn):
 
 
 def _rope_rows(x, c_rows, s_rows, one, d: int):
-    """Rotate a [rows, d] tile in fp32 with per-product rounding forced
-    (opaque-one against fma contraction, reduce_precision against
-    convert-pair elision) so the tile is bitwise what the eager
-    apply_rope would have produced."""
+    """Rotate a [rows, d] tile in fp32.  With ``one`` (interpret mode:
+    the body is compiled by XLA:CPU) per-product rounding is forced —
+    opaque-one against fma contraction, reduce_precision against
+    convert-pair elision — so the tile is bitwise what the eager
+    apply_rope would have produced.  Mosaic lowers neither guard and the
+    compiled kernel is held to a tolerance on the chip instead."""
     x32 = x.astype(jnp.float32)
     xs = jnp.concatenate([x32[:, d // 2:], x32[:, :d // 2]], axis=1)
+    if one is None:
+        return (x32 * c_rows + xs * s_rows).astype(x.dtype)
     y = (x32 * c_rows) * one + (xs * s_rows) * one
     if x.dtype == jnp.bfloat16:
         y = lax.reduce_precision(y, 8, 7)
     return y.astype(x.dtype)
 
 
-def _rope_flash_fwd_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, one_ref,
-                           o_ref, lse_ref=None, *, causal, sm_scale, block_k,
-                           seq_len, d, rope_q, rope_k):
+def _rope_flash_fwd_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, *refs,
+                           causal, sm_scale, block_k, seq_len, d, rope_q,
+                           rope_k, exact, with_lse):
     """_flash_fwd_kernel_native specialized to hp=1, with the rotary
-    applied to the q tile once and to each k tile inside the loop."""
+    applied to the q tile once and to each k tile inside the loop.
+    ``refs`` = [one_ref if exact] + [o_ref] + [lse_ref if with_lse]."""
     import jax.experimental.pallas as pl
+
+    refs = list(refs)
+    one_ref = refs.pop(0) if exact else None
+    o_ref = refs.pop(0)
+    lse_ref = refs.pop(0) if with_lse else None
 
     q_idx = pl.program_id(2)
     bq = q_ref.shape[0]
@@ -129,7 +139,7 @@ def _rope_flash_fwd_kernel(q_ref, k_ref, v_ref, cos_ref, sin_ref, one_ref,
     # compile-time constant (it always is under jit: the ones array is
     # created inside the traced wrapper) — without it XLA folds the
     # *one muls away and fma contraction skips the product rounding
-    one = lax.optimization_barrier(one_ref[0, 0])
+    one = lax.optimization_barrier(one_ref[0, 0]) if exact else None
 
     q = q_ref[...]                                   # [bq, d]
     if rope_q:
@@ -191,7 +201,11 @@ def _rope_fwd(q, k, v, cos_f, sin_sgn, causal: bool, sm_scale: float,
     blk = pl.BlockSpec((None, block_q, d), lambda ib, ih, iq: (ib, iq, ih))
     full = pl.BlockSpec((None, s, d), lambda ib, ih, iq: (ib, 0, ih))
     tab = pl.BlockSpec((s, d), lambda ib, ih, iq: (0, 0))
-    one = pl.BlockSpec((1, 1), lambda ib, ih, iq: (0, 0))
+    exact = _interpret_mode()
+    ops, in_specs = [qf, kf, vf, cos_f, sin_sgn], [blk, full, full, tab, tab]
+    if exact:
+        ops.append(jnp.ones((1, 1), jnp.float32))
+        in_specs.append(pl.BlockSpec((1, 1), lambda ib, ih, iq: (0, 0)))
     out_shapes = [jax.ShapeDtypeStruct((b, s, h * d), q.dtype)]
     out_specs = [blk]
     if with_lse:
@@ -200,18 +214,18 @@ def _rope_fwd(q, k, v, cos_f, sin_sgn, causal: bool, sm_scale: float,
                                       lambda ib, ih, iq: (ib, ih, 0, iq)))
     kern = functools.partial(
         _rope_flash_fwd_kernel, causal=causal, sm_scale=sm_scale,
-        block_k=block_k, seq_len=s, d=d, rope_q=rope_q, rope_k=rope_k)
-    if not with_lse:
-        kern = functools.partial(kern, lse_ref=None)
+        block_k=block_k, seq_len=s, d=d, rope_q=rope_q, rope_k=rope_k,
+        exact=exact, with_lse=with_lse)
     res = pl.pallas_call(
         kern,
         grid=grid,
-        in_specs=[blk, full, full, tab, tab, one],
+        in_specs=in_specs,
         out_specs=out_specs if with_lse else out_specs[0],
         out_shape=out_shapes if with_lse else out_shapes[0],
-        interpret=_interpret_mode(),
+        interpret=exact,
         compiler_params=_tpu_params(2),
-    )(qf, kf, vf, cos_f, sin_sgn, jnp.ones((1, 1), jnp.float32))
+        name="rope_flash_fwd",
+    )(*ops)
     if with_lse:
         out, lse = res
         return out.reshape(b, s, h, d), lse

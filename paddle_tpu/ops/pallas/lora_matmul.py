@@ -108,6 +108,7 @@ def lora_matmul_kernel(x, a_stack, b_stack, ids, bn: int):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, qb, N), jnp.float32),
         interpret=_interpret_mode(),
+        name="lora_matmul",
     )(ids.astype(jnp.int32), x, a_stack, b_stack)
 
 

@@ -95,6 +95,7 @@ def quant_matmul_kernel(x2, wq, scale, bm: int, bn: int, bk: int):
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.float32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
         interpret=_interpret_mode(),
+        name="quant_matmul",
     )(x2, wq, scale)
     return out
 

@@ -203,6 +203,7 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, nkv, qb * G, d), q.dtype),
         interpret=_interpret_mode(),
+        name="ragged_paged_attention",
     )
     pre = (rows_flat, pos0.astype(jnp.int32), n_valid.astype(jnp.int32))
     if quant:

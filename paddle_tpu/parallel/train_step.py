@@ -307,24 +307,6 @@ def _abstract_opt_state(params_abs: dict, mesh: Mesh, *, master: bool,
     return out
 
 
-def zero_shard_opt_state(state: dict, mesh: Mesh, axis: str = "dp") -> dict:
-    """ZeRO-1: spread AdamW moments (and fp32 masters, when present) over
-    the dp axis (reference DygraphShardingOptimizer,
-    dygraph_sharding_optimizer.py:49)."""
-    if axis not in mesh.axis_names or mesh.shape[axis] == 1:
-        return state
-    from ..distributed.sharding import shard_array_over
-
-    def put(a):
-        return shard_array_over(a, mesh, axis) if a.ndim > 0 else a
-
-    out = {"m": jax.tree.map(put, state["m"]),
-           "v": jax.tree.map(put, state["v"]), "t": state["t"]}
-    if "master" in state:
-        out["master"] = jax.tree.map(put, state["master"])
-    return out
-
-
 def make_sharded_train_step(cfg: GPTConfig, mesh: Mesh, lr: float = 1e-4,
                             n_microbatches: int = 1, zero1: bool = True,
                             seed: int = 0, m_dtype: str | None = None,
@@ -383,29 +365,38 @@ def make_sharded_train_step(cfg: GPTConfig, mesh: Mesh, lr: float = 1e-4,
     low_precision = jnp.dtype(cfg.param_dtype) != jnp.dtype(cfg.dtype)
     sr = weights == "sr-bf16" and low_precision
     master = low_precision and not sr
-    if abstract:
-        # AOT mode: ShapeDtypeStructs with the exact shardings the real
-        # path would produce — lets configs too large for the analysis host
-        # (13B+) be lowered/compiled for memory + collective analysis.
-        params = _abstract_params(cfg, mesh, seed)
-        if master or sr:
-            params = jax.tree.map(
-                lambda a: (jax.ShapeDtypeStruct(a.shape, cfg.dtype,
-                                                sharding=a.sharding)
-                           if a.ndim >= 2 else a), params)
-        opt_state = _abstract_opt_state(params, mesh, master=master,
-                                        m_dtype=m_dtype, v_dtype=v_dtype,
-                                        zero1=zero1)
-    else:
-        params = init_params(cfg, jax.random.PRNGKey(seed))
-        params = shard_gpt_params(params, cfg, mesh)
-        opt_state = adamw_init(params, master_weights=master,
-                               m_dtype=m_dtype, v_dtype=v_dtype)
-        if master or sr:
-            params = jax.tree.map(
-                lambda a: a.astype(cfg.dtype) if a.ndim >= 2 else a, params)
-        if zero1:
-            opt_state = zero_shard_opt_state(opt_state, mesh)
+    # The state as ShapeDtypeStructs carrying its shardings: params in
+    # the Megatron layout, moments/masters inheriting it plus the ZeRO-1
+    # dp shard.  AOT mode returns exactly this — configs too large for
+    # the analysis host (13B+) lower/compile from it for memory +
+    # collective analysis.
+    params = _abstract_params(cfg, mesh, seed)
+    if master or sr:
+        params = jax.tree.map(
+            lambda a: (jax.ShapeDtypeStruct(a.shape, cfg.dtype,
+                                            sharding=a.sharding)
+                       if a.ndim >= 2 else a), params)
+    opt_state = _abstract_opt_state(params, mesh, master=master,
+                                    m_dtype=m_dtype, v_dtype=v_dtype,
+                                    zero1=zero1)
+    if not abstract:
+        # The real state is that abstract state materialized by ONE
+        # program whose outputs are born in those shardings: each device
+        # creates only its own shards.  (Built eagerly, the full fp32
+        # params and moments land on the default device first, so a
+        # model that needs the mesh would not fit at all.)
+        def init_state(key):
+            p = init_params(cfg, key)
+            o = adamw_init(p, master_weights=master, m_dtype=m_dtype,
+                           v_dtype=v_dtype)
+            if master or sr:
+                p = jax.tree.map(
+                    lambda a: a.astype(cfg.dtype) if a.ndim >= 2 else a, p)
+            return p, o
+
+        params, opt_state = jax.jit(init_state, out_shardings=jax.tree.map(
+            lambda a: a.sharding, (params, opt_state)))(
+                jax.random.PRNGKey(seed))
 
     use_pp = "pp" in mesh.axis_names and mesh.shape["pp"] > 1
     use_sp = "mp" in mesh.axis_names and mesh.shape["mp"] > 1
@@ -553,8 +544,7 @@ def make_sharded_train_step(cfg: GPTConfig, mesh: Mesh, lr: float = 1e-4,
 
     def put_batch(arr):
         """Shard a host batch over dp. Call once per batch; feeding numpy
-        directly to step_fn also works but re-uploads every call (costly
-        over remote-device tunnels)."""
+        directly to step_fn also works but re-uploads every call."""
         return jax.device_put(arr, NamedSharding(
             mesh, _sanitize(P("dp"), arr.shape, mesh)))
 

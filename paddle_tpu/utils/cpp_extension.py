@@ -190,9 +190,9 @@ def custom_op(name: str, forward_cfunc, grad_cfunc=None):
     def _run_host(call, x):
         """Run the C function: through pure_callback where the backend
         supports host callbacks (CPU, standard TPU runtimes), else via an
-        eager host round-trip (some remote PJRT backends, e.g. tunneled
-        ones, lack send/recv callbacks — eager mode still works there;
-        captured programs need callback support)."""
+        eager host round-trip (a PJRT backend may lack send/recv
+        callbacks — eager mode still works there; captured programs need
+        callback support)."""
         if _callbacks_supported():
             return jax.pure_callback(
                 call, jax.ShapeDtypeStruct(x.shape, jnp.float32),

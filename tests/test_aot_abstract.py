@@ -49,10 +49,6 @@ def test_abstract_state_matches_real():
         assert a.dtype == r.dtype
 
 
-from conftest import requires_native_partial_manual
-
-
-@requires_native_partial_manual()
 @pytest.mark.parametrize("weights,m_dtype", [("auto", None),
                                              ("sr-bf16", "bfloat16")])
 def test_abstract_lower_compile_memory(weights, m_dtype):

@@ -15,8 +15,8 @@ import pytest
 import jax
 
 from paddle_tpu.core.flags import GLOBAL_FLAGS
-from paddle_tpu.ops.pallas.autotune import (AutotuneRegistry, cache_path,
-                                            source_hash)
+from paddle_tpu.ops.pallas.autotune import (AutotuneRegistry, _device_kind,
+                                            cache_path, source_hash)
 
 pytestmark = pytest.mark.smoke
 
@@ -101,15 +101,73 @@ def test_disabled_registry_returns_default(tmp_path, sweep_on):
         GLOBAL_FLAGS.set("pallas_autotune", old)
 
 
-def test_all_candidates_failing_returns_default(tmp_path, sweep_on):
+def test_refused_default_or_kernel_arm_raises(tmp_path, sweep_on):
+    """A sweep may not swallow a compile failure of the default or of a
+    Pallas arm: that is how a Mosaic refusal used to turn into the XLA
+    path without a word."""
     def boom(cand):
-        raise RuntimeError("infeasible")
+        raise RuntimeError("mosaic says no")
 
-    reg = AutotuneRegistry(str(tmp_path / "cache.json"))
-    assert reg.tuned("k", "b1", "bf16", [256, 512], measure=boom,
-                     source="s") == 256
+    path = str(tmp_path / "cache.json")
+    with pytest.raises(RuntimeError, match="candidate 256 failed"):
+        AutotuneRegistry(path).tuned("k", "b1", "bf16", [256, 512],
+                                     measure=boom, source="s")
+
+    def kernel_boom(cand):
+        if cand != "xla":
+            raise RuntimeError("mosaic says no")
+        return 1.0
+
+    with pytest.raises(RuntimeError, match="'kernel:128' failed"):
+        AutotuneRegistry(path).tuned("k", "b2", "bf16",
+                                     ["xla", "kernel:128"],
+                                     measure=kernel_boom, source="s")
     # a failed sweep must not poison the cache
-    assert not os.path.exists(str(tmp_path / "cache.json"))
+    assert not os.path.exists(path)
+
+
+def test_refused_tile_is_skipped_with_its_error_text(tmp_path, sweep_on):
+    def measure(cand):
+        if cand == 1024:
+            raise RuntimeError("scoped vmem exceeded")
+        return {256: 2.0, 512: 1.0}[cand]
+
+    path = str(tmp_path / "cache.json")
+    reg = AutotuneRegistry(path)
+    assert reg.tuned("k", "b1", "bf16", [256, 512, 1024], measure=measure,
+                     source="s") == 512
+    entry = json.load(open(path))["entries"]["k|%s|b1|bf16" % _device_kind()]
+    assert entry["refused"] == {"1024": "RuntimeError: scoped vmem exceeded"}
+
+
+def test_committed_table_is_read_first_and_never_written(tmp_path, sweep_on):
+    """The tracked table pins configs across checkouts: it outranks the
+    per-checkout file, hits without sweeping, and is never modified."""
+    key = "k|%s|b1|bf16" % _device_kind()
+    committed = str(tmp_path / "committed.json")
+    with open(committed, "w") as f:
+        json.dump({"version": 2, "programs": {}, "entries": {
+            key: {"config": 512, "source": "s"}}}, f)
+    before = open(committed).read()
+    path = str(tmp_path / "cache.json")
+    with open(path, "w") as f:
+        json.dump({"version": 2, "programs": {}, "entries": {
+            key: {"config": 256, "source": "s"}}}, f)
+
+    def never(cand):
+        raise AssertionError("committed entry must not be re-swept")
+
+    reg = AutotuneRegistry(path, committed=committed)
+    assert reg.tuned("k", "b1", "bf16", [256, 512], measure=never,
+                     source="s") == 512
+    assert (reg.hits, reg.sweeps) == (1, 0)
+    # a kernel edit (new source hash) makes the committed entry stale:
+    # the sweep result goes to the per-checkout file only
+    assert reg.tuned("k", "b1", "bf16", [256, 512],
+                     measure=_measure({256: 1.0, 512: 2.0}),
+                     source="s2") == 256
+    assert open(committed).read() == before
+    assert json.load(open(path))["entries"][key]["source"] == "s2"
 
 
 def test_corrupt_cache_is_empty_cache(tmp_path, sweep_on):

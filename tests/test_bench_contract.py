@@ -335,14 +335,6 @@ def test_obs_key_contract(bench):
     assert "_obs_keys(" in body
 
 
-from conftest import requires_native_partial_manual
-
-
-# On a jax_compat-shimmed runtime the real primary bench (a compiled
-# sharded train step over the 8-device virtual mesh) segfaults jaxlib
-# mid-suite; the JSON-line contract is fully covered by the stubbed
-# secondary tests below, so gate the real-step run on native lowering.
-@requires_native_partial_manual()
 def test_cpu_main_emits_one_json_line(bench):
     """The CI-path main() honors the one-JSON-line driver contract."""
     buf = io.StringIO()

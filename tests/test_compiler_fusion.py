@@ -176,8 +176,8 @@ def test_rope_attention_matches_both_chains():
     rep = discover(fn, q, k, v, cos, sin)
     assert [s["template"] for s in rep.sites] == ["rope_attention"]
     assert rep.n_applied == 1
-    # both chains consumed: q rope (11) + k rope (11) + flash (1)
-    assert rep.sites[0]["eqns"] == 23
+    # both chains consumed: q rope (10) + k rope (10) + flash (1)
+    assert rep.sites[0]["eqns"] == 21
     _check_parity(fn, q, k, v, cos, sin)
 
 
@@ -199,7 +199,7 @@ def test_rope_attention_escaping_k_falls_back_to_q_only():
     rep = discover(fn, q, k, v, cos, sin)
     assert [s["template"] for s in rep.sites] == ["rope_attention"]
     assert rep.n_applied == 1
-    assert rep.sites[0]["eqns"] == 12   # q chain + flash only
+    assert rep.sites[0]["eqns"] == 11   # q chain + flash only
     _check_parity(fn, q, k, v, cos, sin)
 
 
@@ -291,7 +291,7 @@ def test_foreign_tables_fuse_q_only():
 
     rep = discover(fn, q, k, v, cos, sin, cos2, sin2)
     assert [s["template"] for s in rep.sites] == ["rope_attention"]
-    assert rep.sites[0]["eqns"] == 12   # q chain + flash only
+    assert rep.sites[0]["eqns"] == 11   # q chain + flash only
 
 
 def test_sharding_constraint_blocks_norm_fusion(fusion_flags):
@@ -398,7 +398,7 @@ def test_llama_prefill_gets_q_only_rope():
                    params, tokens, cache)
     rope = [s for s in rep.sites if s["template"] == "rope_attention"]
     assert len(rope) == 1
-    assert rope[0]["eqns"] == 12   # q chain + flash; k passed pre-rotated
+    assert rope[0]["eqns"] == 11   # q chain + flash; k passed pre-rotated
 
 
 def test_gpt_rediscovers_layer_epilogues_and_bias_gelu():

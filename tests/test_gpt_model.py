@@ -124,10 +124,6 @@ def test_pipeline_grads_match_dense():
                                    rtol=5e-3, atol=1e-4)
 
 
-from conftest import requires_native_partial_manual
-
-
-@requires_native_partial_manual()
 def test_hybrid_train_step_learns():
     cfg = GPTConfig(vocab_size=64, hidden=32, n_layers=4, n_heads=4,
                     seq_len=16, n_experts=2, n_moe_layers=1,
@@ -240,3 +236,33 @@ def test_interleaved_pipeline_matches_serial():
             h = l(h)
         tot += float(loss_fn(h, pt.to_tensor(Y[k * 4:(k + 1) * 4])).numpy())
     np.testing.assert_allclose(vpp_loss, tot / 2, rtol=1e-4)
+
+
+def test_flash_runs_per_shard_under_a_mesh():
+    """Under a dp x mp mesh the flash entries sit in a fully-manual
+    shard_map over batch and heads (a bare Mosaic kernel cannot be
+    partitioned; interpret mode would hide a replicated one, so the
+    specs are pinned here at the jaxpr level)."""
+    from jax.sharding import PartitionSpec as P
+
+    from paddle_tpu.models import gpt as G
+
+    cfg = GPTConfig(vocab_size=64, hidden=512, n_layers=1, n_heads=4,
+                    seq_len=128)
+    mesh = build_mesh((2, 1, 2), ("dp", "pp", "mp"))
+    qkv = jnp.zeros((4, 128, 3 * 512), jnp.bfloat16)
+    with jax.sharding.set_mesh(mesh):
+        jaxpr = jax.make_jaxpr(lambda x: G._flash_qkv(x, cfg))(qkv).jaxpr
+    (sm,) = [e for e in jaxpr.eqns if e.primitive.name == "shard_map"]
+    assert sm.params["manual_axes"] == frozenset({"dp", "pp", "mp"})
+    assert sm.params["in_specs"] == (P("dp", None, None, "mp", None),)
+    assert sm.params["out_specs"] == (P("dp", None, "mp", None),)
+    # a batch the dp axis does not divide stays replicated over it, as
+    # in the surrounding program (distributed/placement.py)
+    with jax.sharding.set_mesh(mesh):
+        jaxpr = jax.make_jaxpr(lambda x: G._flash_qkv(x, cfg))(qkv[:3]).jaxpr
+    (sm,) = [e for e in jaxpr.eqns if e.primitive.name == "shard_map"]
+    assert sm.params["in_specs"] == (P(None, None, None, "mp", None),)
+    # one device: the kernel is called bare
+    jaxpr = jax.make_jaxpr(lambda x: G._flash_qkv(x, cfg))(qkv).jaxpr
+    assert not [e for e in jaxpr.eqns if e.primitive.name == "shard_map"]

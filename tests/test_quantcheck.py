@@ -249,7 +249,7 @@ def test_tpl302_fires_on_upcast_point_and_f64_invar():
     import jax
     import jax.numpy as jnp
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         up = _entry_of(lambda x: x.astype(jnp.float64) * 2.0,
                        [jax.ShapeDtypeStruct((4,), jnp.float32)])
         inv = _entry_of(lambda x: x + 1.0,

@@ -288,10 +288,6 @@ def test_tuner_calibration():
         cand.est_step_time < 0.2
 
 
-from conftest import requires_native_partial_manual
-
-
-@requires_native_partial_manual()
 def test_ring_attention_reachable_from_flagship():
     """cfg.ring_axis wires ring attention into the sharded train step
     (VERDICT r2 weak 10): loss must match the dense-attention step."""

@@ -44,7 +44,7 @@ propagated environment:
           int8 (or their raw float view) without an intervening
           dequantize/rescale — each pass multiplies the rounding error.
 
-The interpreter recurses into scan/remat2/pjit/shard_map/custom-vjp
+The interpreter recurses into scan/remat2/jit/shard_map/custom-vjp
 bodies exactly as shardcheck does (scan carries run a 2-sweep
 fixpoint), and baseline/EXPLAINED/diff semantics mirror shardcheck:
 ``python -m tools.lint --quantcheck`` with exit codes 0 clean / 1
@@ -300,7 +300,6 @@ def build_train_entry() -> QuantEntry:
 
 def build_serving_fp32_entry() -> QuantEntry:
     _jax()
-    import paddle_tpu  # noqa: F401  -- installs the jax_compat shims
 
     eng = _tiny_engine(kv_quant=False)
     closed = eng.trace_unified()
@@ -464,8 +463,8 @@ _STRUCTURAL = {
 }
 
 _HIGHER_ORDER = {
-    "pjit", "scan", "while", "cond", "remat2", "custom_jvp_call",
-    "custom_vjp_call", "custom_vjp_call_jaxpr", "shard_map",
+    "jit", "scan", "while", "cond", "remat2", "custom_jvp_call",
+    "custom_vjp_call", "shard_map",
 }
 
 _SCATTER_SET = {"scatter", "scatter-add", "scatter_add",
@@ -625,7 +624,7 @@ class QuantInterp:
         return carry + outs[ncarry:]
 
     def _do_body(self, eqn, ins):
-        """Generic higher-order handler (pjit/while/cond/remat/custom-
+        """Generic higher-order handler (jit/while/cond/remat/custom-
         vjp/shard_map): run every body with the trailing-aligned operand
         states and join the results — QVals are shape-agnostic, so no
         per-dim bookkeeping is needed."""

@@ -10,7 +10,7 @@ properties from the **jaxpr**, before any device executes anything:
    RPA serving step, the disagg wire stage/commit kernels, the
    quantized all-reduce) is traced to a closed jaxpr,
 2. an abstract interpreter propagates PartitionSpecs through every
-   equation — recursing into scan/remat2/pjit/shard_map/custom-vjp
+   equation — recursing into scan/remat2/jit/shard_map/custom-vjp
    bodies exactly as ``compiler/fusion_pass.py`` recurses for fusion
    discovery,
 3. four rule families fire on the propagated environment:
@@ -76,9 +76,8 @@ VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 EXPLAINED = {
     ("train_dp2_pp2_mp2", "TPL202"):
         "the 1F1B pipeline region is partial-manual by design (pp manual,"
-        " dp/mp auto); it lowers only on runtimes with native"
-        " partial-manual shard_map — tests skip it via"
-        " requires_native_partial_manual, shardcheck documents it here",
+        " dp/mp auto); jax.shard_map lowers it natively, shardcheck"
+        " documents it here",
     ("quant_allreduce_dp2pp2", "TPL202"):
         "the known dist_allreduce_quant pp>1 refusal: train_step raises"
         " ValueError for this mesh before tracing; the entry exists so"
@@ -224,7 +223,6 @@ def build_train_entry(name: str = "train_dp2_pp2_mp2",
     import numpy as np
 
     jax = _jax()
-    import paddle_tpu  # noqa: F401  -- installs the jax_compat shims
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
     from paddle_tpu.parallel.train_step import make_sharded_train_step
@@ -365,23 +363,18 @@ def build_entries(names=None) -> list:
 
 def _eqn_location(eqn):
     """(repo-relative path, line) of the user frame that created the
-    eqn, best effort."""
-    try:
-        from jax._src import source_info_util
+    eqn; (None, 0) when the traceback holds no user frame."""
+    from jax._src import source_info_util
 
-        frame = source_info_util.user_frame(eqn.source_info)
-        if frame is None:
-            return None, 0
-        fname = frame.file_name
-        line = getattr(frame, "start_line", None) or getattr(
-            frame, "line_num", 0)
-        for anchor in ("paddle_tpu/", "tools/", "tests/"):
-            i = fname.find(anchor)
-            if i >= 0:
-                return fname[i:], int(line)
-        return fname, int(line)
-    except Exception:
+    frame = source_info_util.user_frame(eqn.source_info.traceback)
+    if frame is None:
         return None, 0
+    fname = frame.file_name
+    for anchor in ("paddle_tpu/", "tools/", "tests/"):
+        i = fname.find(anchor)
+        if i >= 0:
+            return fname[i:], int(frame.start_line)
+    return fname, int(frame.start_line)
 
 
 def _inner_closed(eqn):
@@ -391,22 +384,14 @@ def _inner_closed(eqn):
     p = eqn.params
     name = eqn.primitive.name
     out = []
-    if name == "scan" or name == "pjit":
+    if name == "scan" or name == "jit":
         c = p["jaxpr"]
         out.append((c.jaxpr, c.consts))
-    elif name == "remat2" or name == "custom_vjp_call_jaxpr":
-        j = p.get("jaxpr") or p.get("fun_jaxpr")
-        if hasattr(j, "jaxpr"):
-            out.append((j.jaxpr, j.consts))
-        else:
-            out.append((j, []))
+    elif name == "remat2":
+        out.append((p["jaxpr"], []))                 # an open jaxpr
     elif name in ("custom_jvp_call", "custom_vjp_call"):
-        c = p.get("call_jaxpr") or p.get("fun_jaxpr")
-        if c is not None:
-            if hasattr(c, "jaxpr"):
-                out.append((c.jaxpr, c.consts))
-            else:
-                out.append((c, []))
+        c = p["call_jaxpr"]
+        out.append((c.jaxpr, c.consts))
     elif name == "while":
         c = p["body_jaxpr"]
         out.append((c.jaxpr, c.consts))
@@ -414,11 +399,7 @@ def _inner_closed(eqn):
         for c in p["branches"]:
             out.append((c.jaxpr, c.consts))
     elif name == "shard_map":
-        j = p["jaxpr"]
-        if hasattr(j, "jaxpr"):
-            out.append((j.jaxpr, j.consts))
-        else:
-            out.append((j, []))
+        out.append((p["jaxpr"], []))                 # an open jaxpr
     return out
 
 
@@ -462,8 +443,6 @@ class ShardInterp:
 
     @staticmethod
     def _read(env, atom):
-        import jax.core as jcore  # noqa: F401  (Literal check via name)
-
         if type(atom).__name__ == "Literal":
             return _empty_spec(_nd(atom.aval)), False
         return env.get(atom, (_empty_spec(_nd(atom.aval)), False))
@@ -504,14 +483,14 @@ class ShardInterp:
         for i, eqn in enumerate(jaxpr.eqns):
             name = eqn.primitive.name
             ins = [self._read(env, a) for a in eqn.invars]
-            if name == "pjit":
-                outs = self._do_pjit(eqn, ins, region)
+            if name == "jit":
+                outs = self._do_jit(eqn, ins, region)
             elif name == "scan":
                 outs = self._do_scan(eqn, ins, region)
             elif name == "shard_map":
                 outs = self._do_shard_map(eqn, ins, region)
             elif name in ("remat2", "custom_jvp_call", "custom_vjp_call",
-                          "custom_vjp_call_jaxpr", "while", "cond"):
+                          "while", "cond"):
                 outs = self._do_opaque_body(eqn, ins, region)
             else:
                 if name in COLLECTIVE_PRIMS:
@@ -538,7 +517,7 @@ class ShardInterp:
         self._interp(jaxpr, env, region)
         return [self._read(env, v) for v in jaxpr.outvars], env
 
-    def _do_pjit(self, eqn, ins, region):
+    def _do_jit(self, eqn, ins, region):
         inner, consts = eqn.params["jaxpr"].jaxpr, eqn.params["jaxpr"].consts
         states = list(ins)
         for j, sh in enumerate(eqn.params.get("in_shardings", ()) or ()):
@@ -580,47 +559,28 @@ class ShardInterp:
 
     def _do_shard_map(self, eqn, ins, region):
         p = eqn.params
-        mesh = p.get("mesh")
-        mesh_axes = dict(region.mesh_axes)
-        if mesh is not None and getattr(mesh, "shape", None):
-            try:
-                mesh_axes = dict(mesh.shape)
-            except Exception:
-                pass
-        auto = frozenset(p.get("auto", frozenset()) or frozenset())
-        manual = frozenset(a for a in mesh_axes if a not in auto)
+        mesh_axes = dict(p["mesh"].shape) or dict(region.mesh_axes)
+        manual = frozenset(p["manual_axes"])
         inner_region = _Region(mesh_axes=mesh_axes,
                                manual=region.manual | manual)
-        bodies = _inner_closed(eqn)
-        if not bodies:
-            return _propagate(eqn, ins)
-        inner, consts = bodies[0]
-        # inside the manual region the named axes are local: strip them
+        inner = p["jaxpr"]
+        # inside the manual region the axes an in_spec names are local:
+        # strip them
         states = []
-        for (s, pf), names in zip(ins, p.get("in_names", ()) or ()):
-            if s is not None and isinstance(names, dict):
-                manual_axes = {a for axs in names.values() for a in axs}
-                s = tuple(d - manual_axes for d in s)
+        for (s, pf), pspec in zip(ins, p["in_specs"]):
+            if s is not None:
+                named = set().union(*_spec_from_partition(pspec, len(s)))
+                s = tuple(d - named for d in s)
             states.append((s, pf))
-        while len(states) < len(inner.invars):
-            states.append((_empty_spec(0), False))
-        outs, _ = self._run_body(inner, consts,
-                                 states[:len(inner.invars)], inner_region)
+        outs, _ = self._run_body(inner, [], states, inner_region)
         res = []
         for j, v in enumerate(eqn.outvars):
-            names = None
-            out_names = p.get("out_names", ()) or ()
-            if j < len(out_names) and isinstance(out_names[j], dict):
-                names = out_names[j]
-            s = outs[j][0] if j < len(outs) else _empty_spec(_nd(v.aval))
-            if s is not None and names:
-                s = list(s if len(s) == _nd(v.aval)
-                         else _empty_spec(_nd(v.aval)))
-                for d, axs in names.items():
-                    if d < len(s):
-                        s[d] = s[d] | frozenset(axs)
-                s = tuple(s)
-            res.append((s, False))
+            nd = _nd(v.aval)
+            s = outs[j][0]
+            if s is None or len(s) != nd:
+                s = _empty_spec(nd)
+            named = _spec_from_partition(p["out_specs"][j], nd)
+            res.append((tuple(a | b for a, b in zip(s, named)), False))
         return res
 
     def _do_opaque_body(self, eqn, ins, region):

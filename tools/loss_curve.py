@@ -85,6 +85,9 @@ def main() -> None:
     ap.add_argument("--config", choices=sorted(CONFIGS), default="tiny")
     ap.add_argument("--out", required=True)
     args = ap.parse_args()
+    from paddle_tpu.core.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     res = run_curve(args.config)
     os.makedirs(os.path.dirname(args.out) or ".", exist_ok=True)
     with open(args.out, "w") as f:

@@ -15,31 +15,30 @@ Measures the hybrid-parallel (dp x pp x mp) train step against a serial
   ``dist_allreduce_quant`` off vs on — int8-wire gradient-sync
   throughput plus the measured loss delta after identical step counts.
 
-Mesh choice is deterministic per runtime: native partial-manual
-shard_map runtimes get the full dp=2·pp=2·mp=2; jax_compat-shimmed ones
-(where XLA CPU rejects the partial-manual pp lowering) get dp=4·pp=1·mp=2.
+Mesh: dp=n/4·pp=2·mp=2 when n is a multiple of 8, else dp=n/2·mp=2.
 
 Standalone: ``python tools/multichip_bench.py`` prints one JSON line of
-raw measurements. If the host has fewer than 2 devices it re-execs a
-child with an 8-fake-device CPU world (XLA_FLAGS must precede jax init).
-On-chip numbers come from bench.py calling ``measure()`` in-process.
+raw measurements from the devices this process has, and refuses with
+fewer than two: a multichip number is measured on the chips it names,
+never on virtual CPU devices beside them.  (A CPU rehearsal of the code
+path is ``XLA_FLAGS=--xla_force_host_platform_device_count=8
+JAX_PLATFORMS=cpu``, set by the caller.)  bench.py calls ``measure()``
+in-process.
 """
 
 from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
 _REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-N_DEV = 8
 _WINDOWS, _WIN_STEPS = 3, 2
 
 
-def _mesh_shape(n: int, native: bool) -> tuple[int, int, int]:
-    if native and n % 8 == 0:
+def _mesh_shape(n: int) -> tuple[int, int, int]:
+    if n % 8 == 0:
         return (n // 4, 2, 2)
     if n % 2 == 0:
         return (n // 2, 1, 2)
@@ -52,16 +51,17 @@ def measure() -> dict:
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
 
-    from paddle_tpu.core import jax_compat
     from paddle_tpu.core.flags import set_flags
     from paddle_tpu.distributed.process_mesh import build_mesh
     from paddle_tpu.models.gpt import GPTConfig
     from paddle_tpu.parallel import make_sharded_train_step
 
     n = len(jax.devices())
-    assert n >= 2, f"multichip bench needs >= 2 devices, have {n}"
-    native = "shard_map" not in jax_compat.PATCHED
-    dp, pp, mp = _mesh_shape(n, native)
+    if n < 2:
+        raise RuntimeError(
+            f"multichip bench needs >= 2 devices; this "
+            f"{jax.devices()[0].platform} host has {n}")
+    dp, pp, mp = _mesh_shape(n)
     n_micro = 2 if pp > 1 else 1
 
     cfg = GPTConfig(vocab_size=2048, hidden=128, n_layers=4, n_heads=4,
@@ -87,7 +87,7 @@ def measure() -> dict:
             t = step.put_batch(toks)
             l = step.put_batch(labs)
             loss, params, opt = step(params, opt, t, l)
-            float(loss)  # fetch = the reliable device sync (bench.py note)
+            float(loss)  # fetch = device sync
             best = float("inf")
             for _ in range(_WINDOWS):
                 t0 = time.perf_counter()
@@ -145,22 +145,11 @@ def measure() -> dict:
 
 
 def main(argv=None) -> int:
-    import jax
+    from paddle_tpu.core.compile_cache import enable_compile_cache
 
-    if len(jax.devices()) >= 2:
-        print(json.dumps(measure()), flush=True)
-        return 0
-
-    # 1-device host (CPU CI): re-exec with an 8-fake-device world — the
-    # flag must be in the environment before the child's jax initializes
-    env = dict(os.environ)
-    extra = f"--xla_force_host_platform_device_count={N_DEV}"
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " " + extra).strip()
-    env["JAX_PLATFORMS"] = "cpu"
-    proc = subprocess.run(
-        [sys.executable, os.path.abspath(__file__)],
-        env=env, cwd=_REPO, timeout=1800)
-    return proc.returncode
+    enable_compile_cache()
+    print(json.dumps(measure()), flush=True)
+    return 0
 
 
 if __name__ == "__main__":

@@ -6,11 +6,8 @@ Three contracts on an 8-fake-device CPU world
 1. **dryrun**: the full hybrid-parallel train step compiles and runs with
    serial-parity loss AND a clean SPMD log — any "Involuntary full
    rematerialization" line is a hard failure (__graft_entry__ pin,
-   MULTICHIP_r05 regression). Native partial-manual runtimes run the full
-   dp=2·pp=2·mp=2 mesh; on a jax_compat-shimmed runtime (0.4.x, where XLA
-   CPU rejects the partial-manual PartitionId lowering) it downgrades to
-   dp=4·pp=1·mp=2 and says so — the driver environment runs the real
-   thing.
+   embedding-gather regression PR 9 fixed) on the full dp=2·pp=2·mp=2
+   mesh.
 2. **quant**: a 2-step quantized-collective run on a dp=8 mesh:
    ``dist_allreduce_quant=0`` is bit-identical across independent builds,
    ``=1`` tracks the fp32 loss within the parity bound.
@@ -32,23 +29,10 @@ N_DEV = 8
 QUANT_REL_BOUND = 5e-3
 
 
-def _native_partial_manual() -> bool:
-    from paddle_tpu.core import jax_compat
-
-    return "shard_map" not in jax_compat.PATCHED
-
-
 def _part_dryrun() -> None:
     import __graft_entry__ as g
 
-    if _native_partial_manual():
-        shape = None          # _factor_mesh(8) -> the full (2, 2, 2)
-    else:
-        shape = (4, 1, 2)
-        print("multichip_smoke: shimmed shard_map runtime — downgrading "
-              "dryrun mesh to dp=4 pp=1 mp=2 (partial-manual pp is not "
-              "lowerable on XLA CPU here)", flush=True)
-    g._dryrun_impl(N_DEV, shape=shape)
+    g._dryrun_impl(N_DEV)     # _factor_mesh(8) -> the full (2, 2, 2)
 
 
 def _part_quant() -> None:

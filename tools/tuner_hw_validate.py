@@ -25,9 +25,11 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main() -> None:
     import jax
 
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.distributed.auto_tuner import (AutoTuner, Candidate,
                                                    TunerConfig)
 
+    enable_compile_cache()
     on_tpu = "tpu" in jax.devices()[0].platform.lower()
 
     tc = TunerConfig(n_devices=1, global_batch_size=16, hidden=1024,
