@@ -1034,10 +1034,9 @@ def _obs_keys(n_emitted: int, steps: int, plain_s: float,
 
 def _bench_obs():
     """Observability-plane overhead (ISSUE 19): the same serving run
-    with tracing disarmed then armed, identical engine/params/requests.
-    The disarmed fast path is one module-global load per probe, so the
-    frac should sit in measurement noise; events_per_step sizes the
-    armed ring against FLAGS_obs_buffer_events."""
+    after ``obs.disarm()`` (the control) then with the ring on, as it is
+    from import, identical engine/params/requests. events_per_step sizes
+    the ring against FLAGS_obs_buffer_events."""
     from paddle_tpu import obs
     from paddle_tpu.inference.serving import Request, ServingEngine
     from paddle_tpu.models.llama import LlamaConfig
@@ -1068,6 +1067,7 @@ def _bench_obs():
     obs.disarm()
     plain_s, _, _, params = run(armed=False)
     armed_s, steps, n_emitted, _ = run(armed=True, params=params)
+    obs.arm()                           # back to the process default
     return _obs_keys(n_emitted, steps, plain_s, armed_s)
 
 

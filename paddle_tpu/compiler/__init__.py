@@ -37,6 +37,7 @@ import numpy as np
 import jax
 from jax import tree_util
 
+from .. import obs as _obs
 from .fusion_pass import eval_fused, plan_closed, program_hash
 
 __all__ = ["auto_fuse", "fused_call", "discover", "last_report",
@@ -122,7 +123,8 @@ def auto_fuse(fn):
         flat, in_tree = tree_util.tree_flatten(tuple(args))
         key = _trace_key(flat, in_tree)
         state = cache.get(key)
-        if state is None:
+        planned = state is None
+        if planned:
             closed, out_tree, plan, phash = _plan_and_trace(
                 fn, flat, in_tree)
             state = {"closed": closed, "out_tree": out_tree, "plan": plan,
@@ -136,6 +138,15 @@ def auto_fuse(fn):
             state["warm"] = (not plan.empty()
                              and reg.adopt_program(phash, src))
         _LAST_REPORT = _report(plan, phash, state["warm"])
+        if planned and _LAST_REPORT.n_sites:
+            # once per plan computed, never on replay: the sites the
+            # catalog found in this program and how many it rewrote. A
+            # program in which it finds none is passed through unrecorded
+            # (the train step's outer wrapper: the model's own fused_call
+            # inside it has rewritten every site already).
+            _obs.instant("compiler.plan", n_sites=_LAST_REPORT.n_sites,
+                         n_applied=_LAST_REPORT.n_applied, phash=phash,
+                         warm=_LAST_REPORT.program_cache_hit)
         if plan.empty():
             return fn(*args)
         capturing = reg.begin_capture()

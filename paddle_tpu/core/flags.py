@@ -422,16 +422,11 @@ define_flag("resilient_retry_base_delay", 0.05,
             "Base backoff seconds for with_retries (exponential, "
             "full jitter).")
 
-define_flag("obs_trace", False,
-            "Arm the observability plane (paddle_tpu/obs): host-side "
-            "span tracing into a bounded ring, chaos-fault trace "
-            "annotation, and flight-recorder dumps on every death path. "
-            "Observation only — computed streams are bit-identical off "
-            "AND on; off (default) leaves one global load per probe.")
 define_flag("obs_buffer_events", 65536,
-            "Capacity of the per-process trace ring (events). The "
-            "flight recorder dumps whatever the ring holds, so this is "
-            "also the postmortem window length.")
+            "Capacity of the per-process trace ring (events), read when "
+            "paddle_tpu.obs is imported: the ring (paddle_tpu/obs) is "
+            "always on. The flight recorder dumps whatever the ring "
+            "holds, so this is also the postmortem window length.")
 define_flag("obs_dir", "artifacts",
             "Directory for observability artifacts: flight-recorder "
             "dumps (flightrec-*.json) and exported Chrome traces.")

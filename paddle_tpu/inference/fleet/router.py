@@ -298,9 +298,6 @@ class FleetRouter:
             "n_swap_deaths": 0, "rollout_ms": 0.0, "n_slo_shed": 0,
             "n_scale_up": 0, "n_scale_down": 0,
         }
-        # FLAGS_obs_trace=1 arms the observability plane from any entry
-        # point (the engines' constructors do the same)
-        _obs.arm_from_flags()
 
     # -- registration broadcast ------------------------------------------
 

@@ -114,6 +114,7 @@ class Request:
     n_preempted: int = 0               # KV evictions this request survived
 
 
+@jax.named_scope("sample")
 def _pick_tokens(logits, temps, topps, seeds, positions):
     """Next-token selection for a batch of rows, IN-program.
 
@@ -545,9 +546,6 @@ class ServingEngine:
             # wire cost the overlapped path shrinks to a buffer swap)
             "wire_export_ms": 0.0,
         }
-        # FLAGS_obs_trace=1 arms the observability plane from any entry
-        # point; default off = zero probes beyond one global load each
-        _obs.arm_from_flags()
 
     # -- compiled program ---------------------------------------------------
 
@@ -603,9 +601,10 @@ class ServingEngine:
         offs = (positions % self.bs).reshape(-1)
         pages = jnp.where(valid, jnp.take_along_axis(rows, blk, axis=1),
                           0).reshape(-1)             # padding -> sink
-        x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
-        cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
-        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
+            cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
+            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
         sm_scale = 1.0 / math.sqrt(dH)
 
         def body(carry, inp):
@@ -614,51 +613,58 @@ class ServingEngine:
                 bp, kp, vp, aq_l, bq_l, av_l, bv_l = inp
             else:
                 bp, kp, vp = inp
-            h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
-            q = _mm(h, bp["wq"], cfg)
-            k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
-            v = _mm(h, bp["wv"], cfg)
-            if self._lora_on:
-                # grouped BGMV: each packed row through ITS adapter's
-                # q/v low-rank delta (slot 0 = exact +0.0 identity)
-                q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
-                v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
-            q = q.reshape(C, qb, nH, dH)
-            v = v.reshape(C, qb, nKV, dH)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            kp = kp.at[pages, :, :, offs].set(
-                k.reshape(C * qb, nKV, dH).astype(kp.dtype))
-            vp = vp.at[pages, :, offs].set(
-                v.reshape(C * qb, nKV, dH).astype(vp.dtype))
-            o = ragged_paged_attention(q, kp, vp, rows, pos0, n_valid,
-                                       sm_scale, k_layout="d_major")
-            x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
-            h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
-            x = x + _mm(jax.nn.silu(
-                _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
-                    cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
+            with jax.named_scope("layer/qkv"):
+                h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
+                q = _mm(h, bp["wq"], cfg)
+                k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
+                v = _mm(h, bp["wv"], cfg)
+                if self._lora_on:
+                    # grouped BGMV: each packed row through ITS adapter's
+                    # q/v low-rank delta (slot 0 = exact +0.0 identity)
+                    q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
+                    v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
+                q = q.reshape(C, qb, nH, dH)
+                v = v.reshape(C, qb, nKV, dH)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+            with jax.named_scope("layer/kv_write"):
+                kp = kp.at[pages, :, :, offs].set(
+                    k.reshape(C * qb, nKV, dH).astype(kp.dtype))
+                vp = vp.at[pages, :, offs].set(
+                    v.reshape(C * qb, nKV, dH).astype(vp.dtype))
+            with jax.named_scope("layer/attn"):
+                o = ragged_paged_attention(q, kp, vp, rows, pos0, n_valid,
+                                           sm_scale, k_layout="d_major")
+                x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
+            with jax.named_scope("layer/mlp"):
+                h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
+                x = x + _mm(jax.nn.silu(
+                    _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
+                        cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
             return x, (kp, vp)
 
         xs = (params["blocks"], k_pages, v_pages)
         if self._lora_on:
             xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
         x, (ks, vs) = lax.scan(body, x, xs)
-        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         if self.spec_k:
             # speculative verify needs the model's pick at EVERY draft
             # position; keying on each input position keeps the accepted
             # stream identical to one-token-at-a-time decoding
-            logits = _mm(x, params["head"], cfg).astype(jnp.float32)
+            with jax.named_scope("head"):
+                logits = _mm(x, params["head"], cfg).astype(jnp.float32)
             picks = _pick_tokens(
                 logits.reshape(C * qb, -1), jnp.repeat(temps, qb),
                 jnp.repeat(topps, qb), jnp.repeat(seeds, qb),
                 positions.reshape(-1))
             out = picks.reshape(C, qb)
         else:
-            last = x[jnp.arange(C), n_valid - 1]     # [C, H]
-            logits = _mm(last[:, None], params["head"], cfg).astype(
-                jnp.float32)[:, 0]
+            with jax.named_scope("head"):
+                last = x[jnp.arange(C), n_valid - 1]     # [C, H]
+                logits = _mm(last[:, None], params["head"], cfg).astype(
+                    jnp.float32)[:, 0]
             if self._constr_on:
                 # constrained rows only see schema-legal logits;
                 # unconstrained rows carry an all-True mask, and
@@ -806,9 +812,10 @@ class ServingEngine:
             + jnp.arange(npw, dtype=jnp.int32)[None, :],
             0, self.max_blocks - 1)
         pages_rw = jnp.take_along_axis(rows, blk_rw, axis=1).reshape(-1)
-        x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
-        cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
-        cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
+            cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
+            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
         sm_scale = 1.0 / math.sqrt(dH)
 
         def body(carry, inp):
@@ -817,61 +824,68 @@ class ServingEngine:
                 bp, kp, vp, ksc, vsc, aq_l, bq_l, av_l, bv_l = inp
             else:
                 bp, kp, vp, ksc, vsc = inp
-            h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
-            q = _mm(h, bp["wq"], cfg)
-            k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
-            v = _mm(h, bp["wv"], cfg)
-            if self._lora_on:
-                q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
-                v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
-            q = q.reshape(C, qb, nH, dH)
-            v = v.reshape(C, qb, nKV, dH)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
-            kf = k.reshape(C * qb, nKV, dH).astype(jnp.float32)
-            vf = v.reshape(C * qb, nKV, dH).astype(jnp.float32)
-            ksc_new = kv_scale_update(
-                ksc, pages, jnp.max(jnp.abs(kf), axis=-1) / 127.0)
-            vsc_new = kv_scale_update(
-                vsc, pages, jnp.max(jnp.abs(vf), axis=-1) / 127.0)
-            kp = kp.at[pages_rw].set(rescale_int8(
-                kp[pages_rw],
-                jnp.take(ksc, pages_rw, axis=0)[:, :, None, None],
-                jnp.take(ksc_new, pages_rw, axis=0)[:, :, None, None]))
-            vp = vp.at[pages_rw].set(rescale_int8(
-                vp[pages_rw],
-                jnp.take(vsc, pages_rw, axis=0)[:, :, None, None],
-                jnp.take(vsc_new, pages_rw, axis=0)[:, :, None, None]))
-            kp = kp.at[pages, :, :, offs].set(quantize_to_scale(
-                kf, jnp.take(ksc_new, pages, axis=0)[:, :, None]))
-            vp = vp.at[pages, :, offs].set(quantize_to_scale(
-                vf, jnp.take(vsc_new, pages, axis=0)[:, :, None]))
-            o = ragged_paged_attention(q, kp, vp, rows, pos0, n_valid,
-                                       sm_scale, k_layout="d_major",
-                                       k_scales=ksc_new, v_scales=vsc_new)
-            x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
-            h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
-            x = x + _mm(jax.nn.silu(
-                _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
-                    cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
+            with jax.named_scope("layer/qkv"):
+                h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
+                q = _mm(h, bp["wq"], cfg)
+                k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
+                v = _mm(h, bp["wv"], cfg)
+                if self._lora_on:
+                    q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
+                    v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
+                q = q.reshape(C, qb, nH, dH)
+                v = v.reshape(C, qb, nKV, dH)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
+            with jax.named_scope("layer/kv_write"):
+                kf = k.reshape(C * qb, nKV, dH).astype(jnp.float32)
+                vf = v.reshape(C * qb, nKV, dH).astype(jnp.float32)
+                ksc_new = kv_scale_update(
+                    ksc, pages, jnp.max(jnp.abs(kf), axis=-1) / 127.0)
+                vsc_new = kv_scale_update(
+                    vsc, pages, jnp.max(jnp.abs(vf), axis=-1) / 127.0)
+                kp = kp.at[pages_rw].set(rescale_int8(
+                    kp[pages_rw],
+                    jnp.take(ksc, pages_rw, axis=0)[:, :, None, None],
+                    jnp.take(ksc_new, pages_rw, axis=0)[:, :, None, None]))
+                vp = vp.at[pages_rw].set(rescale_int8(
+                    vp[pages_rw],
+                    jnp.take(vsc, pages_rw, axis=0)[:, :, None, None],
+                    jnp.take(vsc_new, pages_rw, axis=0)[:, :, None, None]))
+                kp = kp.at[pages, :, :, offs].set(quantize_to_scale(
+                    kf, jnp.take(ksc_new, pages, axis=0)[:, :, None]))
+                vp = vp.at[pages, :, offs].set(quantize_to_scale(
+                    vf, jnp.take(vsc_new, pages, axis=0)[:, :, None]))
+            with jax.named_scope("layer/attn"):
+                o = ragged_paged_attention(q, kp, vp, rows, pos0, n_valid,
+                                           sm_scale, k_layout="d_major",
+                                           k_scales=ksc_new, v_scales=vsc_new)
+                x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
+            with jax.named_scope("layer/mlp"):
+                h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
+                x = x + _mm(jax.nn.silu(
+                    _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
+                        cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
             return x, (kp, vp, ksc_new, vsc_new)
 
         xs = (params["blocks"], k_pages, v_pages, k_scales, v_scales)
         if self._lora_on:
             xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
         x, (ks, vs, kss, vss) = lax.scan(body, x, xs)
-        x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        with jax.named_scope("head"):
+            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         if self.spec_k:
-            logits = _mm(x, params["head"], cfg).astype(jnp.float32)
+            with jax.named_scope("head"):
+                logits = _mm(x, params["head"], cfg).astype(jnp.float32)
             picks = _pick_tokens(
                 logits.reshape(C * qb, -1), jnp.repeat(temps, qb),
                 jnp.repeat(topps, qb), jnp.repeat(seeds, qb),
                 positions.reshape(-1))
             out = picks.reshape(C, qb)
         else:
-            last = x[jnp.arange(C), n_valid - 1]     # [C, H]
-            logits = _mm(last[:, None], params["head"], cfg).astype(
-                jnp.float32)[:, 0]
+            with jax.named_scope("head"):
+                last = x[jnp.arange(C), n_valid - 1]     # [C, H]
+                logits = _mm(last[:, None], params["head"], cfg).astype(
+                    jnp.float32)[:, 0]
             if self._constr_on:
                 logits = jnp.where(vmask, logits, -1e30)
             out = _pick_tokens(logits, temps, topps, seeds,
@@ -1284,42 +1298,31 @@ class ServingEngine:
         """
         if _chaos.active():               # disarmed: one global load,
             self._chaos_step()            # nothing else on the hot path
-        if _obs.active():                 # same pattern for the tracer
-            with _obs.span("engine.step", engine=self.engine_id):
-                return self._step_impl(now, traced=True)
-        return self._step_impl(now, traced=False)
+        with _obs.span("engine.step", engine=self.engine_id) as sp:
+            return self._step_impl(now, sp)
 
-    def _step_impl(self, now: Optional[float], traced: bool) -> bool:
+    def _step_impl(self, now: Optional[float], sp) -> bool:
         now = _clock.now() if now is None else now
-        if traced:
-            with _obs.span("engine.admit", engine=self.engine_id):
-                self._admit(now)
-        else:
+        with _obs.span("engine.admit", engine=self.engine_id):
             self._admit(now)
         prev = self._inflight
-        if traced:
-            with _obs.span("engine.dispatch", engine=self.engine_id):
-                self._dispatch_unified(now)
-        else:
+        with _obs.span("engine.dispatch", engine=self.engine_id):
             self._dispatch_unified(now)
-        if self.spec_k or self._constr_on:
-            # synchronous modes: drafts (spec) and vocab masks
-            # (constrained) are host state derived from the previous
-            # step's tokens, so each step harvests before the next
-            # dispatch (chaining is moot — nothing stays in flight)
-            if self._inflight is not None:
-                if traced:
-                    with _obs.span("engine.harvest",
-                                   engine=self.engine_id):
-                        self._harvest(self._inflight)
-                else:
-                    self._harvest(self._inflight)
-        elif prev is not None:
-            if traced:
-                with _obs.span("engine.harvest", engine=self.engine_id):
-                    self._harvest(prev)
-            else:
-                self._harvest(prev)
+        # what this tick put on the device, recorded on engine.step's
+        # end: decode and prefill rows of the grid, requests left waiting
+        rows = self._inflight[1] if self._inflight is not prev else ()
+        n_dec = sum(1 for r in rows if r[3] == "dec")
+        sp.set(rows_decode=n_dec, rows_prefill=len(rows) - n_dec,
+               queued=len(self.queue))
+        # synchronous modes (spec, constrained): drafts and vocab masks
+        # are host state derived from the previous step's tokens, so each
+        # step harvests before the next dispatch (chaining is moot —
+        # nothing stays in flight); pipelined: the step before this one
+        harvest = (self._inflight if self.spec_k or self._constr_on
+                   else prev)
+        if harvest is not None:
+            with _obs.span("engine.harvest", engine=self.engine_id):
+                self._harvest(harvest)
         if self.prefill_only:
             self._export_completed()
         if self._inflight is None and (self._deferred_free
@@ -1591,7 +1594,8 @@ class ServingEngine:
         the serving path) and apply them; release pages freed one cycle
         ago — no in-flight program can reference them anymore."""
         out_dev, snap = inflight
-        toks = np.asarray(out_dev)                   # [C, 1] or [C, qb]
+        with _obs.span("engine.harvest.wait", engine=self.engine_id):
+            toks = np.asarray(out_dev)               # [C, 1] or [C, qb]
         if self._inflight is not None and self._inflight[0] is out_dev:
             self._inflight = None
         self.pool.release(self._deferred_free)
@@ -2055,12 +2059,9 @@ class ServingEngine:
         shipment, staged = handle["shipment"], handle["staged"]
         idx = [j for j, _ in staged]
         pages = [p for _, p in staged]
-        if _obs.active():
-            with _obs.span("wire.commit", engine=self.engine_id,
-                           rid=shipment.get("rid"), pages=len(pages)):
-                return self._commit_adopt_impl(shipment, staged, idx,
-                                               pages)
-        return self._commit_adopt_impl(shipment, staged, idx, pages)
+        with _obs.span("wire.commit", engine=self.engine_id,
+                       rid=shipment.get("rid"), pages=len(pages)):
+            return self._commit_adopt_impl(shipment, staged, idx, pages)
 
     def _commit_adopt_impl(self, shipment: dict, staged: list,
                            idx: list, pages: list) -> int:

@@ -1,33 +1,40 @@
 """Fleet-wide observability plane: span tracing, metrics, flight
 recorder.
 
-Armed/disarmed follows the :mod:`paddle_tpu.testing.chaos` pattern: one
-module global, one load on the disarmed fast path, and **no effect on
-any computed stream** in either state — tracing observes host control
-flow only, never touches device programs, RNG or scheduling decisions,
-so serving/fleet outputs are pinned bit-identical with tracing off AND
-on.
+One tracer, always recording: the ring is armed when this module is
+imported, with ``obs_buffer_events`` capacity — a flight recorder that
+is off until somebody thinks to turn it on records no flight. Tracing
+observes host control flow only, never touches device programs, RNG or
+scheduling decisions, so serving/fleet outputs are pinned bit-identical
+with the ring on AND after ``disarm()``.
 
 Usage (host code)::
 
     from paddle_tpu import obs as _obs
 
-    # hot path: guard on active() exactly like chaos probes
-    if _obs.active():
-        with _obs.span("engine.step", engine=self.engine_id):
-            ...
-
-    # cold paths may call unconditionally: every helper no-ops when
-    # disarmed
+    with _obs.span("engine.step", engine=self.engine_id) as sp:
+        ...
+        sp.set(rows_decode=n)        # counts, recorded on the span's end
     _obs.lifecycle(req.rid, "first-token", engine=self.engine_id)
     _obs.flight_dump("engine-death", detail=rep.last_error)
 
-Arming: ``obs.arm()`` in tests/tools, or the ``obs_trace`` flag
-(``FLAGS_obs_trace=1``) picked up by ``arm_from_flags()`` from the
-engine/router/train-loop constructors. While armed, chaos faults that
-actually fire are annotated into the trace (instant events named
-``chaos.<point>``) and logged for the flight recorder through a chaos
-observer callback.
+Scope: coarse events only — per tick, per request, per train step, per
+compile. Nothing per token, per layer or per op goes into the ring.
+
+A span is also a ``jax.profiler.TraceAnnotation`` (obs/trace.py), so a
+profiler session shows the program's spans on the device's timeline.
+JAX's own phases arrive through ``jax.monitoring``: every trace,
+lowering and backend compile (cache loads included) is a ``jax.trace``
+/ ``jax.lower`` / ``jax.compile`` span with its duration and the
+function's name — a recompile is an event in the ring and in every
+flight dump.
+
+``obs.arm()`` / ``obs.disarm()`` are the programmatic switch: tests arm a
+ring of their own, and an on/off cost measurement disarms. Disarmed,
+every helper is a no-op behind one module-global load. While armed,
+chaos faults that actually fire are annotated into the trace (instant
+events named ``chaos.<point>``) and logged for the flight recorder
+through a chaos observer callback.
 
 Export: ``obs.export(path)`` writes Chrome trace-event JSON — open in
 Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.
@@ -35,7 +42,10 @@ Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.
 
 from __future__ import annotations
 
+import threading
 from typing import Optional
+
+import jax
 
 from paddle_tpu.core.flags import GLOBAL_FLAGS
 from paddle_tpu.testing import chaos as _chaos
@@ -45,9 +55,9 @@ from .metrics import (FLEET_STATS_SCHEMA, MetricsRegistry,
                       SERVING_STATS_SCHEMA, TRAIN_STATS_SCHEMA)
 from .trace import Tracer
 
-__all__ = ["active", "arm", "arm_from_flags", "disarm", "span",
+__all__ = ["active", "arm", "disarm", "span",
            "instant", "lifecycle", "flight_dump", "export", "tracer",
-           "registry", "clock", "Tracer", "MetricsRegistry",
+           "clock", "Tracer", "MetricsRegistry",
            "SERVING_STATS_SCHEMA", "FLEET_STATS_SCHEMA",
            "TRAIN_STATS_SCHEMA"]
 
@@ -56,6 +66,9 @@ class _NoopSpan:
     """Shared reusable ``with`` guard for the disarmed path."""
 
     __slots__ = ()
+
+    def set(self, **attrs) -> None:
+        pass
 
     def __enter__(self):
         return self
@@ -70,11 +83,10 @@ _NOOP = _NoopSpan()
 class _ObsState:
     """Everything one armed session owns."""
 
-    def __init__(self, capacity: int, dump_dir: str):
+    def __init__(self, capacity: int, dump_dir: Optional[str]):
         self.tracer = Tracer(capacity)
-        self.registry = MetricsRegistry()
         self.faults: list = []          # chaos specs that actually fired
-        self.dump_dir = dump_dir
+        self.dump_dir = dump_dir        # None: the obs_dir flag, at dump
         self.dumps: list = []           # flightrec paths written
 
 
@@ -112,12 +124,10 @@ def active() -> bool:
 
 def arm(capacity: Optional[int] = None,
         dump_dir: Optional[str] = None) -> _ObsState:
-    """Activate tracing process-wide (replaces any armed session)."""
+    """Start a fresh ring process-wide (replaces the armed session)."""
     global _armed
     if capacity is None:
         capacity = int(GLOBAL_FLAGS.get("obs_buffer_events"))
-    if dump_dir is None:
-        dump_dir = str(GLOBAL_FLAGS.get("obs_dir"))
     _armed = _ObsState(capacity, dump_dir)
     _chaos.add_observer(_on_chaos_fire)
     return _armed
@@ -129,26 +139,13 @@ def disarm() -> None:
     _chaos.remove_observer(_on_chaos_fire)
 
 
-def arm_from_flags() -> bool:
-    """Arm iff the ``obs_trace`` flag is set (the constructors of
-    ServingEngine / FleetRouter / ResilientTrainLoop call this, so
-    ``FLAGS_obs_trace=1`` traces any entry point without code changes).
-    Idempotent; returns whether tracing is armed afterwards."""
-    if _armed is not None:
-        return True
-    if not GLOBAL_FLAGS.get("obs_trace"):
-        return False
-    arm(capacity=int(GLOBAL_FLAGS.get("obs_buffer_events")),
-        dump_dir=str(GLOBAL_FLAGS.get("obs_dir")))
-    return True
-
-
 # -- recording ---------------------------------------------------------------
 
 def span(name: str, engine=None, **attrs):
-    """``with obs.span("engine.step", engine=0):`` — a no-op shared
-    guard when disarmed (one global load), a B/E pair on the engine's
-    track when armed."""
+    """``with obs.span("engine.step", engine=0) as sp:`` — a B/E pair on
+    the engine's track and a profiler annotation; ``sp.set(k=v)`` puts
+    counts on the E event. A no-op shared guard when disarmed (one global
+    load)."""
     st = _armed
     if st is None:
         return _NOOP
@@ -185,18 +182,56 @@ def lifecycle(rid: int, event: str, engine=None, **attrs) -> None:
                           tid=_tid(engine), attrs=attrs)
 
 
+# JAX's compile phases, by the names of the installed JAX 0.9.0
+# (jax/_src/dispatch.py); each arrives with ``fun_name``.
+_JAX_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+
+
+_phase_depth = threading.local()
+
+
+def _on_jax_phase_start(event: str, value, **kw) -> None:
+    """JAX announces a phase's start as a scalar under the phase's name;
+    counting them tells the outermost phase from those inside it."""
+    if event in _JAX_PHASES:
+        _phase_depth.n = getattr(_phase_depth, "n", 0) + 1
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kw) -> None:
+    """Only the outermost phase of a thread is recorded: tracing one step
+    traces a jitted ``add`` or ``where`` a thousand times inside it, and
+    nothing per op goes into the ring. So the seconds of one name add up
+    without counting any twice."""
+    name = _JAX_PHASES.get(event)
+    if name is None:
+        return
+    depth = _phase_depth.n = max(0, getattr(_phase_depth, "n", 1) - 1)
+    st = _armed
+    if depth or st is None:
+        return
+    fun = kw.get("fun_name")
+    st.tracer.complete(name, duration_secs,
+                       attrs=None if fun is None else {"fun_name": str(fun)})
+
+
 # -- artifacts ---------------------------------------------------------------
 
 def flight_dump(reason: str, detail: Optional[str] = None) -> Optional[str]:
-    """Dump the ring on a death path; returns the flightrec path, or
-    None when disarmed."""
+    """Dump the ring on a death path, into the session's ``dump_dir`` or
+    where the ``obs_dir`` flag says; returns the flightrec path, or None
+    when disarmed."""
     st = _armed
     if st is None:
         return None
     st.tracer.instant("flightrec.dump", attrs={"reason": reason})
     path = _flight.dump(st.tracer, reason, detail=detail,
-                        faults=st.faults, registry=st.registry,
-                        dump_dir=st.dump_dir)
+                        faults=st.faults,
+                        dump_dir=(st.dump_dir
+                                  or str(GLOBAL_FLAGS.get("obs_dir"))))
     st.dumps.append(path)
     return path
 
@@ -214,5 +249,8 @@ def tracer() -> Optional[Tracer]:
     return _armed.tracer if _armed is not None else None
 
 
-def registry() -> Optional[MetricsRegistry]:
-    return _armed.registry if _armed is not None else None
+# Registered once and for the life of the process: a listener is one
+# dict lookup per JAX event and records nothing while disarmed.
+jax.monitoring.register_scalar_listener(_on_jax_phase_start)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+arm()

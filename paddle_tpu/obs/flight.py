@@ -1,15 +1,14 @@
-"""Flight recorder: dump the tracer ring + fault log + metrics snapshot
-on a death path.
+"""Flight recorder: dump the tracer ring + fault log on a death path.
 
 Every terminal event the fleet already survives — engine fail/hang,
 pool death, rollout swap-death, canary rollback, watchdog escalation,
 NaN rollback — calls :func:`paddle_tpu.obs.flight_dump`, which lands
 here: one ``artifacts/flightrec-<seq>-<reason>.json`` per death holding
-the last N trace events (the tracer ring IS the flight ring), every
+the last N trace events (the tracer ring IS the flight ring) and every
 chaos fault that actually fired (so a chaos-CI failure ships its own
-postmortem naming the injected fault), and a metrics snapshot. The dump
-is append-only evidence: it never consumes the ring, so several deaths
-in one run produce several overlapping dumps.
+postmortem naming the injected fault). The dump is append-only
+evidence: it never consumes the ring, so several deaths in one run
+produce several overlapping dumps.
 """
 
 from __future__ import annotations
@@ -30,7 +29,7 @@ def _slug(reason: str) -> str:
 
 
 def dump(tracer, reason: str, detail: Optional[str] = None,
-         faults: Optional[list] = None, registry=None,
+         faults: Optional[list] = None,
          dump_dir: str = "artifacts") -> str:
     """Write one flight-recorder JSON; returns its path."""
     os.makedirs(dump_dir, exist_ok=True)
@@ -39,7 +38,6 @@ def dump(tracer, reason: str, detail: Optional[str] = None,
         "reason": reason,
         "detail": detail,
         "faults": [dict(f) for f in (faults or [])],
-        "metrics": registry.snapshot() if registry is not None else {},
         "trace": (tracer.export() if tracer is not None
                   else {"traceEvents": []}),
     }

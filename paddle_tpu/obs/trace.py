@@ -7,7 +7,8 @@ ring: the last N events survive, older ones fall off). Export produces
 the Chrome trace-event JSON object format (``{"traceEvents": [...]}``),
 loadable in Perfetto / ``chrome://tracing``:
 
-- duration spans: ``ph "B"`` / ``ph "E"`` pairs per track;
+- duration spans: ``ph "B"`` / ``ph "E"`` pairs per track, or one
+  ``ph "X"`` with ``dur`` for a span reported after the fact;
 - instants: ``ph "i"`` (thread-scoped);
 - request lifecycle: async ``ph "b"`` (arrival) / ``"n"`` (admit,
   first-token, preempt, migrate, ship, adopt) / ``"e"`` (done) events
@@ -17,7 +18,18 @@ loadable in Perfetto / ``chrome://tracing``:
   engine (track 0 is the host/fleet track).
 
 Timestamps are microseconds on :mod:`paddle_tpu.obs.clock` relative to
-the tracer's construction. Export never mutates the ring: truncated
+the tracer's construction: ``tracer.t0 + ev["ts"] * 1e-6`` is an event's
+time on ``time.perf_counter()``, the axis every driver loop and the
+benchmark harness stamp on, so a reader can cut the ring to a window it
+timed itself.
+
+The shared clock with the device: a span also opens a
+``jax.profiler.TraceAnnotation`` of the same name (free while no
+profiler session runs), so whenever anyone profiles, the program's spans
+lie in the xplane's host plane beside the device ops. This is the one
+place in ``paddle_tpu/`` that emits annotations.
+
+Export never mutates the ring: truncated
 spans (a ``B`` whose ``E`` fell outside the ring or has not happened
 yet) are closed with synthetic ``E``/``e`` events carrying
 ``args.truncated`` so the JSON always balances.
@@ -30,15 +42,20 @@ import threading
 from collections import deque
 from typing import Optional
 
+import jax
+
 from . import clock
 
 __all__ = ["Tracer"]
 
 
 class _Span:
-    """Reusable ``with`` guard emitting one B/E pair on a tracer."""
+    """``with`` guard emitting one B/E pair on a tracer and the same
+    interval as a profiler annotation. ``set(**attrs)`` inside the block
+    records counts on the E event: at the boundary where the work
+    happened, when its size is known."""
 
-    __slots__ = ("_tr", "_name", "_tid", "_attrs")
+    __slots__ = ("_tr", "_name", "_tid", "_attrs", "_end_attrs", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, tid: int,
                  attrs: Optional[dict]):
@@ -46,14 +63,22 @@ class _Span:
         self._name = name
         self._tid = tid
         self._attrs = attrs
+        self._end_attrs = None
+        self._ann = jax.profiler.TraceAnnotation(name)
+
+    def set(self, **attrs) -> None:
+        self._end_attrs = attrs
 
     def __enter__(self) -> "_Span":
+        self._ann.__enter__()
         self._tr.begin(self._name, tid=self._tid, attrs=self._attrs)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._tr.end(self._name, tid=self._tid,
-                     error=None if exc_type is None else exc_type.__name__)
+                     error=None if exc_type is None else exc_type.__name__,
+                     attrs=self._end_attrs)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -63,7 +88,11 @@ class Tracer:
     def __init__(self, capacity: int = 65536):
         self.capacity = int(capacity)
         self.events: deque = deque(maxlen=self.capacity)
+        #: ``time.perf_counter()`` at construction; every ``ts`` is
+        #: microseconds after it
         self.t0 = clock.now()
+        #: events ever emitted; more than ``capacity`` means the oldest
+        #: have left the ring
         self.n_emitted = 0
         self._lock = threading.Lock()
 
@@ -85,12 +114,25 @@ class Tracer:
             ev["args"] = dict(attrs)
         self._emit(ev)
 
-    def end(self, name: str, tid: int = 0,
-            error: Optional[str] = None) -> None:
+    def end(self, name: str, tid: int = 0, error: Optional[str] = None,
+            attrs: Optional[dict] = None) -> None:
         ev = {"name": name, "ph": "E", "ts": self._ts(), "pid": 0,
               "tid": tid}
+        if attrs:
+            ev["args"] = dict(attrs)
         if error is not None:
-            ev["args"] = {"error": error}
+            ev.setdefault("args", {})["error"] = error
+        self._emit(ev)
+
+    def complete(self, name: str, dur_s: float, tid: int = 0,
+                 attrs: Optional[dict] = None) -> None:
+        """A span reported once it is over (``ph "X"``): ends now, began
+        ``dur_s`` seconds ago."""
+        dur = dur_s * 1e6
+        ev = {"name": name, "ph": "X", "ts": self._ts() - dur, "dur": dur,
+              "pid": 0, "tid": tid}
+        if attrs:
+            ev["args"] = dict(attrs)
         self._emit(ev)
 
     def span(self, name: str, tid: int = 0,
@@ -117,6 +159,11 @@ class Tracer:
         if attrs:
             ev["args"] = dict(attrs)
         self._emit(ev)
+
+    def snapshot(self) -> tuple:
+        """(events oldest first, n_emitted), taken under the lock."""
+        with self._lock:
+            return list(self.events), self.n_emitted
 
     # -- export -----------------------------------------------------------
 
@@ -173,13 +220,12 @@ class Tracer:
     def export(self, path: Optional[str] = None) -> dict:
         """The Chrome trace-event object; written to ``path`` as JSON
         when given. Does not consume or mutate the ring."""
-        with self._lock:
-            evs = [dict(e) for e in self.events]
-        evs = self._balanced(evs)
+        evs, n_emitted = self.snapshot()
+        evs = self._balanced([dict(e) for e in evs])
         tids = {e.get("tid", 0) for e in evs}
         doc = {"traceEvents": self._metadata(tids) + evs,
                "displayTimeUnit": "ms",
-               "otherData": {"n_emitted": self.n_emitted,
+               "otherData": {"n_emitted": n_emitted,
                              "capacity": self.capacity}}
         if path is not None:
             with open(path, "w") as f:

@@ -173,9 +173,6 @@ class ResilientTrainLoop:
         self.bad_streak = 0
         self.stats = {"skipped": 0, "rollbacks": 0, "hangs": 0,
                       "io_retries": 0}
-        # FLAGS_obs_trace=1 arms the observability plane on the train
-        # side too (train.step / ckpt.save spans, death-path dumps)
-        _obs.arm_from_flags()
 
     # -- recovery ---------------------------------------------------------
     def resume(self) -> Optional[int]:
@@ -289,7 +286,7 @@ class ResilientTrainLoop:
             # through the launcher's death watch / stale heartbeat lease
             os._exit(int(fault.args.get("code", 1)))
         with self.watchdog.guard(f"step{self.step}"):
-            with _obs.span("train.step", step=self.step):
+            with _obs.span("train.guarded_step", step=self.step):
                 if fault is not None and fault.kind == "hang":
                     time.sleep(float(fault.args.get("seconds", 1.0)))
                 loss, new_state = self.step_fn(self.state, batch)

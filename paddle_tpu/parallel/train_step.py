@@ -30,6 +30,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from .. import obs as _obs
 from ..models.gpt import GPTConfig, block_apply, init_params, loss_fn
 from .pipeline import pipeline_blocks_fn
 
@@ -458,7 +459,9 @@ def make_sharded_train_step(cfg: GPTConfig, mesh: Mesh, lr: float = 1e-4,
 
         def body(p, tok, lab):
             def lf_local(pl):
-                return loss_fn(pl, tok, lab, cfg, sp_constraint=sp_local)
+                with jax.named_scope("fwd"):
+                    return loss_fn(pl, tok, lab, cfg,
+                                   sp_constraint=sp_local)
 
             loss, grads = jax.value_and_grad(lf_local)(p)
             grads = jax.tree.map(
@@ -498,11 +501,14 @@ def make_sharded_train_step(cfg: GPTConfig, mesh: Mesh, lr: float = 1e-4,
             labels = _constrain(labels, P("dp"))
 
         def lf(p):
-            return loss_fn(p, tokens, labels, cfg, sp_constraint=sp,
-                           emb_constraint=emb,
-                           blocks_fn=(functools.partial(_run_blocks,
-                                                        blocks_fn)
-                                      if blocks_fn else None))
+            # device-side scopes (an op's op_name in XProf / Perfetto);
+            # the backward inherits transpose(jvp(fwd))
+            with jax.named_scope("fwd"):
+                return loss_fn(p, tokens, labels, cfg, sp_constraint=sp,
+                               emb_constraint=emb,
+                               blocks_fn=(functools.partial(_run_blocks,
+                                                            blocks_fn)
+                                          if blocks_fn else None))
 
         if use_quant_sync:
             loss, grads = _quant_sync_grads(params, tokens, labels)
@@ -519,10 +525,10 @@ def make_sharded_train_step(cfg: GPTConfig, mesh: Mesh, lr: float = 1e-4,
             grads = jax.tree.map(lambda g, s: _constrain(g, s),
                                  grads, grad_specs,
                                  is_leaf=lambda x: isinstance(x, P))
-        new_params, new_state = adamw_update(params, grads, opt_state, lr,
-                                             m_dtype=m_dtype,
-                                             v_dtype=v_dtype,
-                                             stochastic_round=sr)
+        with jax.named_scope("adamw"):
+            new_params, new_state = adamw_update(
+                params, grads, opt_state, lr, m_dtype=m_dtype,
+                v_dtype=v_dtype, stochastic_round=sr)
         return loss, new_params, new_state
 
     def _run_blocks(fn, bp, x):
@@ -553,8 +559,10 @@ def make_sharded_train_step(cfg: GPTConfig, mesh: Mesh, lr: float = 1e-4,
             tokens = put_batch(tokens)
         if not isinstance(labels, jax.Array):
             labels = put_batch(labels)
-        # context mesh for the partial-manual pipeline shard_map
-        with jax.sharding.set_mesh(mesh):
+        # context mesh for the partial-manual pipeline shard_map; the
+        # span is the host's time to dispatch one step (the call returns
+        # before the device finishes)
+        with jax.sharding.set_mesh(mesh), _obs.span("train.step"):
             return jitted(params, opt_state, tokens, labels)
 
     step_fn.put_batch = put_batch

@@ -4,16 +4,12 @@ Re-design of python/paddle/profiler (profiler.py:358 Profiler with
 CLOSED/READY/RECORD scheduler states :89, RecordEvent spans,
 chrometracing_logger.h Chrome export). TPU translation: the device-side
 tracer is the XLA/jax profiler (TensorBoard/perfetto trace, which subsumes
-the CUPTI tracer + chrome-trace logger); RecordEvent maps to
-jax.profiler.TraceAnnotation so user spans appear inside the device trace;
-host-side per-op stats ride the dispatch funnel hook (the host_tracer.h
-role).
-
-When the observability plane is armed (FLAGS_obs_trace=1 or
-``obs.arm()``), RecordEvent spans also land in the shared obs tracer
-ring, so profiler user-spans and engine/fleet spans interleave in one
-Chrome trace; ``export_chrome_tracing`` then writes that trace next to
-the host summary.
+the CUPTI tracer + chrome-trace logger); RecordEvent is a front over
+``obs.span``, the one place that emits profiler annotations, so user
+spans appear inside the device trace and in the obs ring beside the
+engine/fleet spans; host-side per-op stats ride the dispatch funnel hook
+(the host_tracer.h role). ``export_chrome_tracing`` writes the ring's
+Chrome trace next to the host summary.
 """
 
 from __future__ import annotations
@@ -73,34 +69,26 @@ def make_scheduler(closed: int = 0, ready: int = 0, record: int = 1,
 
 
 class RecordEvent:
-    """User span; appears in the device trace (TraceAnnotation) and in the
-    host op-summary (reference: paddle.profiler.RecordEvent)."""
+    """User span: an ``obs.span`` (the ring and the device trace's host
+    plane) counted in the host op-summary (reference:
+    paddle.profiler.RecordEvent)."""
 
     def __init__(self, name: str, event_type=None):
         self.name = name
-        self._ann = None
+        self._span = None
         self._t0 = None
-        self._obs_open = False
 
     def begin(self):
-        self._ann = jax.profiler.TraceAnnotation(self.name)
-        self._ann.__enter__()
+        self._span = _obs.span(self.name, src="profiler")
+        self._span.__enter__()
         self._t0 = _clock.now()
         _HOST_EVENTS[self.name]["count"] += 1
-        if _obs.active():
-            _obs.tracer().begin(self.name, attrs={"src": "profiler"})
-            self._obs_open = True
 
     def end(self):
-        if self._ann is not None:
+        if self._span is not None:
             _HOST_EVENTS[self.name]["total_s"] += _clock.now() - self._t0
-            self._ann.__exit__(None, None, None)
-            self._ann = None
-        if self._obs_open:
-            self._obs_open = False
-            tr = _obs.tracer()
-            if tr is not None:      # obs may have disarmed mid-span
-                tr.end(self.name)
+            self._span.__exit__(None, None, None)
+            self._span = None
 
     def __enter__(self):
         self.begin()
@@ -243,10 +231,9 @@ def export_chrome_tracing(dir_name: str, worker_name: Optional[str] = None):
 
         os.makedirs(dir_name, exist_ok=True)
         prof.export(os.path.join(dir_name, "host_summary.txt"))
-        if _obs.active():
-            # the shared obs ring (RecordEvent spans included) as Chrome
-            # trace-event JSON, next to the host summary
-            _obs.export(os.path.join(dir_name, "obs_trace.json"))
+        # the shared obs ring (RecordEvent spans included) as Chrome
+        # trace-event JSON, next to the host summary; nothing when disarmed
+        _obs.export(os.path.join(dir_name, "obs_ring.json"))
 
     return handler
 

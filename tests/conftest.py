@@ -35,6 +35,17 @@ import pytest
 jax.config.update("jax_default_matmul_precision", "highest")
 
 
+@pytest.fixture(scope="session", autouse=True)
+def _flight_dumps_out_of_the_tree(tmp_path_factory):
+    """The obs ring is on from import, so every death path a chaos test
+    walks writes a flight record: send them to a temporary directory, not
+    to artifacts/ in the checkout."""
+    from paddle_tpu.core.flags import GLOBAL_FLAGS
+
+    GLOBAL_FLAGS.set("obs_dir", str(tmp_path_factory.mktemp("flightrec")))
+    yield
+
+
 @pytest.fixture(autouse=True)
 def _seed_everything():
     import paddle_tpu

@@ -1,10 +1,12 @@
-"""Observability plane (PR 19): span tracer + Chrome export, typed
-metrics registry, flight recorder, and the zero-cost disarmed contract.
+"""Observability plane (PR 19; always on since PR 27): span tracer +
+Chrome export, typed metrics registry, flight recorder, and the contract
+that recording changes nothing.
 
-The headline property mirrors the chaos harness: with tracing DISARMED
-(the default) the serving fast path performs one module-global load and
-nothing else, so token streams are bit-identical with tracing off AND
-on — tracing observes host control flow, never steers it.
+The ring is armed when ``paddle_tpu.obs`` is imported; ``obs.arm()`` gives
+a test a fresh ring of its own and ``obs.disarm()`` is the control. The
+headline property mirrors the chaos harness: token streams are
+bit-identical with the ring on AND after ``disarm()`` — tracing observes
+host control flow, never steers it.
 """
 
 import glob
@@ -33,10 +35,13 @@ EKW = dict(max_batch=2, page_size=16, max_seq=128, n_pages=1 + 24,
 
 
 @pytest.fixture(autouse=True)
-def _disarm_all():
+def _fresh_ring():
+    """Every test starts from the process default (a ring that is on) and
+    leaves it so for the tests after it."""
+    assert obs.active()
     yield
     chaos.disarm()
-    obs.disarm()
+    obs.arm()
 
 
 def _mk_reqs(rng, n=4, max_new=10, sampled=()):
@@ -82,9 +87,10 @@ def _assert_chrome_valid(doc):
 
 def test_span_nesting_attrs_and_error_tagging():
     tr = Tracer(capacity=128)
-    with tr.span("outer", tid=1, attrs={"k": 1}):
+    with tr.span("outer", tid=1, attrs={"k": 1}) as sp:
         with tr.span("inner", tid=1):
             tr.instant("tick", tid=1, attrs={"n": 2})
+        sp.set(rows=3)            # counts land on the span's end
     with pytest.raises(RuntimeError):
         with tr.span("boom", tid=0):
             raise RuntimeError("x")
@@ -94,6 +100,7 @@ def test_span_nesting_attrs_and_error_tagging():
         ("outer", "E"), ("boom", "B"), ("boom", "E")]
     assert evs[0]["args"] == {"k": 1}
     assert evs[2]["args"] == {"n": 2} and evs[2]["s"] == "t"
+    assert evs[4]["args"] == {"rows": 3} and "args" not in evs[3]
     assert evs[-1]["args"] == {"error": "RuntimeError"}
     ts = [e["ts"] for e in evs]
     assert ts == sorted(ts) and all(t >= 0 for t in ts)
@@ -110,6 +117,7 @@ def test_export_balances_truncated_and_overflowed_ring(tmp_path):
     tr.end("lost")            # orphan E: its B left the ring
     tr.begin("open")          # never ended: synthetic closer
     tr.async_event("req", 7, "b")
+    tr.complete("after-the-fact", 0.25)   # ph X: passes through whole
     doc = tr.export(path=str(tmp_path / "t.json"))
     _assert_chrome_valid(doc)
     evs = doc["traceEvents"]
@@ -117,7 +125,11 @@ def test_export_balances_truncated_and_overflowed_ring(tmp_path):
     closers = [e for e in evs if e.get("args", {}).get("truncated")]
     assert {(e["name"], e["ph"]) for e in closers} == {("open", "E"),
                                                        ("req", "e")}
-    assert doc["otherData"]["n_emitted"] == 8
+    x = [e for e in evs if e["ph"] == "X"]
+    assert len(x) == 1 and x[0]["dur"] == pytest.approx(0.25e6)
+    assert doc["otherData"]["n_emitted"] == 9
+    events, n = tr.snapshot()
+    assert n == 9 and len(events) == 4 == tr.capacity
     on_disk = json.load(open(tmp_path / "t.json"))
     assert on_disk["traceEvents"] == evs
 

@@ -113,7 +113,11 @@ def main() -> int:
                prefill_budget=32)
 
     # -- pass 1: ARMED, chaos kill ------------------------------------------
-    st = obs.arm(capacity=16384, dump_dir="artifacts")
+    if not obs.active():
+        print("obs_smoke: FAIL — the ring is not on at import",
+              file=sys.stderr)
+        return 1
+    st = obs.arm(capacity=16384, dump_dir="artifacts")   # a fresh ring
     armed_reqs, router = _run_fleet(cfg, ekw, kill=True)
     bad = [r.rid for r in armed_reqs if r.aborted or r.t_done is None
            or len(r.out_tokens) != r.max_new_tokens]
