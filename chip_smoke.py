@@ -58,6 +58,7 @@ from paddle_tpu.ops.pallas import fused_bias_act as BA
 from paddle_tpu.ops.pallas import fused_ce as CE
 from paddle_tpu.ops.pallas import fused_norm_epilogue as NE
 from paddle_tpu.ops.pallas import fused_rope_attention as RA
+from paddle_tpu.ops.pallas import paged_kv_write as KVW
 from paddle_tpu.ops.pallas import ragged_paged_attention as RPA
 from paddle_tpu.parallel import make_sharded_train_step
 
@@ -392,8 +393,9 @@ def train_leg(leg: TrainLeg) -> dict:
 def _serve_kernel_parity(leg: ServeLeg, engine: ServingEngine,
                          t_ref: int) -> dict:
     """Each kernel of the Llama paths vs its XLA reference at the leg's
-    shapes: the engine's ragged-paged attention, and llama_apply's
-    rms epilogue, swiglu and rope+flash attention."""
+    shapes: the engine's ragged-paged attention and its write into the
+    pages, and llama_apply's rms epilogue, swiglu and rope+flash
+    attention."""
     cfg = leg.model
     checks: dict = {}
     H, nH, nKV, dH = cfg.hidden, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
@@ -415,6 +417,20 @@ def _serve_kernel_parity(leg: ServeLeg, engine: ServingEngine,
         RPA.ragged_paged_attention_kernel(q, kp, vp, rows, pos0, n_valid, sm),
         jax.jit(lambda *o: RPA._ragged_paged_xla(*o, sm, "d_major"))(
             q, kp, vp, rows, pos0, n_valid), TOL_BF16)
+
+    # the write into the pages, on as many chunks of that grid as can own
+    # the two pages a chunk may touch (the kernel's contract: one writer
+    # a page); the arms agree exactly on every page but the sink
+    W = min(C, (P - 1) // 2)
+    own = 1 + 2 * np.arange(W)[:, None] + (np.arange(mb)[None, :]
+                                            - pos0[:W, None] // bs) % 2
+    k_new, v_new = rnd((W, qb, nKV, dH)), rnd((W, qb, nKV, dH))
+    got = KVW.paged_kv_write_kernel(kp, vp, k_new, v_new, own, pos0[:W],
+                                    n_valid[:W])
+    want = jax.jit(KVW._paged_kv_write_xla)(kp, vp, k_new, v_new, own,
+                                            pos0[:W], n_valid[:W], 0)
+    check_close(checks, "paged_kv_write_k", got[0][1:], want[0][1:], 0.0)
+    check_close(checks, "paged_kv_write_v", got[1][1:], want[1][1:], 0.0)
 
     # rms epilogue (ffn_norm's shape: residual + norm)
     a, sub = rnd((1, t_ref, H)), rnd((1, t_ref, H))
@@ -504,7 +520,8 @@ def serve_leg(leg: ServeLeg) -> dict:
 
     facts["kernels"] = kernels_in(
         engine.lower_unified().compile().as_text())
-    _check_kernels_present(facts["kernels"], {"ragged_paged_attention"})
+    _check_kernels_present(facts["kernels"], {"ragged_paged_attention",
+                                              "paged_kv_write"})
 
     # the engine against the dense model: llama_apply (the fused Pallas
     # forward: 3 rms epilogues, rope+flash attention, swiglu) scores the

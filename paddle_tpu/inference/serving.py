@@ -171,6 +171,36 @@ def wire_scatter_pages(pages, pg, payload):
     return pages.at[:, pg].set(payload)
 
 
+def _scan_layers(body, x, k_pages, v_pages, xs):
+    """The unified steps' layer loop, with the KV pool as a loop CARRY.
+
+    The addressing rule, stated once: outside the program the pool is
+    ``[L, P, ...]``; inside it is ``[L*P, ...]`` (a reshape of the two
+    leading dims) and layer ``l`` finds page ``p`` at ``l*P + p``, so
+    the sink — page 0, where padding writes — is page ``l*P`` of each
+    layer. A ``lax.scan`` reads its ``xs`` and stacks fresh ``ys``, so a
+    pool scanned that way can never be updated where it lies; the carry
+    can, and the donated input buffer becomes the output.
+
+    ``body(x, k_pool, v_pool, base, layer_xs) -> (x, k_pool, v_pool, ys)``
+    sees the flattened pools and ``base = l*P`` to add to every page id
+    it scatters to or attends over. Returns ``(x, k_pages, v_pages, ys)``
+    with the pools back in their ``[L, P, ...]`` shape."""
+    L, P = k_pages.shape[:2]
+
+    def step(carry, inp):
+        l, layer_xs = inp
+        x, kp, vp, ys = body(*carry, l * P, layer_xs)
+        return (x, kp, vp), ys
+
+    (x, kp, vp), ys = lax.scan(
+        step,
+        (x, k_pages.reshape((L * P,) + k_pages.shape[2:]),
+         v_pages.reshape((L * P,) + v_pages.shape[2:])),
+        (jnp.arange(L, dtype=jnp.int32), xs))
+    return x, kp.reshape(k_pages.shape), vp.reshape(v_pages.shape), ys
+
+
 def kv_scale_reset(scales, page_ids, axis: int = 0):
     """Zero the scale-plane entries of freshly allocated pages — the
     PR 8 fix: a reused page's stale running-absmax would quantize the
@@ -449,6 +479,9 @@ class ServingEngine:
         # per-head fp32 scale plane per layer — KV bytes per token drop
         # from 2*itemsize*nKV*dH to 2*nKV*dH (+ amortized scales), so a
         # fixed-byte pool holds ~2x the sequences (kv_bytes_per_token()).
+        # The pool is [L, P, ...] everywhere outside the step program;
+        # inside, layer l finds page p at l*P + p (_scan_layers), and
+        # page 0 of each layer is its sink.
         page_dtype = jnp.int8 if self._kv_quant else cfg.dtype
         self.k_pages = jnp.zeros((L, self.n_pages, nKV, d, self.bs),
                                  page_dtype)
@@ -573,12 +606,16 @@ class ServingEngine:
         Returns (out, k_pages, v_pages): out [C, 1] — each row's pick
         after its last valid token — or [C, qb] with per-position picks
         when speculative verification needs the full ladder. Per-token
-        KV scatter: valid tokens write their own (page, offset), padding
-        tokens hit the sink page, so garbage never lands in request
-        pages (write-before-attend, per layer)."""
+        KV write (ops/pallas/paged_kv_write.py): valid tokens land at
+        their own (page, offset), padding never lands in request pages
+        (write-before-attend, per layer). The pool is [L, P, ...] at
+        this boundary and donated; the layers see it as a loop carry
+        under _scan_layers' (l*P + p) addressing, so it is updated where
+        it lies."""
         cfg = self.cfg
         C, qb = tokens.shape
         nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        from ..ops.pallas.paged_kv_write import paged_kv_write
         from ..ops.pallas.ragged_paged_attention import \
             ragged_paged_attention
 
@@ -596,23 +633,17 @@ class ServingEngine:
         tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
         rows = ptable[row_slot]                      # [C, max_blocks]
         positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
-        valid = jnp.arange(qb, dtype=jnp.int32)[None, :] < n_valid[:, None]
-        blk = positions // self.bs
-        offs = (positions % self.bs).reshape(-1)
-        pages = jnp.where(valid, jnp.take_along_axis(rows, blk, axis=1),
-                          0).reshape(-1)             # padding -> sink
         with jax.named_scope("embed"):
             x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
             cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
             cos, sin = cos[:, :, None, :], sin[:, :, None, :]
         sm_scale = 1.0 / math.sqrt(dH)
 
-        def body(carry, inp):
-            x = carry
+        def body(x, kp, vp, base, inp):
             if self._lora_on:
-                bp, kp, vp, aq_l, bq_l, av_l, bv_l = inp
+                bp, aq_l, bq_l, av_l, bv_l = inp
             else:
-                bp, kp, vp = inp
+                bp, = inp
             with jax.named_scope("layer/qkv"):
                 h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
                 q = _mm(h, bp["wq"], cfg)
@@ -628,25 +659,25 @@ class ServingEngine:
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
             with jax.named_scope("layer/kv_write"):
-                kp = kp.at[pages, :, :, offs].set(
-                    k.reshape(C * qb, nKV, dH).astype(kp.dtype))
-                vp = vp.at[pages, :, offs].set(
-                    v.reshape(C * qb, nKV, dH).astype(vp.dtype))
+                kp, vp = paged_kv_write(
+                    kp, vp, k.astype(kp.dtype), v.astype(vp.dtype),
+                    rows + base, pos0, n_valid, sink=base)
             with jax.named_scope("layer/attn"):
-                o = ragged_paged_attention(q, kp, vp, rows, pos0, n_valid,
-                                           sm_scale, k_layout="d_major")
+                o = ragged_paged_attention(q, kp, vp, rows + base, pos0,
+                                           n_valid, sm_scale,
+                                           k_layout="d_major")
                 x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
             with jax.named_scope("layer/mlp"):
                 h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
                 x = x + _mm(jax.nn.silu(
                     _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
                         cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
-            return x, (kp, vp)
+            return x, kp, vp, None
 
-        xs = (params["blocks"], k_pages, v_pages)
+        xs = (params["blocks"],)
         if self._lora_on:
             xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
-        x, (ks, vs) = lax.scan(body, x, xs)
+        x, ks, vs, _ = _scan_layers(body, x, k_pages, v_pages, xs)
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         if self.spec_k:
@@ -679,23 +710,23 @@ class ServingEngine:
         return out, ks, vs
 
     def unified_arg_shapes(self) -> tuple:
-        """Shape-only arguments of the (non-quant) unified step,
+        """Shape-only arguments of the unified step (either program),
         mirroring the live ``self._unified(...)`` dispatch exactly —
-        for tracing it (``trace_unified``) or lowering it
-        (``self._unified.lower(*shapes)``) with no device executing
+        for tracing it (``trace_unified``, ``trace_unified_quant``) or
+        lowering it (``lower_unified``) with no device executing
         anything."""
-        if self._kv_quant or self._lora_on or self._constr_on:
+        if self._lora_on or self._constr_on:
             raise NotImplementedError(
-                "unified_arg_shapes covers the base non-quant, "
-                "non-multitenant program; register a dedicated entry for "
-                "variant engines")
+                "unified_arg_shapes covers the non-multitenant programs; "
+                "register a dedicated entry for variant engines")
         C, qb, B = self.n_rows, self.qb, self.B
 
         def sds(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
-        params = jax.tree.map(sds, self.params)
-        kp, vp = sds(self.k_pages), sds(self.v_pages)
+        pool = (sds(self.k_pages), sds(self.v_pages))
+        if self._kv_quant:
+            pool += (sds(self.k_scales), sds(self.v_scales))
         i32, f32 = jnp.int32, jnp.float32
         tokens = jax.ShapeDtypeStruct((C, qb), i32)
         prev = jax.ShapeDtypeStruct((C, qb if self.spec_k else 1), i32)
@@ -704,8 +735,9 @@ class ServingEngine:
         ptab = jax.ShapeDtypeStruct((B + 1, self.max_blocks), i32)
         col_i = jax.ShapeDtypeStruct((C,), i32)
         col_f = jax.ShapeDtypeStruct((C,), f32)
-        return (params, kp, vp, tokens, prev, cmask, crow, ptab,
-                col_i, col_i, col_i, col_f, col_f, col_i)
+        return (jax.tree.map(sds, self.params),) + pool + (
+            tokens, prev, cmask, crow, ptab, col_i, col_i, col_i, col_f,
+            col_f, col_i)
 
     def lower_unified(self):
         """The live jitted unified step lowered at its dispatch shapes
@@ -716,6 +748,10 @@ class ServingEngine:
         """Trace the (non-quant) unified step to a closed jaxpr — the
         ``serving_unified`` entry program tools/lint/shardcheck.py
         propagates partition specs through."""
+        if self._kv_quant:
+            raise NotImplementedError(
+                "trace_unified covers the base engine; use "
+                "trace_unified_quant for the serving_kv_quant program")
         return jax.make_jaxpr(self._unified_step_impl)(
             *self.unified_arg_shapes())
 
@@ -729,29 +765,8 @@ class ServingEngine:
             raise NotImplementedError(
                 "trace_unified_quant covers the serving_kv_quant "
                 "program; use trace_unified for the base engine")
-        if self._lora_on or self._constr_on:
-            raise NotImplementedError(
-                "trace_unified_quant covers the non-multitenant quant "
-                "program; register a dedicated entry for variant engines")
-        C, qb, B = self.n_rows, self.qb, self.B
-
-        def sds(a):
-            return jax.ShapeDtypeStruct(a.shape, a.dtype)
-
-        params = jax.tree.map(sds, self.params)
-        kp, vp = sds(self.k_pages), sds(self.v_pages)
-        ksc, vsc = sds(self.k_scales), sds(self.v_scales)
-        i32, f32 = jnp.int32, jnp.float32
-        tokens = jax.ShapeDtypeStruct((C, qb), i32)
-        prev = jax.ShapeDtypeStruct((C, qb if self.spec_k else 1), i32)
-        cmask = jax.ShapeDtypeStruct((C,), jnp.bool_)
-        crow = jax.ShapeDtypeStruct((C,), i32)
-        ptab = jax.ShapeDtypeStruct((B + 1, self.max_blocks), i32)
-        col_i = jax.ShapeDtypeStruct((C,), i32)
-        col_f = jax.ShapeDtypeStruct((C,), f32)
         return jax.make_jaxpr(self._unified_step_impl_q)(
-            params, kp, vp, ksc, vsc, tokens, prev, cmask, crow, ptab,
-            col_i, col_i, col_i, col_f, col_f, col_i)
+            *self.unified_arg_shapes())
 
     def _unified_step_impl_q(self, params, k_pages, v_pages, k_scales,
                              v_scales, tokens, prev_out, chain_mask,
@@ -771,7 +786,14 @@ class ServingEngine:
            scale did not grow; duplicate writes across rows of one
            request produce identical bytes, so order cannot matter);
         3. quantize the new tokens against the updated scale and
-           scatter them per (page, offset) exactly like the bf16 path.
+           write them per (page, offset) exactly like the bf16 path.
+
+        The pages travel as in the bf16 step (a carry under
+        _scan_layers' (l*P + p) addressing; steps 2 and 3 add ``l*P``);
+        the fp32 scale planes stay scanned per layer, [P, nKV] under the
+        layer's own page ids, because the attention kernel holds one
+        layer's plane in SMEM and finds a page's scale by the id it
+        finds the page by.
 
         Speculative rollback and aborts need no extra handling: a
         rejected draft's or reused page's *content* is overwritten
@@ -782,6 +804,7 @@ class ServingEngine:
         cfg = self.cfg
         C, qb = tokens.shape
         nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        from ..ops.pallas.paged_kv_write import paged_kv_write
         from ..ops.pallas.ragged_paged_attention import \
             ragged_paged_attention
         from ..ops.quant import (kv_scale_update, quantize_to_scale,
@@ -798,10 +821,9 @@ class ServingEngine:
         rows = ptable[row_slot]                      # [C, max_blocks]
         positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
         valid = jnp.arange(qb, dtype=jnp.int32)[None, :] < n_valid[:, None]
-        blk = positions // self.bs
-        offs = (positions % self.bs).reshape(-1)
-        pages = jnp.where(valid, jnp.take_along_axis(rows, blk, axis=1),
-                          0).reshape(-1)             # padding -> sink
+        pages = jnp.where(
+            valid, jnp.take_along_axis(rows, positions // self.bs, axis=1),
+            0).reshape(-1)                           # padding -> sink
         # every page this step's chunks might straddle (per row: the
         # first written page plus any the qb-token span can spill
         # into); entries past a row's span hit its future pages or the
@@ -818,12 +840,11 @@ class ServingEngine:
             cos, sin = cos[:, :, None, :], sin[:, :, None, :]
         sm_scale = 1.0 / math.sqrt(dH)
 
-        def body(carry, inp):
-            x = carry
+        def body(x, kp, vp, base, inp):
             if self._lora_on:
-                bp, kp, vp, ksc, vsc, aq_l, bq_l, av_l, bv_l = inp
+                bp, ksc, vsc, aq_l, bq_l, av_l, bv_l = inp
             else:
-                bp, kp, vp, ksc, vsc = inp
+                bp, ksc, vsc = inp
             with jax.named_scope("layer/qkv"):
                 h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
                 q = _mm(h, bp["wq"], cfg)
@@ -843,34 +864,46 @@ class ServingEngine:
                     ksc, pages, jnp.max(jnp.abs(kf), axis=-1) / 127.0)
                 vsc_new = kv_scale_update(
                     vsc, pages, jnp.max(jnp.abs(vf), axis=-1) / 127.0)
-                kp = kp.at[pages_rw].set(rescale_int8(
-                    kp[pages_rw],
+                kp = kp.at[pages_rw + base].set(rescale_int8(
+                    kp[pages_rw + base],
                     jnp.take(ksc, pages_rw, axis=0)[:, :, None, None],
                     jnp.take(ksc_new, pages_rw, axis=0)[:, :, None, None]))
-                vp = vp.at[pages_rw].set(rescale_int8(
-                    vp[pages_rw],
+                vp = vp.at[pages_rw + base].set(rescale_int8(
+                    vp[pages_rw + base],
                     jnp.take(vsc, pages_rw, axis=0)[:, :, None, None],
                     jnp.take(vsc_new, pages_rw, axis=0)[:, :, None, None]))
-                kp = kp.at[pages, :, :, offs].set(quantize_to_scale(
-                    kf, jnp.take(ksc_new, pages, axis=0)[:, :, None]))
-                vp = vp.at[pages, :, offs].set(quantize_to_scale(
-                    vf, jnp.take(vsc_new, pages, axis=0)[:, :, None]))
+                kp, vp = paged_kv_write(
+                    kp, vp,
+                    quantize_to_scale(
+                        kf, jnp.take(ksc_new, pages, axis=0)[:, :, None]
+                    ).reshape(C, qb, nKV, dH),
+                    quantize_to_scale(
+                        vf, jnp.take(vsc_new, pages, axis=0)[:, :, None]
+                    ).reshape(C, qb, nKV, dH),
+                    rows + base, pos0, n_valid, sink=base)
             with jax.named_scope("layer/attn"):
-                o = ragged_paged_attention(q, kp, vp, rows, pos0, n_valid,
-                                           sm_scale, k_layout="d_major",
-                                           k_scales=ksc_new, v_scales=vsc_new)
+                # the attention finds a page's scale by the id it finds
+                # the page by, and the scale planes stay one layer's
+                # [P, nKV] (they ride SMEM): so it gets this layer's
+                # pages under their local ids, which is a copy of them
+                P = ksc.shape[0]
+                o = ragged_paged_attention(
+                    q, lax.dynamic_slice_in_dim(kp, base, P),
+                    lax.dynamic_slice_in_dim(vp, base, P), rows, pos0,
+                    n_valid, sm_scale, k_layout="d_major",
+                    k_scales=ksc_new, v_scales=vsc_new)
                 x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
             with jax.named_scope("layer/mlp"):
                 h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
                 x = x + _mm(jax.nn.silu(
                     _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
                         cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
-            return x, (kp, vp, ksc_new, vsc_new)
+            return x, kp, vp, (ksc_new, vsc_new)
 
-        xs = (params["blocks"], k_pages, v_pages, k_scales, v_scales)
+        xs = (params["blocks"], k_scales, v_scales)
         if self._lora_on:
             xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
-        x, (ks, vs, kss, vss) = lax.scan(body, x, xs)
+        x, ks, vs, (kss, vss) = _scan_layers(body, x, k_pages, v_pages, xs)
         with jax.named_scope("head"):
             x = rms_norm(x, params["final_norm"], cfg.rms_eps)
         if self.spec_k:

@@ -14,6 +14,7 @@ import warnings
 import numpy as np
 import pytest
 
+import jax
 import jax.numpy as jnp
 
 from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
@@ -330,3 +331,188 @@ def test_kv_quant_capacity_doubles_at_fixed_bytes():
     assert budget // on.kv_bytes_per_page() >= 2 * (
         budget // off.kv_bytes_per_page())
     assert on.kv_bytes_per_token() * 2 <= off.kv_bytes_per_token()
+
+
+# -- the pool is a loop carry addressed by (layer, page) -------------------
+
+def _scans(jaxpr):
+    """Every ``scan`` equation of a jaxpr, nested ones included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scan":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _scans(sub)
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+def test_pool_is_a_scan_carry_not_a_scanned_operand(kv_quant):
+    """A scan reads its ``xs`` and stacks fresh ``ys``, so a pool that
+    travels that way is copied every step. Both step programs carry the
+    pool, flattened to ``[L*P, ...]``, and scan over nothing
+    pool-shaped."""
+    engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
+                           kv_quant=kv_quant)
+    closed = (engine.trace_unified_quant() if kv_quant
+              else engine.trace_unified())
+    (scan,) = list(_scans(closed.jaxpr))
+    nc, nk = scan.params["num_consts"], scan.params["num_carry"]
+    carry = [v.aval.shape for v in scan.invars[nc:nc + nk]]
+    xs = [v.aval.shape for v in scan.invars[nc + nk:]]
+    ys = [v.aval.shape for v in scan.outvars[nk:]]
+    L, P = engine.k_pages.shape[:2]
+    k_pool = (L * P,) + engine.k_pages.shape[2:]
+    v_pool = (L * P,) + engine.v_pages.shape[2:]
+    assert k_pool in carry and v_pool in carry, carry
+    assert [v.aval.shape for v in scan.outvars[:nk]] == carry
+    tails = {engine.k_pages.shape[2:], engine.v_pages.shape[2:]}
+    for shape in xs + ys:
+        assert shape[-3:] not in tails, (shape, xs, ys)
+    assert (L,) in xs                          # the layer index travels as xs
+    # the shape at the jit boundary did not change
+    assert [v.aval.shape for v in closed.jaxpr.outvars[1:3]] == [
+        engine.k_pages.shape, engine.v_pages.shape]
+
+
+def _reference_step(engine, args):
+    """The unified step as a plain loop over the layers in Python: layer
+    ``l`` scatters into ``k_pages[l]`` under the layer's own page ids and
+    attends over that one layer's pages — the addressing the engine had
+    before the pool became a carry. Returns the pools (and scale planes)
+    the step leaves behind."""
+    from paddle_tpu.models.llama import (_mm, apply_rope, rms_norm,
+                                         rope_angles)
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        _ragged_paged_xla
+    from paddle_tpu.ops.quant import (kv_scale_update, quantize_to_scale,
+                                      rescale_int8)
+
+    cfg, bs, quant = engine.cfg, engine.bs, engine._kv_quant
+    if quant:
+        (params, kp, vp, ksc, vsc, tokens, prev_out, cmask, crow, ptable,
+         row_slot, pos0, n_valid) = args[:13]
+    else:
+        (params, kp, vp, tokens, prev_out, cmask, crow, ptable, row_slot,
+         pos0, n_valid) = args[:11]
+    C, qb = tokens.shape
+    nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+
+    def layer(x, bp, kl, vl, kscl, vscl):
+        h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
+        q = apply_rope(_mm(h, bp["wq"], cfg).reshape(C, qb, nH, dH),
+                       cos, sin)
+        k = apply_rope(_mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH),
+                       cos, sin)
+        v = _mm(h, bp["wv"], cfg).reshape(C, qb, nKV, dH)
+        sc = {}
+        if quant:
+            kf = k.reshape(C * qb, nKV, dH).astype(jnp.float32)
+            vf = v.reshape(C * qb, nKV, dH).astype(jnp.float32)
+            new = [kv_scale_update(s, pages,
+                                   jnp.max(jnp.abs(f), axis=-1) / 127.0)
+                   for s, f in ((kscl, kf), (vscl, vf))]
+            kl, vl = [p.at[pages_rw].set(rescale_int8(
+                p[pages_rw], jnp.take(s, pages_rw, axis=0)[:, :, None, None],
+                jnp.take(n, pages_rw, axis=0)[:, :, None, None]))
+                for p, s, n in ((kl, kscl, new[0]), (vl, vscl, new[1]))]
+            kw, vw = [quantize_to_scale(
+                f, jnp.take(n, pages, axis=0)[:, :, None])
+                for f, n in ((kf, new[0]), (vf, new[1]))]
+            kscl, vscl = new
+            sc = dict(k_scales=kscl, v_scales=vscl)
+        else:
+            kw = k.reshape(C * qb, nKV, dH).astype(kl.dtype)
+            vw = v.reshape(C * qb, nKV, dH).astype(vl.dtype)
+        kl = kl.at[pages, :, :, offs].set(kw)
+        vl = vl.at[pages, :, offs].set(vw)
+        o = _ragged_paged_xla(q, kl, vl, rows, pos0, n_valid,
+                              1.0 / np.sqrt(dH), "d_major", **sc)
+        x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
+        h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
+        x = x + _mm(jax.nn.silu(
+            _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
+                cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
+        return x, kl, vl, kscl, vscl
+
+    tok0 = jnp.where(cmask, prev_out[crow, 0], tokens[:, 0])
+    tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
+    rows = ptable[row_slot]
+    positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
+    valid = jnp.arange(qb, dtype=jnp.int32)[None, :] < n_valid[:, None]
+    pages = jnp.where(valid, jnp.take_along_axis(rows, positions // bs,
+                                                 axis=1), 0).reshape(-1)
+    offs = (positions % bs).reshape(-1)
+    blk_rw = jnp.clip(pos0[:, None] // bs + jnp.arange(
+        (qb - 1) // bs + 2, dtype=jnp.int32)[None, :],
+        0, engine.max_blocks - 1)
+    pages_rw = jnp.take_along_axis(rows, blk_rw, axis=1).reshape(-1)
+    x = params["wte"][tokens].astype(cfg.dtype)
+    cos, sin = rope_angles(cfg, positions)
+    cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+    ks, vs, kss, vss = [], [], [], []
+    for l in range(cfg.n_layers):
+        bp = jax.tree.map(lambda a: a[l], params["blocks"])
+        x, kl, vl, kscl, vscl = layer(
+            x, bp, kp[l], vp[l], ksc[l] if quant else None,
+            vsc[l] if quant else None)
+        ks.append(kl), vs.append(vl), kss.append(kscl), vss.append(vscl)
+    out = [jnp.stack(ks), jnp.stack(vs)]
+    return out + ([jnp.stack(kss), jnp.stack(vss)] if quant else [])
+
+
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+def test_pool_bit_identical_to_per_layer_reference(kv_quant):
+    """After every dispatch of a mixed prefill/decode run the pool —
+    every page of every layer, each layer's sink included — holds
+    exactly what a per-layer loop that scatters into ``k_pages[l]``
+    leaves there: (l*P + p) addressing of the carried pool puts the same
+    values into the same pages."""
+    engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=256,
+                           prefill_budget=32, qb=8, kv_quant=kv_quant)
+    inner, seen = engine._unified, []
+
+    def recording(*args):
+        host = jax.tree.map(np.asarray, args)      # before the donation
+        out = inner(*args)
+        seen.append((host, [np.asarray(o) for o in out[1:]]))
+        return out
+
+    engine._unified = recording
+    reference = jax.jit(lambda *a: _reference_step(engine, a))
+    engine.run(_mk_reqs(np.random.RandomState(5), sampled=True))
+    assert len(seen) > 8
+    mixed = 0
+    for host, got in seen:
+        n_valid, row_slot = host[-4], host[-6]
+        live = n_valid[row_slot < engine.B]
+        mixed += bool((live > 1).any() and (live == 1).any())
+        want = reference(*jax.tree.map(jnp.asarray, host))
+        for g, w in zip(got, want):
+            np.testing.assert_array_equal(g, np.asarray(w))
+    assert mixed, "no dispatch mixed prefill chunks with decode rows"
+
+
+def test_chunks_of_one_request_are_adjacent_rows():
+    """The write kernel keeps a page in VMEM across ADJACENT rows that
+    write it (ops/pallas/paged_kv_write.py), so the dispatcher's packing
+    is part of its contract: the rows of one request are consecutive and
+    in position order, and no other request writes its pages."""
+    engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=256,
+                           prefill_budget=32, qb=8, speculative_k=2)
+    inner, tables = engine._unified, []
+
+    def recording(*args):
+        tables.append([np.asarray(a) for a in args[7:11]])
+        return inner(*args)
+
+    engine._unified = recording
+    engine.run(_mk_reqs(np.random.RandomState(7)))
+    assert tables
+    for ptable, row_slot, pos0, n_valid in tables:
+        live = np.nonzero(row_slot < engine.B)[0]
+        for s in set(row_slot[live].tolist()):
+            idx = np.nonzero(row_slot == s)[0]
+            assert (np.diff(idx) == 1).all(), row_slot
+            assert (pos0[idx][1:] == (pos0[idx] + n_valid[idx])[:-1]).all()
+        owned = [set(ptable[s][ptable[s] > 0].tolist())
+                 for s in set(row_slot[live].tolist())]
+        assert sum(map(len, owned)) == len(set().union(*owned)), ptable
