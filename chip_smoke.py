@@ -58,6 +58,7 @@ from paddle_tpu.ops.pallas import fused_bias_act as BA
 from paddle_tpu.ops.pallas import fused_ce as CE
 from paddle_tpu.ops.pallas import fused_norm_epilogue as NE
 from paddle_tpu.ops.pallas import fused_rope_attention as RA
+from paddle_tpu.ops.pallas import mla_paged_attention as MPA
 from paddle_tpu.ops.pallas import paged_kv_write as KVW
 from paddle_tpu.ops.pallas import ragged_paged_attention as RPA
 from paddle_tpu.parallel import make_sharded_train_step
@@ -116,6 +117,20 @@ class ServeLeg:
     # prompt length must be a multiple of 256 (the fused kernels' rows)
     ref_request: int = 1
     seed: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentLeg:
+    """The latent (MLA) serving kernels at one grid geometry: the
+    benchmark's joyai-flash-ep16 cell by default."""
+    n_rows: int = 32
+    qb: int = 16
+    n_heads: int = 32
+    kv_rank: int = 512
+    rope_dim: int = 64
+    page_size: int = 128
+    max_blocks: int = 52
+    n_pages: int = 256
 
 
 def full_train_leg() -> TrainLeg:
@@ -554,6 +569,49 @@ def serve_leg(leg: ServeLeg) -> dict:
 
 
 # ---------------------------------------------------------------------------
+# latent leg: the MLA serving kernels against their XLA arms
+# ---------------------------------------------------------------------------
+
+def latent_leg(leg: LatentLeg) -> dict:
+    """Absorbed latent attention over latent pages, at the pages-per-
+    step choice the autotune knows, and the latent write (paged_kv_write
+    with planes of unequal width), each against its XLA arm on a mixed
+    grid: decode rows at every depth beside full prefill chunks."""
+    C, qb, nH, R, dr = leg.n_rows, leg.qb, leg.n_heads, leg.kv_rank, \
+        leg.rope_dim
+    bs, mb, P = leg.page_size, leg.max_blocks, leg.n_pages
+    dt = jnp.bfloat16
+    rnd = _normal(13, dt)
+    rs = np.random.RandomState(5)
+    n_valid = np.where(np.arange(C) % 2 == 0, 1, qb).astype(np.int32)
+    pos0 = rs.randint(0, mb * bs - qb, size=C).astype(np.int32)
+    rows = rs.randint(1, P, size=(C, mb)).astype(np.int32)
+    q_lat, q_rope = rnd((C, qb, nH, R)), rnd((C, qb, nH, dr))
+    ckv, kr = rnd((P, bs, R)), rnd((P, dr, bs))
+    sm = 1.0 / math.sqrt(192.0)
+    checks: dict = {}
+    want = jax.jit(lambda *o: MPA._mla_paged_xla(*o, sm))(
+        q_lat, q_rope, ckv, kr, rows, pos0, n_valid)
+    for impl in MPA.candidates_for(mb)[:-1]:
+        check_close(checks, f"mla_paged_attention_{impl}",
+                    MPA.mla_paged_attention_kernel(
+                        q_lat, q_rope, ckv, kr, rows, pos0, n_valid, sm,
+                        int(impl.split("_p")[1])), want, TOL_BF16)
+    W = min(C, (P - 1) // 2)
+    own = 1 + 2 * np.arange(W)[:, None] + (np.arange(mb)[None, :]
+                                            - pos0[:W, None] // bs) % 2
+    k_new, v_new = rnd((W, qb, 1, dr)), rnd((W, qb, 1, R))
+    kp, vp = kr[:, None], ckv[:, None]
+    got = KVW.paged_kv_write_kernel(kp, vp, k_new, v_new, own, pos0[:W],
+                                    n_valid[:W])
+    want = jax.jit(KVW._paged_kv_write_xla)(kp, vp, k_new, v_new, own,
+                                            pos0[:W], n_valid[:W], 0)
+    check_close(checks, "latent_write_k_rope", got[0][1:], want[0][1:], 0.0)
+    check_close(checks, "latent_write_c_kv", got[1][1:], want[1][1:], 0.0)
+    return {"geometry": dataclasses.asdict(leg), "kernel_vs_xla": checks}
+
+
+# ---------------------------------------------------------------------------
 # mesh leg (>= 4 devices)
 # ---------------------------------------------------------------------------
 
@@ -625,7 +683,8 @@ def main() -> int:
     print(json.dumps(report["device"]), flush=True)
 
     legs = [("train", train_leg, full_train_leg()),
-            ("serve", serve_leg, full_serve_leg())]
+            ("serve", serve_leg, full_serve_leg()),
+            ("latent", latent_leg, LatentLeg())]
     if len(jax.devices()) >= 4:
         # first, so that its per-device peaks are its own: the one-chip
         # legs that follow all land on device 0

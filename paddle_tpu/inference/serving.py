@@ -56,9 +56,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.flags import GLOBAL_FLAGS
-from ..models.llama import (LlamaConfig, apply_rope, init_llama_params,
+from ..models.llama import (LlamaConfig, LlamaServing, apply_rope,
                             quantize_weights_int8, rms_norm, rope_angles,
                             _mm)
+from ..models.seam import LayerGroup
 from ..obs import clock as _clock
 from ..testing import chaos as _chaos
 from .. import obs as _obs
@@ -186,6 +187,18 @@ def _scan_layers(body, x, k_pages, v_pages, xs):
     sees the flattened pools and ``base = l*P`` to add to every page id
     it scatters to or attends over. Returns ``(x, k_pages, v_pages, ys)``
     with the pools back in their ``[L, P, ...]`` shape."""
+    L = k_pages.shape[0]
+    x, kp, vp, (ys,) = _run_layer_groups(
+        body, x, k_pages, v_pages, [LayerGroup(0, L, xs)])
+    return x, kp, vp, ys
+
+
+def _run_layer_groups(body, x, k_pages, v_pages, groups):
+    """``_scan_layers`` for a model whose layers are not all alike: the
+    pools are flattened once, each stacked group of ``groups`` is scanned
+    over its own layers ``first .. first+count-1`` (the same carry, the
+    same ``l*P`` rule), a single unstacked layer is applied where it
+    stands. Returns ``(x, k_pages, v_pages, [ys per group])``."""
     L, P = k_pages.shape[:2]
 
     def step(carry, inp):
@@ -193,12 +206,22 @@ def _scan_layers(body, x, k_pages, v_pages, xs):
         x, kp, vp, ys = body(*carry, l * P, layer_xs)
         return (x, kp, vp), ys
 
-    (x, kp, vp), ys = lax.scan(
-        step,
-        (x, k_pages.reshape((L * P,) + k_pages.shape[2:]),
-         v_pages.reshape((L * P,) + v_pages.shape[2:])),
-        (jnp.arange(L, dtype=jnp.int32), xs))
-    return x, kp.reshape(k_pages.shape), vp.reshape(v_pages.shape), ys
+    carry = (x, k_pages.reshape((L * P,) + k_pages.shape[2:]),
+             v_pages.reshape((L * P,) + v_pages.shape[2:]))
+    all_ys = []
+    for g in groups:
+        if g.stacked:
+            carry, ys = lax.scan(
+                step, carry,
+                (jnp.arange(g.first, g.first + g.count, dtype=jnp.int32),
+                 g.xs))
+        else:
+            *carry, ys = body(*carry, g.first * P, g.xs)
+            carry = tuple(carry)
+        all_ys.append(ys)
+    x, kp, vp = carry
+    return (x, kp.reshape(k_pages.shape), vp.reshape(v_pages.shape),
+            all_ys)
 
 
 def kv_scale_reset(scales, page_ids, axis: int = 0):
@@ -338,7 +361,7 @@ class ServingEngine:
     occupancy stats.
     """
 
-    def __init__(self, cfg: LlamaConfig, params: Optional[dict] = None,
+    def __init__(self, cfg, params: Optional[dict] = None,
                  seed: int = 0, max_batch: int = 8, page_size: int = 128,
                  max_seq: Optional[int] = None, n_pages: Optional[int] = None,
                  prefill_budget: Optional[int] = None,
@@ -390,11 +413,22 @@ class ServingEngine:
         # lone engine keeps None and never consults it.
         self.param_version: Optional[str] = None
         self.cfg = cfg
-        self.params = params if params is not None else init_llama_params(
-            cfg, jax.random.PRNGKey(seed))
+        # the model's side of the step (models/seam.py): LLaMA's is built
+        # here, any other config brings its own
+        if lora is None:
+            lora = GLOBAL_FLAGS.get("serving_lora")
+        self.model = (LlamaServing(cfg, lora=bool(lora))
+                      if isinstance(cfg, LlamaConfig)
+                      else cfg.serving_model())
+        self.params = params if params is not None else \
+            self.model.init_params(jax.random.PRNGKey(seed))
         if weight_only_int8 is None:
             weight_only_int8 = bool(GLOBAL_FLAGS.get("decode_weight_quant"))
-        if (weight_only_int8 or cfg.weight_only_int8) and not isinstance(
+        weight_only_int8 = bool(
+            weight_only_int8 or getattr(cfg, "weight_only_int8", False))
+        if weight_only_int8:
+            self._require("weight_only_int8")
+        if weight_only_int8 and not isinstance(
                 self.params["blocks"]["wq"], tuple):
             # halves weight HBM (per-column absmax int8 + bf16 scales;
             # embeddings/norms stay high precision) — every matmul in the
@@ -404,7 +438,7 @@ class ServingEngine:
             self.params = quantize_weights_int8(self.params)
         # remembered for set_params (a live weight swap must land in the
         # same quantized format the ctor established)
-        self._weight_only_int8 = bool(weight_only_int8)
+        self._weight_only_int8 = weight_only_int8
         self.B = max_batch
         self.bs = page_size
         self.max_seq = max_seq or cfg.max_seq_len
@@ -460,8 +494,6 @@ class ServingEngine:
         self.admit_aging = admit_aging
         # -- multi-tenant axes (inference/multitenant/): all default off
         #    = the exact single-tenant engine (bit-identical, pinned) ---
-        if lora is None:
-            lora = GLOBAL_FLAGS.get("serving_lora")
         if priorities is None:
             priorities = GLOBAL_FLAGS.get("serving_priorities")
         if constrained is None:
@@ -469,12 +501,23 @@ class ServingEngine:
         self._lora_on = bool(lora)
         self._prio_on = bool(priorities)
         self._constr_on = bool(constrained)
+        for on, feature in ((self._kv_quant, "kv_quant"),
+                            (self._lora_on, "lora"),
+                            (self._constr_on, "constrained"),
+                            (self.spec_k, "speculative"),
+                            (self.prefill_only, "page_shipment")):
+            if on:
+                self._require(feature)
         if self._constr_on and self.spec_k:
             raise ValueError(
                 "serving_constrained is incompatible with "
                 "serving_speculative_k: a constraint mask covers one "
                 "sampling position per row, not a k-token draft ladder")
-        L, nKV, d = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
+        # what a token stores in a layer is the model's to say; the pool
+        # is the spec's pair of planes, [L, P, *page_shape] each
+        L = self.model.n_layers
+        self.cache_spec = spec = self.model.cache_spec(self.bs)
+        nKV = spec.planes[0].page_shape[0]     # scale planes: GQA pages
         # serving_kv_quant: pages are symmetric int8 with a per-page,
         # per-head fp32 scale plane per layer — KV bytes per token drop
         # from 2*itemsize*nKV*dH to 2*nKV*dH (+ amortized scales), so a
@@ -482,16 +525,19 @@ class ServingEngine:
         # The pool is [L, P, ...] everywhere outside the step program;
         # inside, layer l finds page p at l*P + p (_scan_layers), and
         # page 0 of each layer is its sink.
-        page_dtype = jnp.int8 if self._kv_quant else cfg.dtype
-        self.k_pages = jnp.zeros((L, self.n_pages, nKV, d, self.bs),
-                                 page_dtype)
-        self.v_pages = jnp.zeros((L, self.n_pages, nKV, self.bs, d),
-                                 page_dtype)
+        page_dtype = jnp.int8 if self._kv_quant else spec.dtype
+        self.k_pages, self.v_pages = (
+            jnp.zeros((L, self.n_pages) + plane.page_shape, page_dtype)
+            for plane in spec.planes)
         if self._kv_quant:
             self.k_scales = jnp.zeros((L, self.n_pages, nKV), jnp.float32)
             self.v_scales = jnp.zeros((L, self.n_pages, nKV), jnp.float32)
         else:
             self.k_scales = self.v_scales = None
+        _obs.instant("engine.cache_spec", engine=self.engine_id,
+                     bytes_per_token=self.kv_bytes_per_token(),
+                     planes=",".join(f"{p.name}:{p.width}"
+                                     for p in spec.planes))
         self.table = np.zeros((self.B, self.max_blocks), np.int32)  # sink
         self.seq_lens = np.zeros((self.B,), np.int32)
         self.cur_tok = np.zeros((self.B,), np.int32)
@@ -546,7 +592,7 @@ class ServingEngine:
         # snapshot); _prev_out_dev chains row outputs on-device into the
         # next dispatch; _deferred_free holds page ids for one harvest
         # cycle (an in-flight program may still write them)
-        self._inflight = None              # (out_dev [C, 1|qb], snapshot)
+        self._inflight = None       # (out_dev [C, 1|qb], snapshot, ys)
         self._prev_out_dev = None
         self._deferred_free: list[int] = []
         # migration staging (inference/fleet/): pages allocated by
@@ -579,6 +625,21 @@ class ServingEngine:
             # wire cost the overlapped path shrinks to a buffer swap)
             "wire_export_ms": 0.0,
         }
+        # counters the model's layers send out with the picks (seam.py:
+        # tick_stats), summed here at harvest; the last tick's also go
+        # on engine.step's end
+        self.stats.update(dict.fromkeys(
+            getattr(self.model, "stats_keys", ()), 0))
+        self._tick_stats: dict = {}
+
+    def _require(self, feature: str) -> None:
+        """One error for every engine feature the served model's seam
+        does not cover (``model.unsupported``)."""
+        if feature in self.model.unsupported:
+            raise NotImplementedError(
+                f"{type(self.cfg).__name__} is not served with "
+                f"'{feature}': its serving model leaves out "
+                f"{', '.join(self.model.unsupported)}")
 
     # -- compiled program ---------------------------------------------------
 
@@ -603,7 +664,9 @@ class ServingEngine:
         serializing with it (its cost is not measured on a locally
         attached chip).
 
-        Returns (out, k_pages, v_pages): out [C, 1] — each row's pick
+        Returns (out, k_pages, v_pages, ys); ys holds the layers'
+        counters, one entry per layer group, None for a group that keeps
+        none (models/seam.py): out [C, 1] — each row's pick
         after its last valid token — or [C, qb] with per-position picks
         when speculative verification needs the full ladder. Per-token
         KV write (ops/pallas/paged_kv_write.py): valid tokens land at
@@ -612,12 +675,8 @@ class ServingEngine:
         this boundary and donated; the layers see it as a loop carry
         under _scan_layers' (l*P + p) addressing, so it is updated where
         it lies."""
-        cfg = self.cfg
+        model = self.model
         C, qb = tokens.shape
-        nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        from ..ops.pallas.paged_kv_write import paged_kv_write
-        from ..ops.pallas.ragged_paged_attention import \
-            ragged_paged_attention
 
         # multi-tenant operands ride as trailing varargs so the default
         # (flags off) trace is literally the legacy trace: row adapter
@@ -627,65 +686,29 @@ class ServingEngine:
         if self._lora_on:
             aid, ast = mt.pop(0), mt.pop(0)
         vmask = mt.pop(0) if self._constr_on else None
-        from ..ops.pallas.lora_matmul import lora_matmul
 
         tok0 = jnp.where(chain_mask, prev_out[chain_row, 0], tokens[:, 0])
         tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
         rows = ptable[row_slot]                      # [C, max_blocks]
         positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
-        with jax.named_scope("embed"):
-            x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
-            cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
-            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-        sm_scale = 1.0 / math.sqrt(dH)
+        x, ctx = model.embed(params, tokens, positions)
+        if self._lora_on:
+            ctx["aid"] = aid
+            groups = model.layer_groups(params, ast)
+        else:
+            groups = model.layer_groups(params)
 
         def body(x, kp, vp, base, inp):
-            if self._lora_on:
-                bp, aq_l, bq_l, av_l, bv_l = inp
-            else:
-                bp, = inp
-            with jax.named_scope("layer/qkv"):
-                h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
-                q = _mm(h, bp["wq"], cfg)
-                k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
-                v = _mm(h, bp["wv"], cfg)
-                if self._lora_on:
-                    # grouped BGMV: each packed row through ITS adapter's
-                    # q/v low-rank delta (slot 0 = exact +0.0 identity)
-                    q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
-                    v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
-                q = q.reshape(C, qb, nH, dH)
-                v = v.reshape(C, qb, nKV, dH)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-            with jax.named_scope("layer/kv_write"):
-                kp, vp = paged_kv_write(
-                    kp, vp, k.astype(kp.dtype), v.astype(vp.dtype),
-                    rows + base, pos0, n_valid, sink=base)
-            with jax.named_scope("layer/attn"):
-                o = ragged_paged_attention(q, kp, vp, rows + base, pos0,
-                                           n_valid, sm_scale,
-                                           k_layout="d_major")
-                x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
-            with jax.named_scope("layer/mlp"):
-                h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
-                x = x + _mm(jax.nn.silu(
-                    _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
-                        cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
-            return x, kp, vp, None
+            return model.apply(x, kp, vp, base, inp, rows, pos0, n_valid,
+                               ctx)
 
-        xs = (params["blocks"],)
-        if self._lora_on:
-            xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
-        x, ks, vs, _ = _scan_layers(body, x, k_pages, v_pages, xs)
-        with jax.named_scope("head"):
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
+        x, ks, vs, ys = _run_layer_groups(body, x, k_pages, v_pages, groups)
+        x = model.head(params, x)
         if self.spec_k:
             # speculative verify needs the model's pick at EVERY draft
             # position; keying on each input position keeps the accepted
             # stream identical to one-token-at-a-time decoding
-            with jax.named_scope("head"):
-                logits = _mm(x, params["head"], cfg).astype(jnp.float32)
+            logits = model.logits(params, x)
             picks = _pick_tokens(
                 logits.reshape(C * qb, -1), jnp.repeat(temps, qb),
                 jnp.repeat(topps, qb), jnp.repeat(seeds, qb),
@@ -694,8 +717,7 @@ class ServingEngine:
         else:
             with jax.named_scope("head"):
                 last = x[jnp.arange(C), n_valid - 1]     # [C, H]
-                logits = _mm(last[:, None], params["head"], cfg).astype(
-                    jnp.float32)[:, 0]
+            logits = model.logits(params, last[:, None])[:, 0]
             if self._constr_on:
                 # constrained rows only see schema-legal logits;
                 # unconstrained rows carry an all-True mask, and
@@ -707,7 +729,8 @@ class ServingEngine:
             # bit-identical across chunk/budget/packing boundaries
             out = _pick_tokens(logits, temps, topps, seeds,
                                pos0 + n_valid - 1)[:, None]
-        return out, ks, vs
+        # the layers' counters ride out with the picks
+        return out, ks, vs, ys
 
     def unified_arg_shapes(self) -> tuple:
         """Shape-only arguments of the unified step (either program),
@@ -1345,8 +1368,9 @@ class ServingEngine:
         # end: decode and prefill rows of the grid, requests left waiting
         rows = self._inflight[1] if self._inflight is not prev else ()
         n_dec = sum(1 for r in rows if r[3] == "dec")
-        sp.set(rows_decode=n_dec, rows_prefill=len(rows) - n_dec,
-               queued=len(self.queue))
+        tick = dict(rows_decode=n_dec, rows_prefill=len(rows) - n_dec,
+                    queued=len(self.queue))
+        sp.set(**tick)
         # synchronous modes (spec, constrained): drafts and vocab masks
         # are host state derived from the previous step's tokens, so each
         # step harvests before the next dispatch (chaining is moot —
@@ -1356,6 +1380,9 @@ class ServingEngine:
         if harvest is not None:
             with _obs.span("engine.harvest", engine=self.engine_id):
                 self._harvest(harvest)
+            if self._tick_stats:
+                # and what the harvested step's layers counted
+                sp.set(**tick, **self._tick_stats)
         if self.prefill_only:
             self._export_completed()
         if self._inflight is None and (self._deferred_free
@@ -1550,6 +1577,7 @@ class ServingEngine:
             extra += [jnp.array(aidv), self.adapters.stacks()]
         if self._constr_on:
             extra.append(jnp.array(vm))
+        ys = ()         # the layers' counters (the fp step's groups)
         if self._kv_quant:
             (out, self.k_pages, self.v_pages, self.k_scales,
              self.v_scales) = self._unified(
@@ -1559,12 +1587,12 @@ class ServingEngine:
                 jnp.array(rs), jnp.array(p0), jnp.array(nv),
                 jnp.array(tt), jnp.array(tp), jnp.array(tsd), *extra)
         else:
-            out, self.k_pages, self.v_pages = self._unified(
+            out, self.k_pages, self.v_pages, ys = self._unified(
                 self.params, self.k_pages, self.v_pages, jnp.array(tokens),
                 prev_out, jnp.array(cmask), jnp.array(crow), jnp.array(ptab),
                 jnp.array(rs), jnp.array(p0), jnp.array(nv), jnp.array(tt),
                 jnp.array(tp), jnp.array(tsd), *extra)
-        self._inflight = (out, snap)
+        self._inflight = (out, snap, ys)
         self._prev_out_dev = out
         # post-dispatch bookkeeping: prefix-cache offers for pages this
         # step completed, prefill flips, decode position advance
@@ -1626,9 +1654,16 @@ class ServingEngine:
         """Fetch a completed step's row outputs (the only host sync of
         the serving path) and apply them; release pages freed one cycle
         ago — no in-flight program can reference them anymore."""
-        out_dev, snap = inflight
+        out_dev, snap, ys = inflight
         with _obs.span("engine.harvest.wait", engine=self.engine_id):
-            toks = np.asarray(out_dev)               # [C, 1] or [C, qb]
+            # [C, 1] or [C, qb], and the layers' counters in the same
+            # fetch: one sync
+            toks, ys = jax.device_get((out_dev, ys))
+        if any(y is not None for y in ys):
+            self._tick_stats = self.model.tick_stats(
+                ys, sum(m for _i, _s, _r, _k, m, _d in snap))
+            for k, v in self._tick_stats.items():
+                self.stats[k] += v
         if self._inflight is not None and self._inflight[0] is out_dev:
             self._inflight = None
         self.pool.release(self._deferred_free)
@@ -1755,6 +1790,7 @@ class ServingEngine:
         and (b) dispatched into the pool (``seq_lens`` / ``_prefilling``
         advance at dispatch). None for unknown/queued rids or when no
         full page is covered."""
+        self._require("page_shipment")
         for slot in range(self.B):
             req = self.slots[slot]
             if req is not None and req.rid == rid:
@@ -2015,6 +2051,7 @@ class ServingEngine:
         Returns the staging handle, or None when nothing is adoptable
         (all cached, crc-dead at page 0, allocation failure, or an
         armed ``migration.adopt`` fault)."""
+        self._require("page_shipment")
         cfg = self.cfg
         if (shipment.get("version") not in (1, 2)
                 or shipment["page_size"] != self.bs
@@ -2206,12 +2243,11 @@ class ServingEngine:
         argument for serving_kv_quant: at a fixed page-pool byte budget
         the pool holds bytes_bf16/bytes_int8 ~ 2x the pages, hence ~2x
         the concurrent sequences."""
-        cfg = self.cfg
-        L, nKV, d = cfg.n_layers, cfg.n_kv_heads, cfg.head_dim
-        per = L * nKV * d * self.bs * (self.k_pages.dtype.itemsize
-                                       + self.v_pages.dtype.itemsize)
+        L = self.model.n_layers
+        per = self.cache_spec.page_bytes(L, self.k_pages.dtype.itemsize)
         if self._kv_quant:
-            per += 2 * L * nKV * self.k_scales.dtype.itemsize
+            per += 2 * L * self.k_scales.shape[2] * \
+                self.k_scales.dtype.itemsize
         return float(per)
 
     def kv_bytes_per_token(self) -> float:

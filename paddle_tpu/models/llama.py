@@ -495,3 +495,102 @@ class LlamaForCausalLM:
             if bool((nxt == eos_token_id).all()):
                 break
         return np.stack([np.asarray(o) for o in out], axis=1)
+
+
+# ---------------------------------------------------------------------------
+# the serving engine's side of the model (models/seam.py has the contract)
+# ---------------------------------------------------------------------------
+
+class LlamaServing:
+    """LLaMA behind the serving engine's model seam: k pages d-major and
+    v pages token-major per kv head, the per-token page write, unified
+    ragged-paged attention, SwiGLU.  ``lora`` adds the per-row q/v
+    low-rank deltas (``ctx["aid"]`` and four stacks beside the blocks)."""
+
+    unsupported = ()
+
+    def __init__(self, cfg: LlamaConfig, lora: bool = False):
+        self.cfg, self.lora = cfg, lora
+        self.n_layers = cfg.n_layers
+
+    def init_params(self, key) -> dict:
+        return init_llama_params(self.cfg, key)
+
+    def cache_spec(self, page_size: int):
+        from .seam import CachePlane, CacheSpec
+
+        nKV, d = self.cfg.n_kv_heads, self.cfg.head_dim
+        return CacheSpec(
+            (CachePlane("k", (nKV, d, page_size), nKV * d),
+             CachePlane("v", (nKV, page_size, d), nKV * d)), self.cfg.dtype)
+
+    def embed(self, params, tokens, positions):
+        cfg = self.cfg
+        with jax.named_scope("embed"):
+            x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
+            cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
+            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+        return x, {"cos": cos, "sin": sin}
+
+    def layer_groups(self, params, lora_stacks=None):
+        from .seam import LayerGroup
+
+        xs = (params["blocks"],)
+        if self.lora:
+            ast = lora_stacks
+            xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
+        return [LayerGroup(0, self.n_layers, xs)]
+
+    def apply(self, x, kp, vp, base, inp, rows, pos0, n_valid, ctx):
+        from ..ops.pallas.lora_matmul import lora_matmul
+        from ..ops.pallas.paged_kv_write import paged_kv_write
+        from ..ops.pallas.ragged_paged_attention import \
+            ragged_paged_attention
+
+        cfg = self.cfg
+        C, qb = x.shape[:2]
+        nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        cos, sin = ctx["cos"], ctx["sin"]
+        sm_scale = 1.0 / math.sqrt(dH)
+        if self.lora:
+            bp, aq_l, bq_l, av_l, bv_l = inp
+            aid = ctx["aid"]
+        else:
+            bp, = inp
+        with jax.named_scope("layer/qkv"):
+            h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
+            q = _mm(h, bp["wq"], cfg)
+            k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
+            v = _mm(h, bp["wv"], cfg)
+            if self.lora:
+                # grouped BGMV: each packed row through ITS adapter's
+                # q/v low-rank delta (slot 0 = exact +0.0 identity)
+                q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
+                v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
+            q = q.reshape(C, qb, nH, dH)
+            v = v.reshape(C, qb, nKV, dH)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+        with jax.named_scope("layer/kv_write"):
+            kp, vp = paged_kv_write(
+                kp, vp, k.astype(kp.dtype), v.astype(vp.dtype),
+                rows + base, pos0, n_valid, sink=base)
+        with jax.named_scope("layer/attn"):
+            o = ragged_paged_attention(q, kp, vp, rows + base, pos0,
+                                       n_valid, sm_scale,
+                                       k_layout="d_major")
+            x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
+        with jax.named_scope("layer/mlp"):
+            h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
+            x = x + _mm(jax.nn.silu(
+                _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
+                    cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
+        return x, kp, vp, None
+
+    def head(self, params, x):
+        with jax.named_scope("head"):
+            return rms_norm(x, params["final_norm"], self.cfg.rms_eps)
+
+    def logits(self, params, h):
+        with jax.named_scope("head"):
+            return _mm(h, params["head"], self.cfg).astype(jnp.float32)

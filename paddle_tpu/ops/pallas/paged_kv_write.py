@@ -14,8 +14,11 @@ chunk's tokens into it and writes it back through
 
 Contract shared by the kernel and the XLA fallback:
 
-- k_pages [P, nKV, d, bs] d-major, v_pages [P, nKV, bs, d]; k, v
-  [C, qb, nKV, d] in the pages' dtype.
+- k_pages [P, nKV, d, bs] d-major, v_pages [P, nKV, bs, dv]; k
+  [C, qb, nKV, d] and v [C, qb, nKV, dv] in the pages' dtype.  The two
+  planes need not be of one width: latent (MLA) pages ride them as one
+  "head" each, k_rope d-major beside c_kv token-major
+  (models/mla_moe.py).
 - rows [C, max_blocks], pos0 [C], n_valid [C] as the attention takes
   them: chunk c's token i < n_valid[c] lands at offset
   (pos0[c] + i) % bs of page rows[c, (pos0[c] + i) // bs].
@@ -43,14 +46,16 @@ __all__ = ["paged_kv_write", "paged_kv_write_supported"]
 
 
 def paged_kv_write_supported(kt_pages_shape, qb: int,
-                             itemsize: int = 2) -> bool:
+                             itemsize: int = 2, v_width=None) -> bool:
     """Gate for the kernel: MXU/lane-tileable pages (the attention
-    kernel's own gate on d and bs), a chunk that spans at most two
-    pages, and values that a bf16 one-hot product places exactly (bf16
-    or int8 pages)."""
+    kernel's own gate on d and bs; planes of unequal width need the
+    d-major one sublane-tileable and the token-major one lane-tileable),
+    a chunk that spans at most two pages, and values that a bf16 one-hot
+    product places exactly (bf16 or int8 pages)."""
     _, _, d, bs = kt_pages_shape
-    return (d in (128, 256) and bs % 128 == 0 and qb <= bs
-            and itemsize <= 2)
+    widths = (d in (128, 256) if v_width in (None, d)
+              else d % 16 == 0 and v_width % 128 == 0)
+    return widths and bs % 128 == 0 and qb <= bs and itemsize <= 2
 
 
 def _write_kernel(pid_ref, pos0_ref, nval_ref, kn_ref, vn_ref, kin_ref,
@@ -115,6 +120,7 @@ def paged_kv_write_kernel(kt_pages, v_pages, k, v, rows, pos0, n_valid):
     from jax.experimental.pallas import tpu as pltpu
 
     C, qb, nkv, d = k.shape
+    dv = v.shape[3]
     bs = kt_pages.shape[3]
     pos0 = pos0.astype(jnp.int32)
     n_valid = n_valid.astype(jnp.int32)
@@ -134,12 +140,12 @@ def paged_kv_write_kernel(kt_pages, v_pages, k, v, rows, pos0, n_valid):
         grid=(C, 2),
         in_specs=[
             pl.BlockSpec((None, nkv, d, qb), _new),
-            pl.BlockSpec((None, nkv, qb, d), _new),
+            pl.BlockSpec((None, nkv, qb, dv), _new),
             pl.BlockSpec((None, nkv, d, bs), _page),
-            pl.BlockSpec((None, nkv, bs, d), _page),
+            pl.BlockSpec((None, nkv, bs, dv), _page),
         ],
         out_specs=[pl.BlockSpec((None, nkv, d, bs), _page),
-                   pl.BlockSpec((None, nkv, bs, d), _page)],
+                   pl.BlockSpec((None, nkv, bs, dv), _page)],
     )
     interpret = _interpret_mode()
     return pl.pallas_call(  # tpu-lint: disable=TPL007 -- blocks ARE the page geometry (a whole page per program); nothing to sweep
@@ -169,7 +175,8 @@ def _paged_kv_write_xla(k_pages, v_pages, k, v, rows, pos0, n_valid, sink):
         sink).reshape(-1)
     offs = (positions % bs).reshape(-1)
     k_pages = k_pages.at[pages, :, :, offs].set(k.reshape(C * qb, nkv, d))
-    v_pages = v_pages.at[pages, :, offs].set(v.reshape(C * qb, nkv, d))
+    v_pages = v_pages.at[pages, :, offs].set(
+        v.reshape(C * qb, nkv, v.shape[3]))
     return k_pages, v_pages
 
 
@@ -178,7 +185,7 @@ def paged_kv_write(k_pages, v_pages, k, v, rows, pos0, n_valid, sink=0):
     kernel where the page geometry supports it, else the XLA scatter.
     Returns (k_pages, v_pages)."""
     if paged_kv_write_supported(k_pages.shape, k.shape[1],
-                                k_pages.dtype.itemsize):
+                                k_pages.dtype.itemsize, v.shape[3]):
         return paged_kv_write_kernel(k_pages, v_pages, k, v, rows, pos0,
                                      n_valid)
     return _paged_kv_write_xla(k_pages, v_pages, k, v, rows, pos0, n_valid,

@@ -74,6 +74,16 @@ def test_serve_leg_tiny():
     assert f["logit_gap"] <= f["tol"]
 
 
+def test_latent_leg_tiny():
+    r = chip_smoke.latent_leg(chip_smoke.LatentLeg(
+        n_rows=4, qb=4, n_heads=2, kv_rank=32, rope_dim=8, page_size=16,
+        max_blocks=4, n_pages=12))
+    assert set(r["kernel_vs_xla"]) == {
+        "mla_paged_attention_kernel_p4", "latent_write_k_rope",
+        "latent_write_c_kv"}
+    assert all(c["rel_err"] <= c["tol"] for c in r["kernel_vs_xla"].values())
+
+
 def test_failed_check_raises():
     with pytest.raises(chip_smoke.SmokeFailure, match="rel_err"):
         chip_smoke.check_close({}, "k", jnp.ones(4), 2 * jnp.ones(4), 2e-2)

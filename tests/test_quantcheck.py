@@ -460,7 +460,8 @@ def test_build_report_current_tree_is_clean_and_current():
     assert Q.stale_explanations(findings) == []
     names = set(report["baseline"]["entries"])
     assert names == {"train_dp2_pp2_mp2", "serving_unified_fp32",
-                     "serving_unified_int8kv", "wire_stage_int8",
+                     "serving_unified_int8kv", "serving_unified_mla_moe",
+                     "wire_stage_int8",
                      "wire_commit_int8", "quant_allreduce_dp2pp2",
                      "quant_matmul_decode", "serving_admit_quant"}
     # ... and the committed baseline matches the tree (currency: a PR

@@ -280,7 +280,8 @@ def test_build_report_current_tree_is_clean_and_current():
         [], [f.message for f in S.unexplained_findings(findings)]
     assert S.stale_explanations(findings) == []
     names = set(report["baseline"]["entries"])
-    assert names == {"train_dp2_pp2_mp2", "serving_unified", "wire_stage",
+    assert names == {"train_dp2_pp2_mp2", "serving_unified",
+                     "serving_unified_mla_moe", "wire_stage",
                      "wire_commit", "quant_allreduce_dp2pp2"}
     # ... and the committed baseline matches the tree (currency: a PR
     # that changes sharding must regenerate artifacts/shardcheck.json)

@@ -149,6 +149,7 @@ PALLAS_KERNEL_MODULES = (
     "paddle_tpu.ops.pallas.flash_attention",
     "paddle_tpu.ops.pallas.fused_ce",
     "paddle_tpu.ops.pallas.lora_matmul",
+    "paddle_tpu.ops.pallas.mla_paged_attention",
     "paddle_tpu.ops.pallas.quant_matmul",
     "paddle_tpu.ops.pallas.ragged_paged_attention",
 )
@@ -312,6 +313,35 @@ def build_serving_fp32_entry() -> QuantEntry:
                       invar_names=names)
 
 
+def build_serving_mla_moe_entry() -> QuantEntry:
+    """The unified step over latent (MLA) pages with routed experts
+    (models/mla_moe.py), traced in bf16 so that TPL301 sees every
+    sub-fp32 dot of the absorbed attention, the page write and the
+    grouped expert products."""
+    jax = _jax()
+    from paddle_tpu.inference.serving import ServingEngine
+    from paddle_tpu.models.mla_moe import MlaMoeConfig, init_mla_moe_params
+
+    cfg = MlaMoeConfig(
+        vocab_size=128, hidden=32, n_layers=3, n_heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
+        ffn_hidden=64, moe_hidden=16, n_routed_experts=8,
+        experts_per_token=2, n_mtp=0, max_seq_len=64, held=(2, 4))
+    # shapes only: nothing runs, so no weight is ever drawn
+    params = jax.eval_shape(lambda k: init_mla_moe_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    eng = ServingEngine(cfg, params=params, max_batch=2, page_size=8,
+                        max_seq=64, n_pages=1 + 8)
+    closed = eng.trace_unified()
+    names = (["params" + n for n in _flatten_names(eng.params)]
+             + ["k_pages", "v_pages", "tokens", "prev_out", "chain_mask",
+                "chain_row", "ptable", "row_slot", "pos0", "n_valid",
+                "temps", "topps", "seeds"])
+    return QuantEntry(name="serving_unified_mla_moe", closed=closed,
+                      source="paddle_tpu/models/mla_moe.py",
+                      invar_names=names)
+
+
 def build_serving_int8_entry() -> QuantEntry:
     """The int8-KV unified step: the page arrays are int8 invars paired
     with their scale-plane invars — the engine's allocator maintains the
@@ -441,7 +471,8 @@ def build_entries(names=None) -> list:
     """All registered entry programs (optionally filtered by name)."""
     entries = [build_train_entry(),
                build_serving_fp32_entry(),
-               build_serving_int8_entry()]
+               build_serving_int8_entry(),
+               build_serving_mla_moe_entry()]
     entries += build_wire_entries()
     entries.append(build_allreduce_entry())
     entries.append(build_quant_matmul_entry())

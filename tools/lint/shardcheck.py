@@ -309,6 +309,19 @@ def build_serving_entries() -> list:
     return out
 
 
+def build_serving_mla_moe_entry() -> EntryProgram:
+    """The unified step over latent (MLA) pages with routed experts, on
+    one device: quantcheck's trace of it serves both verifiers."""
+    from .quantcheck import build_serving_mla_moe_entry as _qc_mla
+
+    mla = _qc_mla()
+    return EntryProgram(
+        name=mla.name, closed=mla.closed, mesh_axes={},
+        in_specs=[_empty_spec(_nd(v.aval))
+                  for v in mla.closed.jaxpr.invars],
+        source=mla.source)
+
+
 def build_quant_entry(name: str = "quant_allreduce_dp2pp2",
                       mesh_shape=(("dp", 2), ("pp", 2))) -> EntryProgram:
     """The quantized all-reduce (distributed/autograd_collectives.py)
@@ -351,6 +364,7 @@ def build_entries(names=None) -> list:
     """All registered entry programs (optionally filtered by name)."""
     entries = [build_train_entry()]
     entries += build_serving_entries()
+    entries.append(build_serving_mla_moe_entry())
     entries.append(build_quant_entry())
     if names is not None:
         entries = [e for e in entries if e.name in set(names)]
