@@ -416,8 +416,9 @@ def _serve_kernel_parity(leg: ServeLeg, engine: ServingEngine,
     H, nH, nKV, dH = cfg.hidden, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     rnd = _normal(11, cfg.dtype)
 
-    # ragged-paged attention on a mixed grid: decode rows (n_valid 1) at
-    # every depth and full prefill chunks, over random block tables
+    # ragged-paged attention, at every group size the gate admits, on a
+    # mixed grid: decode rows (n_valid 1) at every depth and full prefill
+    # chunks, over random block tables
     C, qb, bs, mb = engine.n_rows, engine.qb, engine.bs, engine.max_blocks
     P = engine.n_pages
     rs = np.random.RandomState(3)
@@ -427,11 +428,13 @@ def _serve_kernel_parity(leg: ServeLeg, engine: ServingEngine,
     q = rnd((C, qb, nH, dH))
     kp, vp = rnd((P, nKV, dH, bs)), rnd((P, nKV, bs, dH))
     sm = 1.0 / math.sqrt(dH)
-    check_close(
-        checks, "ragged_paged_attention",
-        RPA.ragged_paged_attention_kernel(q, kp, vp, rows, pos0, n_valid, sm),
-        jax.jit(lambda *o: RPA._ragged_paged_xla(*o, sm, "d_major"))(
-            q, kp, vp, rows, pos0, n_valid), TOL_BF16)
+    want = jax.jit(lambda *o: RPA._ragged_paged_xla(*o, sm, "d_major"))(
+        q, kp, vp, rows, pos0, n_valid)
+    for impl in RPA.candidates_for(kp.shape, nH, qb, mb)[:-1]:
+        check_close(checks, f"ragged_paged_attention_{impl}",
+                    RPA.ragged_paged_attention_kernel(
+                        q, kp, vp, rows, pos0, n_valid, sm,
+                        pps=int(impl.split("_p")[1])), want, TOL_BF16)
 
     # the write into the pages, on as many chunks of that grid as can own
     # the two pages a chunk may touch (the kernel's contract: one writer

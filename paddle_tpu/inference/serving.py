@@ -20,7 +20,9 @@ for the paged cache). TPU design:
   per request from a free list and returned on completion; page 0 is a
   write sink for idle rows and padding tokens so the batched program
   needs no masking branches. k pages are d-major — the MXU kernel's
-  native operand (ops/pallas/ragged_paged_attention.py).
+  native operand (ops/pallas/ragged_paged_attention.py: a grid step is
+  one row's group of pages across all kv heads, a decode row runs its
+  one token's query rows alone, padding rows come out as zeros).
 - Prefix caching: page-aligned prompt chunks are content-hashed
   (cumulative chain, so a hit implies the whole prefix matches) and the
   pool refcounts cached pages. A shared system prompt is prefilled ONCE;

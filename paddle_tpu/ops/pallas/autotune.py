@@ -61,6 +61,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from typing import Any, Callable, Sequence
 
 __all__ = ["AutotuneRegistry", "GLOBAL_AUTOTUNE", "tuned", "stats",
@@ -175,6 +176,8 @@ class AutotuneRegistry:
         self._adopted: dict[str, dict] = {}   # program-injected entries
         self._capture: dict[str, dict] | None = None
         self._resolved: dict[str, Any] = {}   # key -> config, this process
+        self._announced_on = lambda: None     # weakref: the tracer told
+        self._announced: set[str] = set()     # keys told to that tracer
         self._loaded_from: str | None = None
         self.hits = 0
         self.misses = 0
@@ -283,13 +286,14 @@ class AutotuneRegistry:
                 entry = table.get(key)
                 if entry is not None and entry.get("source") == source:
                     self.hits += 1
-                    self._record(key, entry)
+                    self._record(key, entry, "hit")
                     return entry["config"]
             # stale-source entries fall through: re-sweep or default
             self.misses += 1
             if (measure is None or len(candidates) < 2
                     or not self._sweep_enabled()):
-                self._record(key, {"config": default, "source": source})
+                self._record(key, {"config": default, "source": source},
+                             "default")
                 return default
         # the sweep runs unlocked: it compiles and times on the device
         t0 = time.perf_counter()
@@ -321,15 +325,32 @@ class AutotuneRegistry:
             self.swept_keys.append(key)
             self.sweep_time_s += elapsed
             self._persist(lambda e, p: e.__setitem__(key, entry))
-            self._record(key, entry)
+            self._record(key, entry, "sweep")
         return candidates[best]
 
     # -- per-program layer (v2; driven by paddle_tpu/compiler) --------------
 
-    def _record(self, key: str, entry: dict) -> None:
+    def _record(self, key: str, entry: dict, how: str) -> None:
+        """Every resolution passes here: ``how`` is ``hit`` (a table had
+        the entry), ``sweep`` (measured now) or ``default`` (no entry
+        and no sweep: ``candidates[0]``).  The ring gets one instant
+        ``autotune.resolve`` per key per tracer, so a flight record or
+        a Chrome export says which form a compiled step took."""
+        from ... import obs as _obs
+
         self._resolved[key] = entry["config"]
         if self._capture is not None:
             self._capture[key] = dict(entry)
+        tracer = _obs.tracer()
+        if tracer is None:
+            return
+        if tracer is not self._announced_on():  # a fresh ring: tell again
+            self._announced_on, self._announced = weakref.ref(tracer), set()
+        if key not in self._announced:
+            self._announced.add(key)
+            kernel, _, bucket = key.split("|", 2)
+            _obs.instant("autotune.resolve", kernel=kernel, bucket=bucket,
+                         config=str(entry["config"]), how=how)
 
     def resolved(self) -> dict[str, Any]:
         """Every (key -> config) :meth:`tuned` has resolved in this

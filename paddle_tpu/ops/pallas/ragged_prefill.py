@@ -4,10 +4,11 @@ of the unified ragged-paged-attention step.
 PR 7 generalized this module into ragged_paged_attention.py, where every
 grid row carries an explicit valid-token count (decode is a 1-token
 chunk).  A page-aligned prefill chunk is exactly the n_valid == qb case
-— the clamped mask qpos(i) = pos0 + min(i, n_valid - 1) degenerates to
-pos0 + i — so the historical entry points below simply delegate.  See
-ragged_paged_attention.py for the kernel, the XLA arm, and the full
-contract.
+— every row is valid, qpos(i) = pos0 + i, and no row is zeroed as
+padding — so the historical entry points below simply delegate (the
+kernel at one page a grid step, the dispatcher at the autotune's
+choice).  See ragged_paged_attention.py for the kernel, the XLA arm,
+and the full contract.
 """
 
 from __future__ import annotations

@@ -170,6 +170,51 @@ def test_committed_table_is_read_first_and_never_written(tmp_path, sweep_on):
     assert json.load(open(path))["entries"][key]["source"] == "s2"
 
 
+@pytest.mark.parametrize("how", ["hit", "sweep", "default"])
+def test_resolve_is_announced_once_per_key_with_how(tmp_path, how):
+    """Every resolution tells the obs ring once per key per tracer which
+    config a call site got and how (a table had it, a sweep measured
+    it, or candidates[0] for want of both), so a flight record says
+    which form the compiled step took."""
+    from paddle_tpu import obs
+
+    committed = str(tmp_path / "committed.json")
+    key = "k|%s|b1|bf16" % _device_kind()
+    entries = {key: {"config": 512, "source": "s"}} if how == "hit" else {}
+    with open(committed, "w") as f:
+        json.dump({"version": 2, "programs": {}, "entries": entries}, f)
+    old = (GLOBAL_FLAGS.get("pallas_autotune_sweep")
+           if GLOBAL_FLAGS.has("pallas_autotune_sweep") else "auto")
+    GLOBAL_FLAGS.set("pallas_autotune_sweep", "1" if how == "sweep" else "0")
+    obs.arm(capacity=256)
+    try:
+        reg = AutotuneRegistry(str(tmp_path / "cache.json"),
+                               committed=committed)
+        want = {"hit": 512, "sweep": 1024, "default": 256}[how]
+        for _ in range(3):      # a step traced three times: one instant
+            assert reg.tuned("k", "b1", "bf16", [256, 512, 1024],
+                             measure=_measure({256: 2., 512: 3., 1024: 1.}),
+                             source="s") == want
+        reg.tuned("k", "b2", "bf16", [7], source="s")   # another key
+        told = [e["args"] for e in obs.tracer().snapshot()[0]
+                if e["name"] == "autotune.resolve"]
+        assert told == [
+            {"kernel": "k", "bucket": "b1|bf16", "config": str(want),
+             "how": how},
+            {"kernel": "k", "bucket": "b2|bf16", "config": "7",
+             "how": "default"}]
+        # a fresh ring is told again: its flight record stands alone
+        obs.arm(capacity=256)
+        reg.tuned("k", "b1", "bf16", [256, 512, 1024], source="s")
+        told = [e["args"] for e in obs.tracer().snapshot()[0]
+                if e["name"] == "autotune.resolve"]
+        assert [t["bucket"] for t in told] == ["b1|bf16"]
+        assert told[0]["how"] == ("default" if how == "default" else "hit")
+    finally:
+        GLOBAL_FLAGS.set("pallas_autotune_sweep", old)
+        obs.arm()
+
+
 def test_corrupt_cache_is_empty_cache(tmp_path, sweep_on):
     path = str(tmp_path / "cache.json")
     with open(path, "w") as f:

@@ -68,7 +68,9 @@ def test_serve_leg_tiny():
         "swiglu": {"sites": 1, "applied": 1}}
     assert r["kernels"] == {} and r["apply_kernels"] == {}
     assert set(r["kernel_vs_xla"]) == {
-        "ragged_paged_attention", "paged_kv_write_k", "paged_kv_write_v",
+        "ragged_paged_attention_kernel_p4", "ragged_paged_attention_kernel_p2",
+        "ragged_paged_attention_kernel_p1", "paged_kv_write_k",
+        "paged_kv_write_v",
         "rms_epilogue_r", "rms_epilogue_y", "swiglu", "rope_attention"}
     f = r["first_token_vs_llama_apply"]
     assert f["logit_gap"] <= f["tol"]

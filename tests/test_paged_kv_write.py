@@ -134,7 +134,7 @@ def compiled_kernels(monkeypatch):
     from paddle_tpu.ops.pallas import ragged_paged_attention as rpa
 
     monkeypatch.setattr(flash_attention, "_INTERPRET", False)
-    monkeypatch.setattr(rpa, "_tuned_impl", lambda *a, **k: "kernel")
+    monkeypatch.setattr(rpa, "_tuned_impl", lambda *a, **k: "kernel_p4")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()

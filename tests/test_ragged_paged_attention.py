@@ -1,6 +1,7 @@
 """Unified ragged-paged-attention: XLA arm vs dense reference, Pallas
-kernel (interpret mode) vs XLA arm, garbage-tail pinning, and the
-delegating ragged_prefill shim."""
+kernel (interpret mode) vs XLA arm at 1, 2 and 4 pages a grid step,
+garbage-tail pinning with zeros in padding rows, the VMEM gate and the
+candidates it admits, and the delegating ragged_prefill shim."""
 
 import numpy as np
 import pytest
@@ -10,6 +11,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     _ragged_paged_xla,
+    _slot,
+    _steer,
+    candidates_for,
     ragged_paged_attention,
     ragged_paged_attention_kernel,
     ragged_paged_supported,
@@ -19,7 +23,7 @@ from paddle_tpu.ops.pallas import ragged_prefill as shim
 
 def _dense_ref(q, k_pages, v_pages, rows, pos0, n_valid, sm_scale):
     """Numpy reference: per valid token, softmax over its causal keys
-    gathered from the block table."""
+    gathered from the block table; padding rows are zeros."""
     C, qb, nH, d = q.shape
     nkv = k_pages.shape[1]
     G = nH // nkv
@@ -30,8 +34,8 @@ def _dense_ref(q, k_pages, v_pages, rows, pos0, n_valid, sm_scale):
         ks = np.moveaxis(ks, 3, 1).reshape(-1, nkv, d)   # [mb*bs, nkv, d]
         vs = np.asarray(v_pages)[rows[c]]           # [mb, nkv, bs, d]
         vs = np.moveaxis(vs, 2, 1).reshape(-1, nkv, d)
-        for i in range(qb):
-            qpos = pos0[c] + min(i, n_valid[c] - 1)
+        for i in range(n_valid[c]):                 # padding rows stay zeros
+            qpos = pos0[c] + i
             n = qpos + 1
             for h in range(nH):
                 s = (np.asarray(q)[c, i, h].astype(np.float32)
@@ -68,35 +72,162 @@ def test_xla_arm_matches_dense_reference():
     np.testing.assert_allclose(np.asarray(got), ref, atol=2e-5, rtol=2e-5)
 
 
-def test_kernel_matches_xla_arm_mixed_batch():
-    # supported geometry: d=128, bs=128; interpret mode on CPU
-    rng = np.random.default_rng(1)
-    C, qb, nH, nkv, d, bs, mb, P = 3, 4, 4, 2, 128, 128, 3, 8
-    assert ragged_paged_supported((P, nkv, d, bs), nH, qb, 4)
-    kp = jnp.asarray(rng.normal(size=(P, nkv, d, bs)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(P, nkv, bs, d)), jnp.float32)
-    q = jnp.asarray(rng.normal(size=(C, qb, nH, d)), jnp.float32)
-    rows = jnp.asarray(rng.integers(0, P, size=(C, mb)), jnp.int32)
-    pos0 = jnp.asarray([200, 0, 131], jnp.int32)
-    n_valid = jnp.asarray([1, qb, 3], jnp.int32)
-    got = ragged_paged_attention_kernel(q, kp, vp, rows, pos0, n_valid,
-                                        0.5)
-    ref = _ragged_paged_xla(q, kp, vp, rows, pos0, n_valid, 0.5,
+# kernel geometry of the CPU cases: d=128, bs=128 (the gate's), 8 table
+# slots so that 1, 2 and 4 pages a step all divide, sink page 0
+_KG = dict(qb=4, nH=4, nkv=2, d=128, bs=128, mb=8, P=20)
+
+
+def _grid(kind, seed=1):
+    """(rows, pos0, n_valid) of one named grid at the _KG geometry."""
+    rng = np.random.default_rng(seed)
+    qb, bs, mb, P = _KG["qb"], _KG["bs"], _KG["mb"], _KG["P"]
+    if kind == "decode_only":       # one token a row at every depth
+        pos0 = np.array([0, 127, 128, 300, 511, 640, 1023], np.int32)
+        n_valid = np.ones_like(pos0)
+    elif kind == "mixed":           # decode, full, partial, tail chunks
+        pos0 = np.array([200, 0, 131, 900, 512], np.int32)
+        n_valid = np.array([1, qb, 3, 2, qb], np.int32)
+    elif kind == "straddle":        # chunks across a page boundary,
+        pos0 = np.array([126, 254, 509, 1021], np.int32)     # unaligned
+        n_valid = np.array([qb, 3, qb, 3], np.int32)
+    elif kind == "ends_in_group":   # context ends inside a group of 4
+        pos0 = np.array([130, 257, 640, 5], np.int32)        # or of 2;
+        n_valid = np.array([1, qb, 2, 1], np.int32)          # junk after
+    elif kind == "idle":            # idle rows: the sink page, pos0 0
+        pos0 = np.array([0, 0, 300, 0], np.int32)
+        n_valid = np.array([1, 1, qb, 1], np.int32)
+    C = len(pos0)
+    rows = rng.integers(1, P, size=(C, mb)).astype(np.int32)
+    if kind == "ends_in_group":     # ids no pool has, past the context
+        for c in range(C):
+            rows[c, (pos0[c] + n_valid[c] - 1) // bs + 1:] = 10 ** 6 + c
+    if kind == "idle":
+        rows[[0, 1, 3]] = 0
+    return rows, pos0, n_valid
+
+
+def _pool(seed, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    nkv, d, bs, P = _KG["nkv"], _KG["d"], _KG["bs"], _KG["P"]
+    return (jnp.asarray(rng.normal(size=(P, nkv, d, bs)), dtype),
+            jnp.asarray(rng.normal(size=(P, nkv, bs, d)), dtype))
+
+
+@pytest.mark.parametrize("pps", [1, 2, 4])
+@pytest.mark.parametrize("kind", ["decode_only", "mixed", "straddle",
+                                  "ends_in_group", "idle"])
+def test_kernel_matches_xla_arm(kind, pps):
+    """The kernel at each group size against the XLA arm, valid rows and
+    the zeros of padding rows alike (interpret mode on CPU)."""
+    rows, pos0, n_valid = _grid(kind)
+    kp, vp = _pool(1)
+    q = jnp.asarray(np.random.default_rng(3).normal(
+        size=(len(pos0), _KG["qb"], _KG["nH"], _KG["d"])), jnp.float32)
+    assert ragged_paged_supported(kp.shape, _KG["nH"], _KG["qb"], 4, pps)
+    got = ragged_paged_attention_kernel(
+        q, kp, vp, jnp.asarray(rows), jnp.asarray(pos0),
+        jnp.asarray(n_valid), 0.5, pps=pps)
+    # the XLA arm gathers every slot of the table: give it a table whose
+    # dead slots name a real page (any: their keys are masked)
+    last_page = (pos0 + n_valid - 1) // _KG["bs"]
+    live = np.arange(_KG["mb"])[None, :] <= last_page[:, None]
+    ref = _ragged_paged_xla(q, kp, vp, jnp.asarray(np.where(live, rows, 0)),
+                            jnp.asarray(pos0), jnp.asarray(n_valid), 0.5,
                             "d_major")
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=2e-5, rtol=2e-5)
+    pad = np.arange(_KG["qb"])[None, :] >= n_valid[:, None]
+    assert not np.asarray(got)[pad].any()
 
 
-@pytest.mark.parametrize("arm", ["xla", "kernel"])
-def test_garbage_tail_pinned(arm):
-    """Outputs (padding rows INCLUDED) must be invariant to garbage
-    beyond the last valid position: future page ids in the table and
-    key/value content past the mask."""
+def test_kernel_decode_tier_in_bf16_matches_xla_arm():
+    """bf16 operands take the decode tier at the bf16 sublane tile (16
+    query rows a head, G of them real)."""
+    rows, pos0, n_valid = _grid("mixed")
+    rng = np.random.default_rng(11)
+    kp, vp = _pool(12, jnp.bfloat16)
+    q = jnp.asarray(rng.normal(size=(len(pos0), 16, 4, 128)), jnp.bfloat16)
+    n_valid = np.where(n_valid == _KG["qb"], 16, n_valid)
+    args = (q, kp, vp, jnp.asarray(rows), jnp.asarray(pos0),
+            jnp.asarray(n_valid), 0.09)
+    got = ragged_paged_attention_kernel(*args, pps=4)
+    ref = _ragged_paged_xla(*args, "d_major")
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=2e-2, rtol=2e-2)
+
+
+@pytest.mark.parametrize("pps", [1, 2, 4])
+def test_dead_slots_repeat_a_block_the_row_already_named(pps):
+    """The index maps' rule: a live slot is its own; a dead slot repeats
+    what the same operand held in the row's last group that had it live
+    (no copy between the two steps), else the last live page; no dead
+    slot ever reads the table past the context."""
+    for last_page in range(0, 12):
+        held = {}
+        for j in range(12 // pps):
+            for i in range(pps):
+                k = int(_slot(j, i, pps, jnp.int32(last_page)))
+                assert 0 <= k <= last_page
+                if j * pps + i <= last_page:
+                    assert k == j * pps + i
+                elif i in held:
+                    assert k == held[i]         # same block: no DMA
+                else:
+                    assert k == last_page
+                held[i] = k
+
+
+@pytest.mark.parametrize("pps", [1, 2, 4])
+def test_dead_groups_name_the_next_rows_first_blocks(pps):
+    """A group past the context computes nothing and names what the next
+    row's first step names, so the only copy between a row's last live
+    step and the next row's first is the next row's own pages, made
+    early; the last row's dead groups repeat its own blocks.  Counted
+    over the whole grid, no block is copied that a live step does not
+    read or that the same operand did not already hold."""
+    bs, mb = 128, 8
+    pos0 = jnp.asarray([0, 300, 1023, 5, 640, 130], jnp.int32)
+    nv = jnp.asarray([1, 4, 1, 1, 3, 1], jnp.int32)
+    C = len(pos0)
+    last_page = [int((pos0[c] + nv[c] - 1) // bs) for c in range(C)]
+    table = np.arange(1, C * mb + 1).reshape(C, mb)     # distinct pages
+
+    def named(c, j):
+        cc, jj, lp = (int(x) for x in _steer(c, j, pos0, nv, C, pps, bs))
+        return cc, jj, [table[cc, int(_slot(jj, i, pps, lp))]
+                        for i in range(pps)]
+
+    copies, held = 0, [None] * pps
+    for c in range(C):
+        for j in range(mb // pps):
+            cc, jj, blocks = named(c, j)
+            if j * pps <= last_page[c]:
+                assert (cc, jj) == (c, j)
+            elif c + 1 < C:
+                assert (cc, jj) == (c + 1, 0)
+                assert blocks == named(c + 1, 0)[2]
+            else:
+                assert cc == c and blocks == held
+            copies += sum(b != h for b, h in zip(blocks, held))
+            held = blocks
+    # one copy per live page, plus the dead slots of a row's FIRST group
+    # (they name the row's last live page: pps - live of them)
+    want = sum(lp + 1 + max(0, pps - (lp + 1)) for lp in last_page)
+    assert copies == want
+
+
+@pytest.mark.parametrize("arm", ["xla", "kernel_p1", "kernel_p4"])
+def test_padding_rows_are_zeros_and_tail_garbage_is_invisible(arm):
+    """Padding rows i >= n_valid come out as ZEROS on both arms, and the
+    whole output is invariant to garbage beyond the last valid
+    position: future page ids in the table and key/value content past
+    the mask."""
     rng = np.random.default_rng(2)
-    if arm == "kernel":
-        C, qb, nH, nkv, d, bs, mb, P = 2, 2, 4, 2, 128, 128, 3, 8
-    else:
+    if arm == "xla":
         C, qb, nH, nkv, d, bs, mb, P = 2, 6, 4, 2, 32, 16, 4, 12
+    else:
+        C, qb, nH, nkv, d, bs, mb, P = 2, 4, 4, 2, 128, 128, 4, 10
     kp = np.asarray(rng.normal(size=(P, nkv, d, bs)), np.float32)
     vp = np.asarray(rng.normal(size=(P, nkv, bs, d)), np.float32)
     q = jnp.asarray(rng.normal(size=(C, qb, nH, d)), jnp.float32)
@@ -107,13 +238,16 @@ def test_garbage_tail_pinned(arm):
     n_valid = np.array([2, 1], np.int32)
 
     def run(kpx, vpx, rowsx):
-        a = (ragged_paged_attention_kernel if arm == "kernel"
-             else lambda *x: _ragged_paged_xla(*x, "d_major"))
+        a = ((lambda *x: _ragged_paged_xla(*x, "d_major")) if arm == "xla"
+             else (lambda *x: ragged_paged_attention_kernel(
+                 *x, pps=int(arm[-1]))))
         return np.asarray(a(q, jnp.asarray(kpx), jnp.asarray(vpx),
                             jnp.asarray(rowsx), jnp.asarray(pos0),
                             jnp.asarray(n_valid), 0.4))
 
     base = run(kp, vp, rows)
+    pad = np.arange(qb)[None, :] >= n_valid[:, None]
+    assert not base[pad].any() and base[~pad].all()
     # scramble table entries for pages wholly past each row's last pos
     rows2 = rows.copy()
     for c in range(C):
@@ -152,13 +286,43 @@ def test_supported_gate():
     assert not shim.ragged_prefill_supported((8, 2, 128, 16), 4, 4)
 
 
-def test_dispatcher_respects_autotune_impl_choice(monkeypatch):
-    """The impl axis ('kernel' vs 'xla') flows through the autotune
-    registry: whatever the registry answers is what runs."""
+@pytest.mark.parametrize("shape,nH,qb,mb,itemsize,want", [
+    # the serving cells' geometry: every group size fits, largest first
+    ((448, 8, 128, 128), 32, 16, 24, 2,
+     ["kernel_p4", "kernel_p2", "kernel_p1", "xla"]),
+    # a table of 6 slots: 4 does not divide it
+    ((448, 8, 128, 128), 32, 16, 6, 2, ["kernel_p2", "kernel_p1", "xla"]),
+    # 16 kv heads of 256: four pages a step are 16 MiB of double-buffered
+    # blocks (20.2 MiB in all), two are 8 (11.1 MiB)
+    ((64, 16, 256, 128), 32, 16, 24, 2, ["kernel_p2", "kernel_p1", "xla"]),
+    # the same in fp32: only one page a step fits (11.6 MiB)
+    ((64, 16, 256, 128), 32, 16, 24, 4, ["kernel_p1", "xla"]),
+    # pages of 256 tokens in fp32: not even one (20.1 MiB)
+    ((64, 16, 256, 256), 32, 16, 24, 4, ["xla"]),
+    # an unsupported head width never reaches the kernel
+    ((64, 8, 64, 128), 32, 16, 24, 2, ["xla"]),
+])
+def test_gate_admits_the_group_sizes_that_fit(shape, nH, qb, mb, itemsize,
+                                              want):
+    """What runs follows from the shapes: the VMEM estimate of each
+    group size, and whether it divides the block table."""
+    assert candidates_for(shape, nH, qb, mb, itemsize) == want
+    for n in (4, 2, 1):
+        if mb % n == 0:
+            assert ragged_paged_supported(shape, nH, qb, itemsize, n) == (
+                f"kernel_p{n}" in want)
+
+
+@pytest.mark.parametrize("impl", ["kernel_p4", "kernel_p2", "kernel_p1",
+                                  "xla"])
+def test_dispatcher_obeys_the_autotune_choice(monkeypatch, impl):
+    """The form ('kernel_p<n>' or 'xla') flows through the autotune
+    registry: whatever the registry answers is what runs, and it is
+    asked with the candidates the gate admits."""
     import paddle_tpu.ops.pallas.ragged_paged_attention as mod
 
     rng = np.random.default_rng(5)
-    C, qb, nH, nkv, d, bs, mb, P = 2, 4, 4, 2, 128, 128, 2, 5
+    C, qb, nH, nkv, d, bs, mb, P = 2, 4, 4, 2, 128, 128, 4, 9
     kp = jnp.asarray(rng.normal(size=(P, nkv, d, bs)), jnp.float32)
     vp = jnp.asarray(rng.normal(size=(P, nkv, bs, d)), jnp.float32)
     q = jnp.asarray(rng.normal(size=(C, qb, nH, d)), jnp.float32)
@@ -167,23 +331,65 @@ def test_dispatcher_respects_autotune_impl_choice(monkeypatch):
     n_valid = jnp.asarray([1, qb], jnp.int32)
     asked = []
 
-    def pin(impl):
-        def fake(C_, qb_, *a, **k):
-            asked.append((C_, qb_))
-            return impl
-        monkeypatch.setattr(mod, "_tuned_impl", fake)
+    def fake(C_, qb_, nH_, d_, nkv_, mb_, bs_, dtype, cands, quant=False):
+        asked.append((C_, qb_, tuple(cands)))
+        return impl
+    monkeypatch.setattr(mod, "_tuned_impl", fake)
+    ran = []
+    inner = mod.ragged_paged_attention_kernel
+    monkeypatch.setattr(
+        mod, "ragged_paged_attention_kernel",
+        lambda *a, pps: ran.append(pps) or inner(*a, pps=pps))
+    got = mod.ragged_paged_attention(q, kp, vp, rows, pos0, n_valid, 0.5)
+    if impl == "xla":
+        want = _ragged_paged_xla(q, kp, vp, rows, pos0, n_valid, 0.5,
+                                 "d_major")
+        assert ran == []
+    else:
+        want = inner(q, kp, vp, rows, pos0, n_valid, 0.5,
+                     pps=int(impl[-1]))
+        assert ran == [int(impl[-1])]
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    assert asked == [(C, qb, ("kernel_p4", "kernel_p2", "kernel_p1",
+                              "xla"))]
 
-    pin("xla")
-    got = mod.ragged_paged_attention(q, kp, vp, rows, pos0, n_valid, 0.5)
-    want = _ragged_paged_xla(q, kp, vp, rows, pos0, n_valid, 0.5,
-                             "d_major")
-    assert np.array_equal(np.asarray(got), np.asarray(want))
-    pin("kernel")
-    got = mod.ragged_paged_attention(q, kp, vp, rows, pos0, n_valid, 0.5)
-    want = ragged_paged_attention_kernel(q, kp, vp, rows, pos0, n_valid,
-                                         0.5)
-    assert np.array_equal(np.asarray(got), np.asarray(want))
-    assert asked == [(C, qb), (C, qb)]   # registry consulted per call
+
+def test_default_without_a_sweep_is_the_largest_group(monkeypatch):
+    """A backend that never sweeps (this CPU) gets candidates[0]: the
+    largest group the gate admits."""
+    import paddle_tpu.ops.pallas.ragged_paged_attention as mod
+    from paddle_tpu.ops.pallas import autotune
+
+    reg = autotune.AutotuneRegistry(path="/nonexistent/none.json",
+                                    committed="/nonexistent/none.json")
+    monkeypatch.setattr(autotune, "GLOBAL_AUTOTUNE", reg)
+    for mb, want in ((24, "kernel_p4"), (6, "kernel_p2"), (5, "kernel_p1")):
+        cands = candidates_for((9, 2, 128, 128), 4, 4, mb, 4)
+        assert mod._tuned_impl(2, 4, 4, 128, 2, mb, 128, jnp.float32,
+                               cands) == want
+
+
+@pytest.mark.parametrize("mb", [24, 16])
+def test_committed_table_serves_the_cells_geometry(mb):
+    """The serving cells (mb 24) and chip_smoke.py (mb 16) must find
+    their form in the tracked table under the kernel's CURRENT source
+    hash: a stale entry is a clean miss, every run's set-up then sweeps,
+    and the XLA arm's temporaries set the run's memory peak.  After an
+    edit to the kernel, set the entries' ``source`` to what
+    ``_autotune_source()`` returns (and re-measure if the form of the
+    step changed)."""
+    import json
+
+    from paddle_tpu.ops.pallas import autotune
+    from paddle_tpu.ops.pallas.ragged_paged_attention import \
+        _autotune_source
+
+    entries = json.load(open(autotune.COMMITTED_PATH))["entries"]
+    entry = entries["ragged_paged_attention|TPU v5 lite|"
+                    f"c32_qb16_h32_d128_kv8_mb{mb}_bs128|bfloat16"]
+    assert entry["source"] == _autotune_source()
+    assert entry["config"] in candidates_for((448, 8, 128, 128), 32, 16,
+                                             mb)[:-1]
 
 
 def _int8_case(seed, C, qb, nH, nkv, d, bs, mb, P):
@@ -220,15 +426,18 @@ def test_xla_arm_int8_matches_predequantized_pages():
     assert np.array_equal(np.asarray(got), np.asarray(ref))
 
 
-def test_kernel_int8_matches_xla_arm():
+@pytest.mark.parametrize("pps", [1, 2, 4])
+def test_kernel_int8_matches_xla_arm(pps):
     """Pallas kernel with scalar-prefetched scale planes vs the XLA arm,
-    on the supported geometry (d=128, bs=128; interpret mode)."""
+    on the supported geometry (d=128, bs=128; interpret mode): each page
+    slot of a group finds its own (page, head) scale."""
     q, kq, vq, ks, vs, _, _, rows = _int8_case(8, 3, 4, 4, 2, 128, 128,
-                                               3, 8)
-    pos0 = jnp.asarray([200, 0, 131], jnp.int32)
+                                               4, 8)
+    pos0 = jnp.asarray([200, 0, 387], jnp.int32)
     n_valid = jnp.asarray([1, 4, 3], jnp.int32)
     got = ragged_paged_attention_kernel(q, kq, vq, rows, pos0, n_valid,
-                                        0.5, k_scales=ks, v_scales=vs)
+                                        0.5, k_scales=ks, v_scales=vs,
+                                        pps=pps)
     ref = _ragged_paged_xla(q, kq, vq, rows, pos0, n_valid, 0.5,
                             "d_major", k_scales=ks, v_scales=vs)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),

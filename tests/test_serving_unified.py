@@ -516,3 +516,37 @@ def test_chunks_of_one_request_are_adjacent_rows():
         owned = [set(ptable[s][ptable[s] > 0].tolist())
                  for s in set(row_slot[live].tolist())]
         assert sum(map(len, owned)) == len(set().union(*owned)), ptable
+
+
+def test_padding_rows_of_the_attention_never_reach_a_served_token(
+        monkeypatch):
+    """The attention's contract for padding rows changed (zeros from
+    both arms, where both used to repeat the last valid row's mask):
+    nothing served may depend on them.  A mixed batch with tail chunks
+    (1 < n_valid < qb) beside decode rows serves the same tokens when
+    the attention leaves 1e4 in every padding row instead (finite, as
+    the old rows were: the last-valid pick is a one-hot product)."""
+    import paddle_tpu.ops.pallas.ragged_paged_attention as rpa
+
+    base, _, engine = _run(qb=16, prefill_budget=64)
+    inner, tails = engine._unified, []
+
+    def recording(*args):
+        tails.append(np.asarray(args[10]))
+        return inner(*args)
+
+    engine._unified = recording
+    engine.run(_mk_reqs(np.random.RandomState(11), sampled=True))
+    n_valid = np.concatenate(tails)
+    assert ((n_valid > 1) & (n_valid < 16)).any() and (n_valid == 1).any()
+
+    real = rpa.ragged_paged_attention
+
+    def poisoned(q, k_pages, v_pages, rows, pos0, n_valid, *a, **k):
+        o = real(q, k_pages, v_pages, rows, pos0, n_valid, *a, **k)
+        pad = jnp.arange(q.shape[1])[None, :] >= n_valid[:, None]
+        return jnp.where(pad[:, :, None, None], 1e4, o)
+
+    monkeypatch.setattr(rpa, "ragged_paged_attention", poisoned)
+    got, _, _ = _run(qb=16, prefill_budget=64)
+    assert got == base
