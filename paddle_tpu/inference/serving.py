@@ -45,9 +45,7 @@ from __future__ import annotations
 
 import dataclasses
 import hashlib
-import math
 import time
-import warnings
 import zlib
 from typing import Optional
 
@@ -58,10 +56,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..core.flags import GLOBAL_FLAGS
-from ..models.llama import (LlamaConfig, LlamaServing, apply_rope,
-                            quantize_weights_int8, rms_norm, rope_angles,
-                            _mm)
-from ..models.seam import LayerGroup
+from ..models.llama import (LlamaConfig, LlamaServing,
+                            quantize_weights_int8)
 from ..obs import clock as _clock
 from ..testing import chaos as _chaos
 from .. import obs as _obs
@@ -174,8 +170,8 @@ def wire_scatter_pages(pages, pg, payload):
     return pages.at[:, pg].set(payload)
 
 
-def _scan_layers(body, x, k_pages, v_pages, xs):
-    """The unified steps' layer loop, with the KV pool as a loop CARRY.
+def _run_layer_groups(body, x, k_pages, v_pages, groups, side=()):
+    """The unified step's layer loop, with the KV pool as a loop CARRY.
 
     The addressing rule, stated once: outside the program the pool is
     ``[L, P, ...]``; inside it is ``[L*P, ...]`` (a reshape of the two
@@ -185,45 +181,44 @@ def _scan_layers(body, x, k_pages, v_pages, xs):
     pool scanned that way can never be updated where it lies; the carry
     can, and the donated input buffer becomes the output.
 
-    ``body(x, k_pool, v_pool, base, layer_xs) -> (x, k_pool, v_pool, ys)``
-    sees the flattened pools and ``base = l*P`` to add to every page id
-    it scatters to or attends over. Returns ``(x, k_pages, v_pages, ys)``
+    ``body(x, k_pool, v_pool, base, layer_xs, *side) -> (x, k_pool,
+    v_pool, ys, *side)`` sees the flattened pools and ``base = l*P`` to
+    add to every page id it scatters to or attends over. ``groups``
+    (models/seam.py: LayerGroup) cover the layers in order: a stacked
+    group is scanned over its own layers ``first .. first+count-1``, a
+    single unstacked layer is applied where it stands. The side planes
+    (``[L, P, ...]``, small) do travel scanned: a layer gets its own
+    ``[P, ...]`` slice under the layer's own page ids and its update is
+    stacked. Returns ``(x, k_pages, v_pages, [ys per group], side)``
     with the pools back in their ``[L, P, ...]`` shape."""
-    L = k_pages.shape[0]
-    x, kp, vp, (ys,) = _run_layer_groups(
-        body, x, k_pages, v_pages, [LayerGroup(0, L, xs)])
-    return x, kp, vp, ys
-
-
-def _run_layer_groups(body, x, k_pages, v_pages, groups):
-    """``_scan_layers`` for a model whose layers are not all alike: the
-    pools are flattened once, each stacked group of ``groups`` is scanned
-    over its own layers ``first .. first+count-1`` (the same carry, the
-    same ``l*P`` rule), a single unstacked layer is applied where it
-    stands. Returns ``(x, k_pages, v_pages, [ys per group])``."""
     L, P = k_pages.shape[:2]
 
     def step(carry, inp):
-        l, layer_xs = inp
-        x, kp, vp, ys = body(*carry, l * P, layer_xs)
-        return (x, kp, vp), ys
+        l, layer_xs, side_l = inp
+        x, kp, vp, ys, *side_l = body(*carry, l * P, layer_xs, *side_l)
+        return (x, kp, vp), (ys, tuple(side_l))
 
     carry = (x, k_pages.reshape((L * P,) + k_pages.shape[2:]),
              v_pages.reshape((L * P,) + v_pages.shape[2:]))
-    all_ys = []
+    all_ys, parts = [], []
     for g in groups:
+        side_g = tuple(s[g.first:g.first + g.count] for s in side)
         if g.stacked:
-            carry, ys = lax.scan(
+            carry, (ys, side_g) = lax.scan(
                 step, carry,
                 (jnp.arange(g.first, g.first + g.count, dtype=jnp.int32),
-                 g.xs))
+                 g.xs, side_g))
         else:
-            *carry, ys = body(*carry, g.first * P, g.xs)
-            carry = tuple(carry)
+            carry, (ys, side_g) = step(
+                carry, (g.first, g.xs, tuple(s[0] for s in side_g)))
+            side_g = tuple(s[None] for s in side_g)
         all_ys.append(ys)
+        parts.append(side_g)
     x, kp, vp = carry
+    side = tuple(p[0] if len(p) == 1 else jnp.concatenate(p)
+                 for p in zip(*parts))
     return (x, kp.reshape(k_pages.shape), vp.reshape(v_pages.shape),
-            all_ys)
+            all_ys, side)
 
 
 def kv_scale_reset(scales, page_ids, axis: int = 0):
@@ -248,9 +243,10 @@ def kv_admit_first_write(pages, scales, page_ids, tokens,
     *previous* tenant's running absmaxes), ``page_ids`` [N] int32,
     ``tokens`` [N, nKV, bs, d] fp32.
 
-    ``_zero_scale_on_alloc`` mirrors the engine attribute of the same
-    name: True is the shipped path (kv_scale_reset before the first
-    kv_scale_update); False rebuilds the pre-PR 8 program where the
+    ``_zero_scale_on_alloc``: True is the shipped path (the engine
+    zeroes a reallocated page's side-plane entries, models/seam.py:
+    kv_scale_reset before the first kv_scale_update); False rebuilds
+    the pre-PR 8 program where the
     prior tenant's absmax survives into the new tenant's quantize —
     tools/lint/quantcheck.py traces both and proves TPL303
     (scale-provenance-mismatch) fires exactly on the False variant."""
@@ -354,13 +350,18 @@ class _PagePool:
 
 
 class ServingEngine:
-    """Continuous-batching LLaMA serving over paged KV.
+    """Continuous-batching serving over paged KV, for any model behind
+    the seam (models/seam.py).
 
     ``step()`` = admissions + ONE unified ragged-paged-attention
     dispatch (decode rows + prefill chunks in the same token grid) +
     harvest of the previous dispatch; ``run(requests)`` drives
     wall-clock arrivals to completion and returns latency/throughput/
-    occupancy stats.
+    occupancy stats. There is one step program (``_unified_step_impl``)
+    for every model and every page format: the engine allocates,
+    carries, donates, resets and sizes what the model's ``cache_spec``
+    declares (``kv_quant=`` makes LLaMA declare int8 pages with two
+    scale planes beside them).
     """
 
     def __init__(self, cfg, params: Optional[dict] = None,
@@ -369,7 +370,6 @@ class ServingEngine:
                  prefill_budget: Optional[int] = None,
                  prefix_cache: Optional[bool] = None,
                  prefix_cache_pages: Optional[int] = None,
-                 decode_quantum: Optional[int] = None,
                  admit_aging: int = 64,
                  weight_only_int8: Optional[bool] = None,
                  qb: Optional[int] = None,
@@ -384,15 +384,6 @@ class ServingEngine:
                  engine_id: int = 0,
                  prefill_only: bool = False,
                  wire_overlap: Optional[bool] = None):
-        if decode_quantum is not None:
-            # the unified step (PR 7) has no decode-quantum boundary;
-            # the kwarg was previously swallowed silently
-            warnings.warn(
-                "ServingEngine(decode_quantum=...) is deprecated and has "
-                "no effect: the unified ragged-paged-attention step has "
-                "no decode-quantum boundary", DeprecationWarning,
-                stacklevel=2)
-        self.decode_quantum = max(1, decode_quantum or 8)  # legacy attr
         # fleet identity: names this replica in router health/stats and
         # targets chaos specs (fire(..., ctx={"engine": id})); a lone
         # engine keeps the default 0 and never consults it otherwise
@@ -419,7 +410,12 @@ class ServingEngine:
         # here, any other config brings its own
         if lora is None:
             lora = GLOBAL_FLAGS.get("serving_lora")
-        self.model = (LlamaServing(cfg, lora=bool(lora))
+        if kv_quant is None:
+            kv_quant = GLOBAL_FLAGS.get("serving_kv_quant")
+        # read by the migration wire alone (shipments name their format)
+        self._kv_quant = bool(kv_quant)
+        self.model = (LlamaServing(cfg, lora=bool(lora),
+                                   kv_quant=self._kv_quant)
                       if isinstance(cfg, LlamaConfig)
                       else cfg.serving_model())
         self.params = params if params is not None else \
@@ -459,15 +455,6 @@ class ServingEngine:
             speculative_k = GLOBAL_FLAGS.get("serving_speculative_k")
         if spec_ngram is None:
             spec_ngram = GLOBAL_FLAGS.get("serving_spec_ngram")
-        if kv_quant is None:
-            kv_quant = GLOBAL_FLAGS.get("serving_kv_quant")
-        self._kv_quant = bool(kv_quant)
-        # the PR 8 scale-leak fix as a named hook: _alloc_pages zeroes a
-        # reused page's scale-plane entries before the new tenant's first
-        # write (kv_scale_reset). tools/lint/quantcheck.py flips this off
-        # to rebuild the pre-fix program and prove TPL303 fires on it —
-        # production engines never disable it.
-        self._zero_scale_on_alloc = True
         # overlapped migration wire (serving_wire_overlap): export stages
         # an async device->host copy chained after the in-flight program
         # instead of a blocking chain sync, and adoption commits fold
@@ -483,7 +470,6 @@ class ServingEngine:
         self.qb = max(1, qb)
         self.n_rows = max(1, prefill_budget // self.qb, max_batch)
         self.prefill_budget = self.n_rows * self.qb
-        self.n_chunks = self.n_rows       # historical alias (pre-PR 7)
         # a decode row holds 1 input token + up to qb-1 verified drafts
         self.spec_k = max(0, min(int(speculative_k), self.qb - 1))
         if self.spec_k:
@@ -515,27 +501,20 @@ class ServingEngine:
                 "serving_constrained is incompatible with "
                 "serving_speculative_k: a constraint mask covers one "
                 "sampling position per row, not a k-token draft ladder")
-        # what a token stores in a layer is the model's to say; the pool
-        # is the spec's pair of planes, [L, P, *page_shape] each
+        # what a token stores in a layer, and what a page keeps beside
+        # its tokens, is the model's to say: the pool is the spec's pair
+        # of planes and its side planes, [L, P, *page_shape] each,
+        # everywhere outside the step program; inside, layer l finds
+        # page p at l*P + p (_run_layer_groups), and page 0 of each
+        # layer is its sink.
         L = self.model.n_layers
         self.cache_spec = spec = self.model.cache_spec(self.bs)
-        nKV = spec.planes[0].page_shape[0]     # scale planes: GQA pages
-        # serving_kv_quant: pages are symmetric int8 with a per-page,
-        # per-head fp32 scale plane per layer — KV bytes per token drop
-        # from 2*itemsize*nKV*dH to 2*nKV*dH (+ amortized scales), so a
-        # fixed-byte pool holds ~2x the sequences (kv_bytes_per_token()).
-        # The pool is [L, P, ...] everywhere outside the step program;
-        # inside, layer l finds page p at l*P + p (_scan_layers), and
-        # page 0 of each layer is its sink.
-        page_dtype = jnp.int8 if self._kv_quant else spec.dtype
         self.k_pages, self.v_pages = (
-            jnp.zeros((L, self.n_pages) + plane.page_shape, page_dtype)
+            jnp.zeros((L, self.n_pages) + plane.page_shape, spec.dtype)
             for plane in spec.planes)
-        if self._kv_quant:
-            self.k_scales = jnp.zeros((L, self.n_pages, nKV), jnp.float32)
-            self.v_scales = jnp.zeros((L, self.n_pages, nKV), jnp.float32)
-        else:
-            self.k_scales = self.v_scales = None
+        self.side_planes = {
+            plane.name: jnp.zeros((L, self.n_pages) + plane.page_shape,
+                                  plane.dtype) for plane in spec.side}
         _obs.instant("engine.cache_spec", engine=self.engine_id,
                      bytes_per_token=self.kv_bytes_per_token(),
                      planes=",".join(f"{p.name}:{p.width}"
@@ -583,12 +562,11 @@ class ServingEngine:
         else:
             self.adapters = None
         self._schemas: dict = {}           # schema id -> ConstraintState factory
-        if self._kv_quant:
-            self._unified = jax.jit(self._unified_step_impl_q,
-                                    donate_argnums=(1, 2, 3, 4))
-        else:
-            self._unified = jax.jit(self._unified_step_impl,
-                                    donate_argnums=(1, 2))
+        # the pools and the side planes (the first of ``rest``) are the
+        # step's to update where they lie
+        self._unified = jax.jit(
+            self._unified_step_impl,
+            donate_argnums=(1, 2) + tuple(range(14, 14 + len(spec.side))))
         # pipelining state (see step() docstring): _inflight holds the
         # dispatched-but-unharvested program's (output tokens, row
         # snapshot); _prev_out_dev chains row outputs on-device into the
@@ -648,7 +626,7 @@ class ServingEngine:
     def _unified_step_impl(self, params, k_pages, v_pages, tokens,
                            prev_out, chain_mask, chain_row, ptable,
                            row_slot, pos0, n_valid, temps, topps, seeds,
-                           *mt_ops):
+                           *rest):
         """THE engine step: one ``[n_rows, qb]`` unified ragged-paged-
         attention program serving an arbitrary prefill/decode mix. Row c
         holds n_valid[c] tokens of request row_slot[c] starting at
@@ -666,25 +644,29 @@ class ServingEngine:
         serializing with it (its cost is not measured on a locally
         attached chip).
 
-        Returns (out, k_pages, v_pages, ys); ys holds the layers'
+        Returns (out, k_pages, v_pages, ys, side); ys holds the layers'
         counters, one entry per layer group, None for a group that keeps
-        none (models/seam.py): out [C, 1] — each row's pick
-        after its last valid token — or [C, qb] with per-position picks
-        when speculative verification needs the full ladder. Per-token
-        KV write (ops/pallas/paged_kv_write.py): valid tokens land at
-        their own (page, offset), padding never lands in request pages
-        (write-before-attend, per layer). The pool is [L, P, ...] at
-        this boundary and donated; the layers see it as a loop carry
-        under _scan_layers' (l*P + p) addressing, so it is updated where
-        it lies."""
+        none (models/seam.py), side the updated side planes (an empty
+        tuple for a page format without any): out [C, 1] — each row's
+        pick after its last valid token — or [C, qb] with per-position
+        picks when speculative verification needs the full ladder.
+        Per-token KV write (ops/pallas/paged_kv_write.py): valid tokens
+        land at their own (page, offset), padding never lands in request
+        pages (write-before-attend, per layer). The pool is [L, P, ...]
+        at this boundary and donated; the layers see it as a loop carry
+        under _run_layer_groups' (l*P + p) addressing, so it is updated
+        where it lies."""
         model = self.model
         C, qb = tokens.shape
 
-        # multi-tenant operands ride as trailing varargs so the default
-        # (flags off) trace is literally the legacy trace: row adapter
-        # slot ids + the four adapter stacks (serving_lora), then the
-        # per-row [C, V] vocab legality mask (serving_constrained)
-        mt = list(mt_ops)
+        # what only some engines have rides as trailing varargs, so the
+        # default trace is literally the legacy trace: the page format's
+        # side planes (cache_spec.side), then the multi-tenant operands —
+        # row adapter slot ids + the four adapter stacks (serving_lora),
+        # then the per-row [C, V] vocab legality mask
+        # (serving_constrained)
+        n_side = len(self.cache_spec.side)
+        side, mt = rest[:n_side], list(rest[n_side:])
         if self._lora_on:
             aid, ast = mt.pop(0), mt.pop(0)
         vmask = mt.pop(0) if self._constr_on else None
@@ -700,11 +682,12 @@ class ServingEngine:
         else:
             groups = model.layer_groups(params)
 
-        def body(x, kp, vp, base, inp):
+        def body(x, kp, vp, base, inp, *side_l):
             return model.apply(x, kp, vp, base, inp, rows, pos0, n_valid,
-                               ctx)
+                               ctx, *side_l)
 
-        x, ks, vs, ys = _run_layer_groups(body, x, k_pages, v_pages, groups)
+        x, ks, vs, ys, side = _run_layer_groups(body, x, k_pages, v_pages,
+                                                groups, side)
         x = model.head(params, x)
         if self.spec_k:
             # speculative verify needs the model's pick at EVERY draft
@@ -732,14 +715,13 @@ class ServingEngine:
             out = _pick_tokens(logits, temps, topps, seeds,
                                pos0 + n_valid - 1)[:, None]
         # the layers' counters ride out with the picks
-        return out, ks, vs, ys
+        return out, ks, vs, ys, side
 
     def unified_arg_shapes(self) -> tuple:
-        """Shape-only arguments of the unified step (either program),
-        mirroring the live ``self._unified(...)`` dispatch exactly —
-        for tracing it (``trace_unified``, ``trace_unified_quant``) or
-        lowering it (``lower_unified``) with no device executing
-        anything."""
+        """Shape-only arguments of the unified step, mirroring the live
+        dispatch (``_dispatch_unified``) exactly — for tracing it
+        (``trace_unified``) or lowering it (``lower_unified``) with no
+        device executing anything."""
         if self._lora_on or self._constr_on:
             raise NotImplementedError(
                 "unified_arg_shapes covers the non-multitenant programs; "
@@ -749,9 +731,6 @@ class ServingEngine:
         def sds(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
 
-        pool = (sds(self.k_pages), sds(self.v_pages))
-        if self._kv_quant:
-            pool += (sds(self.k_scales), sds(self.v_scales))
         i32, f32 = jnp.int32, jnp.float32
         tokens = jax.ShapeDtypeStruct((C, qb), i32)
         prev = jax.ShapeDtypeStruct((C, qb if self.spec_k else 1), i32)
@@ -760,9 +739,10 @@ class ServingEngine:
         ptab = jax.ShapeDtypeStruct((B + 1, self.max_blocks), i32)
         col_i = jax.ShapeDtypeStruct((C,), i32)
         col_f = jax.ShapeDtypeStruct((C,), f32)
-        return (jax.tree.map(sds, self.params),) + pool + (
-            tokens, prev, cmask, crow, ptab, col_i, col_i, col_i, col_f,
-            col_f, col_i)
+        return (jax.tree.map(sds, self.params), sds(self.k_pages),
+                sds(self.v_pages), tokens, prev, cmask, crow, ptab, col_i,
+                col_i, col_i, col_f, col_f, col_i,
+                *map(sds, self.side_planes.values()))
 
     def lower_unified(self):
         """The live jitted unified step lowered at its dispatch shapes
@@ -770,185 +750,13 @@ class ServingEngine:
         return self._unified.lower(*self.unified_arg_shapes())
 
     def trace_unified(self):
-        """Trace the (non-quant) unified step to a closed jaxpr — the
-        ``serving_unified`` entry program tools/lint/shardcheck.py
-        propagates partition specs through."""
-        if self._kv_quant:
-            raise NotImplementedError(
-                "trace_unified covers the base engine; use "
-                "trace_unified_quant for the serving_kv_quant program")
+        """Trace the unified step to a closed jaxpr, shape-only — the
+        entry program tools/lint/shardcheck.py propagates partition
+        specs through and tools/lint/quantcheck.py interprets over the
+        precision lattice (an int8 engine's scale planes, the last two
+        operands, are the TPL303 provenance roots)."""
         return jax.make_jaxpr(self._unified_step_impl)(
             *self.unified_arg_shapes())
-
-    def trace_unified_quant(self):
-        """``trace_unified`` for the ``serving_kv_quant`` engine: the
-        int8 step with its two scale-plane operands, traced shape-only.
-        This is the ``serving_unified_int8kv`` entry program
-        tools/lint/quantcheck.py interprets over the precision lattice
-        (the scale planes are the TPL303 provenance roots)."""
-        if not self._kv_quant:
-            raise NotImplementedError(
-                "trace_unified_quant covers the serving_kv_quant "
-                "program; use trace_unified for the base engine")
-        return jax.make_jaxpr(self._unified_step_impl_q)(
-            *self.unified_arg_shapes())
-
-    def _unified_step_impl_q(self, params, k_pages, v_pages, k_scales,
-                             v_scales, tokens, prev_out, chain_mask,
-                             chain_row, ptable, row_slot, pos0, n_valid,
-                             temps, topps, seeds, *mt_ops):
-        """``serving_kv_quant`` variant of the unified step: pages are
-        int8, each layer's scatter writes quantized pages and maintains
-        the per-page, per-head scale plane, and the attention call
-        dequantizes in-kernel (both RPA arms).
-
-        A page fills incrementally, so its scale is a *running absmax*:
-
-        1. scatter-max the plane with this chunk's token absmaxes
-           (commutative — deterministic under duplicate page ids);
-        2. rescale the previously written int8 content of every page a
-           chunk straddles onto the new scale (exact no-op when the
-           scale did not grow; duplicate writes across rows of one
-           request produce identical bytes, so order cannot matter);
-        3. quantize the new tokens against the updated scale and
-           write them per (page, offset) exactly like the bf16 path.
-
-        The pages travel as in the bf16 step (a carry under
-        _scan_layers' (l*P + p) addressing; steps 2 and 3 add ``l*P``);
-        the fp32 scale planes stay scanned per layer, [P, nKV] under the
-        layer's own page ids, because the attention kernel holds one
-        layer's plane in SMEM and finds a page's scale by the id it
-        finds the page by.
-
-        Speculative rollback and aborts need no extra handling: a
-        rejected draft's or reused page's *content* is overwritten
-        before it can be attended (same argument as the bf16 path), and
-        a page's scale plane entry is reset to 0 when the allocator
-        hands the page to a new request (_admit), so stale absmaxes
-        cannot degrade a later tenant's precision."""
-        cfg = self.cfg
-        C, qb = tokens.shape
-        nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        from ..ops.pallas.paged_kv_write import paged_kv_write
-        from ..ops.pallas.ragged_paged_attention import \
-            ragged_paged_attention
-        from ..ops.quant import (kv_scale_update, quantize_to_scale,
-                                 rescale_int8)
-
-        mt = list(mt_ops)                  # same layout as the bf16 impl
-        if self._lora_on:
-            aid, ast = mt.pop(0), mt.pop(0)
-        vmask = mt.pop(0) if self._constr_on else None
-        from ..ops.pallas.lora_matmul import lora_matmul
-
-        tok0 = jnp.where(chain_mask, prev_out[chain_row, 0], tokens[:, 0])
-        tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
-        rows = ptable[row_slot]                      # [C, max_blocks]
-        positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
-        valid = jnp.arange(qb, dtype=jnp.int32)[None, :] < n_valid[:, None]
-        pages = jnp.where(
-            valid, jnp.take_along_axis(rows, positions // self.bs, axis=1),
-            0).reshape(-1)                           # padding -> sink
-        # every page this step's chunks might straddle (per row: the
-        # first written page plus any the qb-token span can spill
-        # into); entries past a row's span hit its future pages or the
-        # sink, where rescaling is the exact no-op described above
-        npw = (qb - 1) // self.bs + 2
-        blk_rw = jnp.clip(
-            pos0[:, None] // self.bs
-            + jnp.arange(npw, dtype=jnp.int32)[None, :],
-            0, self.max_blocks - 1)
-        pages_rw = jnp.take_along_axis(rows, blk_rw, axis=1).reshape(-1)
-        with jax.named_scope("embed"):
-            x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
-            cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
-            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-        sm_scale = 1.0 / math.sqrt(dH)
-
-        def body(x, kp, vp, base, inp):
-            if self._lora_on:
-                bp, ksc, vsc, aq_l, bq_l, av_l, bv_l = inp
-            else:
-                bp, ksc, vsc = inp
-            with jax.named_scope("layer/qkv"):
-                h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
-                q = _mm(h, bp["wq"], cfg)
-                k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
-                v = _mm(h, bp["wv"], cfg)
-                if self._lora_on:
-                    q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
-                    v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
-                q = q.reshape(C, qb, nH, dH)
-                v = v.reshape(C, qb, nKV, dH)
-                q = apply_rope(q, cos, sin)
-                k = apply_rope(k, cos, sin)
-            with jax.named_scope("layer/kv_write"):
-                kf = k.reshape(C * qb, nKV, dH).astype(jnp.float32)
-                vf = v.reshape(C * qb, nKV, dH).astype(jnp.float32)
-                ksc_new = kv_scale_update(
-                    ksc, pages, jnp.max(jnp.abs(kf), axis=-1) / 127.0)
-                vsc_new = kv_scale_update(
-                    vsc, pages, jnp.max(jnp.abs(vf), axis=-1) / 127.0)
-                kp = kp.at[pages_rw + base].set(rescale_int8(
-                    kp[pages_rw + base],
-                    jnp.take(ksc, pages_rw, axis=0)[:, :, None, None],
-                    jnp.take(ksc_new, pages_rw, axis=0)[:, :, None, None]))
-                vp = vp.at[pages_rw + base].set(rescale_int8(
-                    vp[pages_rw + base],
-                    jnp.take(vsc, pages_rw, axis=0)[:, :, None, None],
-                    jnp.take(vsc_new, pages_rw, axis=0)[:, :, None, None]))
-                kp, vp = paged_kv_write(
-                    kp, vp,
-                    quantize_to_scale(
-                        kf, jnp.take(ksc_new, pages, axis=0)[:, :, None]
-                    ).reshape(C, qb, nKV, dH),
-                    quantize_to_scale(
-                        vf, jnp.take(vsc_new, pages, axis=0)[:, :, None]
-                    ).reshape(C, qb, nKV, dH),
-                    rows + base, pos0, n_valid, sink=base)
-            with jax.named_scope("layer/attn"):
-                # the attention finds a page's scale by the id it finds
-                # the page by, and the scale planes stay one layer's
-                # [P, nKV] (they ride SMEM): so it gets this layer's
-                # pages under their local ids, which is a copy of them
-                P = ksc.shape[0]
-                o = ragged_paged_attention(
-                    q, lax.dynamic_slice_in_dim(kp, base, P),
-                    lax.dynamic_slice_in_dim(vp, base, P), rows, pos0,
-                    n_valid, sm_scale, k_layout="d_major",
-                    k_scales=ksc_new, v_scales=vsc_new)
-                x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
-            with jax.named_scope("layer/mlp"):
-                h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
-                x = x + _mm(jax.nn.silu(
-                    _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
-                        cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
-            return x, kp, vp, (ksc_new, vsc_new)
-
-        xs = (params["blocks"], k_scales, v_scales)
-        if self._lora_on:
-            xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
-        x, ks, vs, (kss, vss) = _scan_layers(body, x, k_pages, v_pages, xs)
-        with jax.named_scope("head"):
-            x = rms_norm(x, params["final_norm"], cfg.rms_eps)
-        if self.spec_k:
-            with jax.named_scope("head"):
-                logits = _mm(x, params["head"], cfg).astype(jnp.float32)
-            picks = _pick_tokens(
-                logits.reshape(C * qb, -1), jnp.repeat(temps, qb),
-                jnp.repeat(topps, qb), jnp.repeat(seeds, qb),
-                positions.reshape(-1))
-            out = picks.reshape(C, qb)
-        else:
-            with jax.named_scope("head"):
-                last = x[jnp.arange(C), n_valid - 1]     # [C, H]
-                logits = _mm(last[:, None], params["head"], cfg).astype(
-                    jnp.float32)[:, 0]
-            if self._constr_on:
-                logits = jnp.where(vmask, logits, -1e30)
-            out = _pick_tokens(logits, temps, topps, seeds,
-                               pos0 + n_valid - 1)[:, None]
-        return out, ks, vs, kss, vss
 
     # -- scheduler ----------------------------------------------------------
 
@@ -1066,19 +874,16 @@ class ServingEngine:
         out: list[bytes] = []
         # the hash preimage covers everything that determines a cached
         # page's bytes: the prefix tokens, the page size, and the KV
-        # representation. Under serving_kv_quant the stored bytes are
-        # the quantized page + its scale-plane entries — a deterministic
-        # function of the prefix tokens given the quant mode — so
-        # tagging the seed keeps int8 and bf16 page content from ever
-        # aliasing in the cache. ``salt`` extends the same argument to
-        # per-request LoRA: the v-projection delta changes the page
-        # bytes, so the adapter's content digest joins the preimage
+        # representation (cache_spec.hash_tag). For int8 pages the
+        # stored bytes are the quantized page + its scale-plane entries
+        # — a deterministic function of the prefix tokens given the
+        # format — so tagging the seed keeps int8 and bf16 page content
+        # from ever aliasing in the cache. ``salt`` extends the same
+        # argument to per-request LoRA: the v-projection delta changes
+        # the page bytes, so the adapter's content digest joins the preimage
         # (same-adapter requests still share; cross-adapter never alias).
-        seed = b"pt-prefix:%d" % self.bs
-        if self._kv_quant:
-            seed += b":kvq8"
-        seed += salt
-        h = hashlib.sha1(seed)
+        h = hashlib.sha1(b"pt-prefix:%d" % self.bs
+                         + self.cache_spec.hash_tag + salt)
         for j in range(n_full):
             h.update(np.ascontiguousarray(
                 prompt[j * self.bs:(j + 1) * self.bs],
@@ -1113,15 +918,17 @@ class ServingEngine:
                and self.adapters._evict_idle()):
             pass
         pages = self.pool.alloc(n)
-        if self._kv_quant and pages and self._zero_scale_on_alloc:
-            # a reused page's stale running-absmax would quantize the
-            # new tenant's tokens against a garbage (possibly inflated)
-            # scale; zeroing at allocation makes the first write set a
-            # fresh scale. Chained after any in-flight step's donated
-            # output, so programs already dispatched are unaffected.
+        if pages and self.side_planes:
+            # a reused page's stale side-plane entries (an int8 page's
+            # running absmax would quantize the new tenant's tokens
+            # against a garbage, possibly inflated, scale) are zeroed at
+            # allocation, so the first write sets fresh ones. Chained
+            # after any in-flight step's donated output, so programs
+            # already dispatched are unaffected.
             pg = jnp.asarray(pages, jnp.int32)
-            self.k_scales = kv_scale_reset(self.k_scales, pg, axis=1)
-            self.v_scales = kv_scale_reset(self.v_scales, pg, axis=1)
+            self.side_planes = {
+                name: kv_scale_reset(plane, pg, axis=1)
+                for name, plane in self.side_planes.items()}
         return pages
 
     def _admit(self, now: float) -> None:
@@ -1574,26 +1381,18 @@ class ServingEngine:
         # state — every operand is a fresh local array here, but
         # jnp.array (copying) keeps the handoff alias-free by
         # construction.
-        extra = []                          # multi-tenant varargs
+        extra = []              # multi-tenant varargs, behind the side planes
         if self._lora_on:
             extra += [jnp.array(aidv), self.adapters.stacks()]
         if self._constr_on:
             extra.append(jnp.array(vm))
-        ys = ()         # the layers' counters (the fp step's groups)
-        if self._kv_quant:
-            (out, self.k_pages, self.v_pages, self.k_scales,
-             self.v_scales) = self._unified(
-                self.params, self.k_pages, self.v_pages, self.k_scales,
-                self.v_scales, jnp.array(tokens), prev_out,
-                jnp.array(cmask), jnp.array(crow), jnp.array(ptab),
-                jnp.array(rs), jnp.array(p0), jnp.array(nv),
-                jnp.array(tt), jnp.array(tp), jnp.array(tsd), *extra)
-        else:
-            out, self.k_pages, self.v_pages, ys = self._unified(
-                self.params, self.k_pages, self.v_pages, jnp.array(tokens),
-                prev_out, jnp.array(cmask), jnp.array(crow), jnp.array(ptab),
-                jnp.array(rs), jnp.array(p0), jnp.array(nv), jnp.array(tt),
-                jnp.array(tp), jnp.array(tsd), *extra)
+        out, self.k_pages, self.v_pages, ys, side = self._unified(
+            self.params, self.k_pages, self.v_pages, jnp.array(tokens),
+            prev_out, jnp.array(cmask), jnp.array(crow), jnp.array(ptab),
+            jnp.array(rs), jnp.array(p0), jnp.array(nv), jnp.array(tt),
+            jnp.array(tp), jnp.array(tsd), *self.side_planes.values(),
+            *extra)
+        self.side_planes = dict(zip(self.side_planes, side))
         self._inflight = (out, snap, ys)
         self._prev_out_dev = out
         # post-dispatch bookkeeping: prefix-cache offers for pages this
@@ -1783,6 +1582,18 @@ class ServingEngine:
     # the device arrays immediately (sync wire) or defers the scatter to
     # the next dispatch as one batched between-programs write
     # (wire_overlap), abort_adopt returns staged pages to the free list.
+    #
+    # The wire still knows the k/v geometry and the int8 format
+    # (ROADMAP D1): it reads ``_kv_quant`` and finds the int8 model's
+    # two side planes under the names below.
+
+    def _side_plane(name):
+        def put(self, plane):
+            self.side_planes[name] = plane
+        return property(lambda self: self.side_planes.get(name), put)
+
+    k_scales, v_scales = _side_plane("k_scales"), _side_plane("v_scales")
+    del _side_plane
 
     def _export_meta(self, rid: int):
         """Shared export-prefix computation: the slot serving ``rid``,
@@ -2241,16 +2052,11 @@ class ServingEngine:
 
     def kv_bytes_per_page(self) -> float:
         """HBM bytes one KV page costs across all layers, including the
-        page's share of the scale planes. The structural capacity
+        page's share of the side planes. The structural capacity
         argument for serving_kv_quant: at a fixed page-pool byte budget
         the pool holds bytes_bf16/bytes_int8 ~ 2x the pages, hence ~2x
         the concurrent sequences."""
-        L = self.model.n_layers
-        per = self.cache_spec.page_bytes(L, self.k_pages.dtype.itemsize)
-        if self._kv_quant:
-            per += 2 * L * self.k_scales.shape[2] * \
-                self.k_scales.dtype.itemsize
-        return float(per)
+        return float(self.cache_spec.page_bytes(self.model.n_layers))
 
     def kv_bytes_per_token(self) -> float:
         """Amortized KV bytes per cached token (page bytes / page size)."""

@@ -505,24 +505,53 @@ class LlamaServing:
     """LLaMA behind the serving engine's model seam: k pages d-major and
     v pages token-major per kv head, the per-token page write, unified
     ragged-paged attention, SwiGLU.  ``lora`` adds the per-row q/v
-    low-rank deltas (``ctx["aid"]`` and four stacks beside the blocks)."""
+    low-rank deltas (``ctx["aid"]`` and four stacks beside the blocks).
+
+    ``kv_quant``: the pages are symmetric int8 and each carries an fp32
+    scale per kv head in two side planes (``k_scales``, ``v_scales``).
+    A page fills incrementally, so its scale is a *running absmax*; a
+    layer's write is
+
+    1. scatter-max the planes with this chunk's token absmaxes
+       (commutative: deterministic under duplicate page ids);
+    2. rescale the int8 content already in every page a chunk straddles
+       onto the new scale (an exact no-op where the scale did not grow;
+       duplicate writes across rows of one request produce identical
+       bytes, so order cannot matter);
+    3. quantize the new tokens against the updated scale and write them
+       per (page, offset) exactly like the fp pages.
+
+    Speculative rollback and aborts need nothing more: a rejected
+    draft's or a reused page's *content* is overwritten before it can be
+    attended, and the engine zeroes a page's scale entries when the page
+    goes to a new tenant (models/seam.py: side planes), so a stale
+    absmax cannot cost a later tenant its precision."""
 
     unsupported = ()
 
-    def __init__(self, cfg: LlamaConfig, lora: bool = False):
-        self.cfg, self.lora = cfg, lora
+    def __init__(self, cfg: LlamaConfig, lora: bool = False,
+                 kv_quant: bool = False):
+        self.cfg, self.lora, self.kv_quant = cfg, lora, kv_quant
         self.n_layers = cfg.n_layers
 
     def init_params(self, key) -> dict:
         return init_llama_params(self.cfg, key)
 
     def cache_spec(self, page_size: int):
-        from .seam import CachePlane, CacheSpec
+        from .seam import CachePlane, CacheSpec, SidePlane
 
         nKV, d = self.cfg.n_kv_heads, self.cfg.head_dim
+        planes = (CachePlane("k", (nKV, d, page_size), nKV * d),
+                  CachePlane("v", (nKV, page_size, d), nKV * d))
+        if not self.kv_quant:
+            return CacheSpec(planes, self.cfg.dtype)
+        # KV bytes per token drop from 2*itemsize*nKV*dH to 2*nKV*dH (+
+        # the amortized scales): a fixed-byte pool holds ~2x the sequences
         return CacheSpec(
-            (CachePlane("k", (nKV, d, page_size), nKV * d),
-             CachePlane("v", (nKV, page_size, d), nKV * d)), self.cfg.dtype)
+            planes, jnp.int8,
+            side=tuple(SidePlane(n, (nKV,), jnp.float32)
+                       for n in ("k_scales", "v_scales")),
+            hash_tag=b":kvq8")
 
     def embed(self, params, tokens, positions):
         cfg = self.cfg
@@ -541,7 +570,8 @@ class LlamaServing:
             xs = xs + (ast["aq"], ast["bq"], ast["av"], ast["bv"])
         return [LayerGroup(0, self.n_layers, xs)]
 
-    def apply(self, x, kp, vp, base, inp, rows, pos0, n_valid, ctx):
+    def apply(self, x, kp, vp, base, inp, rows, pos0, n_valid, ctx,
+              *scales):
         from ..ops.pallas.lora_matmul import lora_matmul
         from ..ops.pallas.paged_kv_write import paged_kv_write
         from ..ops.pallas.ragged_paged_attention import \
@@ -571,21 +601,92 @@ class LlamaServing:
             v = v.reshape(C, qb, nKV, dH)
             q = apply_rope(q, cos, sin)
             k = apply_rope(k, cos, sin)
-        with jax.named_scope("layer/kv_write"):
-            kp, vp = paged_kv_write(
-                kp, vp, k.astype(kp.dtype), v.astype(vp.dtype),
-                rows + base, pos0, n_valid, sink=base)
+        if self.kv_quant:
+            o, kp, vp, scales = self._write_attend_int8(
+                q, k, v, kp, vp, base, rows, pos0, n_valid, sm_scale,
+                *scales)
+        else:
+            with jax.named_scope("layer/kv_write"):
+                kp, vp = paged_kv_write(
+                    kp, vp, k.astype(kp.dtype), v.astype(vp.dtype),
+                    rows + base, pos0, n_valid, sink=base)
+            with jax.named_scope("layer/attn"):
+                o = ragged_paged_attention(q, kp, vp, rows + base, pos0,
+                                           n_valid, sm_scale,
+                                           k_layout="d_major")
         with jax.named_scope("layer/attn"):
-            o = ragged_paged_attention(q, kp, vp, rows + base, pos0,
-                                       n_valid, sm_scale,
-                                       k_layout="d_major")
             x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
         with jax.named_scope("layer/mlp"):
             h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
             x = x + _mm(jax.nn.silu(
                 _mm(h, bp["w_gate"], cfg).astype(jnp.float32)).astype(
                     cfg.dtype) * _mm(h, bp["w_up"], cfg), bp["w_down"], cfg)
-        return x, kp, vp, None
+        return (x, kp, vp, None) + tuple(scales)
+
+    def _write_attend_int8(self, q, k, v, kp, vp, base, rows, pos0,
+                           n_valid, sm_scale, ksc, vsc):
+        """The int8 pages' write and attention (the class docstring's
+        three steps).  The pages are the carried ``[L*P, ...]`` pools
+        (steps 2 and 3 add ``base``); the scale planes are this layer's
+        ``[P, nKV]`` under its own page ids."""
+        from ..ops.pallas.paged_kv_write import paged_kv_write
+        from ..ops.pallas.ragged_paged_attention import \
+            ragged_paged_attention
+        from ..ops.quant import (kv_scale_update, quantize_to_scale,
+                                 rescale_int8)
+
+        C, qb, nKV, dH = k.shape
+        bs, max_blocks = vp.shape[-2], rows.shape[1]
+        positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
+        valid = jnp.arange(qb, dtype=jnp.int32)[None, :] < n_valid[:, None]
+        pages = jnp.where(
+            valid, jnp.take_along_axis(rows, positions // bs, axis=1),
+            0).reshape(-1)                           # padding -> sink
+        # every page this step's chunks might straddle (per row: the
+        # first written page plus any the qb-token span can spill
+        # into); entries past a row's span hit its future pages or the
+        # sink, where rescaling is the exact no-op described above
+        npw = (qb - 1) // bs + 2
+        blk_rw = jnp.clip(
+            pos0[:, None] // bs + jnp.arange(npw, dtype=jnp.int32)[None, :],
+            0, max_blocks - 1)
+        pages_rw = jnp.take_along_axis(rows, blk_rw, axis=1).reshape(-1)
+        with jax.named_scope("layer/kv_write"):
+            kf = k.reshape(C * qb, nKV, dH).astype(jnp.float32)
+            vf = v.reshape(C * qb, nKV, dH).astype(jnp.float32)
+            ksc_new = kv_scale_update(
+                ksc, pages, jnp.max(jnp.abs(kf), axis=-1) / 127.0)
+            vsc_new = kv_scale_update(
+                vsc, pages, jnp.max(jnp.abs(vf), axis=-1) / 127.0)
+            kp = kp.at[pages_rw + base].set(rescale_int8(
+                kp[pages_rw + base],
+                jnp.take(ksc, pages_rw, axis=0)[:, :, None, None],
+                jnp.take(ksc_new, pages_rw, axis=0)[:, :, None, None]))
+            vp = vp.at[pages_rw + base].set(rescale_int8(
+                vp[pages_rw + base],
+                jnp.take(vsc, pages_rw, axis=0)[:, :, None, None],
+                jnp.take(vsc_new, pages_rw, axis=0)[:, :, None, None]))
+            kp, vp = paged_kv_write(
+                kp, vp,
+                quantize_to_scale(
+                    kf, jnp.take(ksc_new, pages, axis=0)[:, :, None]
+                ).reshape(C, qb, nKV, dH),
+                quantize_to_scale(
+                    vf, jnp.take(vsc_new, pages, axis=0)[:, :, None]
+                ).reshape(C, qb, nKV, dH),
+                rows + base, pos0, n_valid, sink=base)
+        with jax.named_scope("layer/attn"):
+            # the attention finds a page's scale by the id it finds
+            # the page by, and the scale planes stay one layer's
+            # [P, nKV] (they ride SMEM): so it gets this layer's
+            # pages under their local ids, which is a copy of them
+            P = ksc.shape[0]
+            o = ragged_paged_attention(
+                q, lax.dynamic_slice_in_dim(kp, base, P),
+                lax.dynamic_slice_in_dim(vp, base, P), rows, pos0,
+                n_valid, sm_scale, k_layout="d_major",
+                k_scales=ksc_new, v_scales=vsc_new)
+        return o, kp, vp, (ksc_new, vsc_new)
 
     def head(self, params, x):
         with jax.named_scope("head"):

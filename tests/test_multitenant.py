@@ -140,14 +140,17 @@ def test_adapter_store_refcount_and_dedup():
     assert st.n_pages_held() == 0 and not pool_pages
 
 
-def test_lora_requests_share_adapter_pages_and_isolate_streams():
+@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+def test_lora_requests_share_adapter_pages_and_isolate_streams(kv_quant):
     """Two live same-adapter requests hold ONE set of adapter pages
     (refcount == 2 while both are resident); different adapters yield
     different streams; a no-adapter rider in the mix is bit-identical to
-    the flag-off engine."""
+    the flag-off engine. Over int8 pages as over fp ones: the deltas are
+    the model's, whatever its pages hold."""
     rng = np.random.RandomState(0)
     p0 = rng.randint(1, 512, size=20).astype(np.int32)
-    eng = _mk_engine(lora=True, lora_rank=8, lora_slots=2, max_batch=3)
+    eng = _mk_engine(lora=True, lora_rank=8, lora_slots=2, max_batch=3,
+                     kv_quant=kv_quant)
     eng.register_adapter("a0", make_lora(CFG, 8, seed=1, scale=0.3))
     eng.register_adapter("a1", make_lora(CFG, 8, seed=2, scale=0.3))
     reqs = [Request(rid=0, prompt=p0, max_new_tokens=6, adapter_id="a0"),
@@ -170,13 +173,14 @@ def test_lora_requests_share_adapter_pages_and_isolate_streams():
     assert reqs[0].out_tokens == reqs[1].out_tokens
     assert reqs[0].out_tokens != reqs[2].out_tokens
     # no-adapter rider == flag-off engine (identity slot + all-zero delta)
-    eng2 = _mk_engine(lora=True, lora_rank=8, lora_slots=2)
+    eng2 = _mk_engine(lora=True, lora_rank=8, lora_slots=2,
+                      kv_quant=kv_quant)
     eng2.register_adapter("a0", make_lora(CFG, 8, seed=1, scale=0.3))
     rider = Request(rid=3, prompt=p0.copy(), max_new_tokens=6)
     lead = Request(rid=4, prompt=rng.randint(1, 512, 24).astype(np.int32),
                    max_new_tokens=6, adapter_id="a0")
     eng2.run([lead, rider])
-    eng3 = _mk_engine()
+    eng3 = _mk_engine(kv_quant=kv_quant)
     base = Request(rid=5, prompt=p0.copy(), max_new_tokens=6)
     lead2 = Request(rid=6, prompt=lead.prompt.copy(), max_new_tokens=6)
     eng3.run([lead2, base])

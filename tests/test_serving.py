@@ -83,8 +83,7 @@ def test_serving_pipelined_page_recycling_exact():
     rng = np.random.RandomState(7)
     engine = ServingEngine(CFG, max_batch=3, page_size=16, max_seq=128,
                            n_pages=1 + 10,          # ~2.5 requests' worth
-                           prefill_budget=32, prefix_cache=False,
-                           decode_quantum=2)
+                           prefill_budget=32, prefix_cache=False)
     prompts = [rng.randint(1, 512, size=n).astype(np.int32)
                for n in (9, 16, 23, 31, 12, 20, 7, 28)]
     max_new = 11                  # not a multiple of the quantum
@@ -105,18 +104,17 @@ def test_serving_pipelined_page_recycling_exact():
 def test_serving_sampling_contract():
     """Per-request sampling (reference fused top_p_sampling role):
     mixed greedy/sampled batches share one program; a sampled request's
-    stream is (seed, position)-keyed — reproducible across runs and
-    quantum sizes; top_p -> 0 keeps only the max token (== greedy); a
+    stream is (seed, position)-keyed — reproducible across runs;
+    top_p -> 0 keeps only the max token (== greedy); a
     greedy request's tokens are unaffected by sampled neighbours."""
     rng = np.random.RandomState(3)
     prompts = [rng.randint(1, 512, size=n).astype(np.int32)
                for n in (9, 16, 23)]
     max_new = 9
 
-    def run(specs, quantum):
+    def run(specs):
         engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
-                               prefill_budget=64,
-                               decode_quantum=quantum)
+                               prefill_budget=64)
         reqs = [Request(rid=i, prompt=p, max_new_tokens=max_new,
                         arrival=0.0, **spec)
                 for i, (p, spec) in enumerate(zip(prompts, specs))]
@@ -124,20 +122,20 @@ def test_serving_sampling_contract():
         return [r.out_tokens for r in reqs], engine
 
     greedy_specs = [{}, {}, {}]
-    base, engine = run(greedy_specs, 4)
+    base, engine = run(greedy_specs)
     want = _isolated_reference(engine, prompts, max_new)
     assert base == [list(map(int, w)) for w in want]
 
     mixed = [{"temperature": 0.9, "top_p": 0.8, "seed": 11}, {}, {}]
-    out1, _ = run(mixed, 4)
-    out2, _ = run(mixed, 3)          # different quantum boundaries
-    assert out1[0] == out2[0], "sampled stream must not depend on quantum"
+    out1, _ = run(mixed)
+    out2, _ = run(mixed)
+    assert out1[0] == out2[0], "a sampled stream must be reproducible"
     assert out1[1] == base[1] and out1[2] == base[2], \
         "greedy neighbours must be unaffected by a sampled request"
     assert out1[0] != base[0], "hot sampling should diverge from greedy"
 
     top1 = [{"temperature": 0.9, "top_p": 1e-6, "seed": 11}, {}, {}]
-    out3, _ = run(top1, 4)
+    out3, _ = run(top1)
     assert out3[0] == base[0], "top_p -> 0 must reduce to greedy"
 
 

@@ -52,7 +52,7 @@ def test_admission_skips_pool_blocked_request():
     once pages free up."""
     engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
                            n_pages=1 + 6, prefill_budget=64,
-                           prefix_cache=False, decode_quantum=2)
+                           prefix_cache=False)
     rng = np.random.RandomState(0)
     small0 = rng.randint(1, 512, size=16).astype(np.int32)
     big = rng.randint(1, 512, size=64).astype(np.int32)
@@ -117,7 +117,7 @@ def test_abort_mid_flight_and_queued():
     drops a queued request outright, and neither corrupts the survivor's
     token stream."""
     engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=256,
-                           prefill_budget=64, decode_quantum=2)
+                           prefill_budget=64)
     rng = np.random.RandomState(1)
     p0 = rng.randint(1, 512, size=20).astype(np.int32)
     p1 = rng.randint(1, 512, size=24).astype(np.int32)
@@ -148,8 +148,7 @@ def test_page_accounting_invariant_randomized():
     n_pages - 1 with all groups disjoint (no leak, no double-free), and
     the occupancy ledger must balance."""
     engine = ServingEngine(CFG, max_batch=3, page_size=16, max_seq=128,
-                           n_pages=1 + 14, prefill_budget=32,
-                           decode_quantum=3)
+                           n_pages=1 + 14, prefill_budget=32)
     rng = np.random.RandomState(2)
     prefixes = [rng.randint(1, 512, size=32).astype(np.int32)
                 for _ in range(2)]
@@ -191,16 +190,15 @@ def test_page_accounting_invariant_randomized():
 def test_sampled_stream_invariant_to_chunk_and_quantum_boundaries():
     """The keyed-RNG contract end to end: a sampled request's token
     stream is bit-identical whether its prompt prefills in one dispatch
-    or three, under different decode quanta, and whether its prefix came
+    or three, and whether its prefix came
     from the cache or was prefilled fresh."""
     rng = np.random.RandomState(3)
     prompt = rng.randint(1, 512, size=40).astype(np.int32)
     spec = dict(max_new_tokens=9, temperature=0.9, top_p=0.85, seed=17)
 
-    def run(budget, quantum, warm=False):
+    def run(budget, warm=False):
         engine = ServingEngine(CFG, max_batch=2, page_size=16,
-                               max_seq=128, prefill_budget=budget,
-                               decode_quantum=quantum)
+                               max_seq=128, prefill_budget=budget)
         if warm:                         # populate the prefix cache
             w = Request(rid=99, prompt=prompt.copy(), **spec)
             engine.run([w])
@@ -209,11 +207,11 @@ def test_sampled_stream_invariant_to_chunk_and_quantum_boundaries():
         engine.run([r])
         return r.out_tokens, engine
 
-    base, _ = run(budget=64, quantum=4)          # one prefill dispatch
-    chunked, _ = run(budget=16, quantum=4)       # three dispatches
-    requantized, _ = run(budget=32, quantum=3)
-    cached, eng = run(budget=64, quantum=5, warm=True)
-    assert base == chunked == requantized == cached
+    base, _ = run(budget=64)             # one prefill dispatch
+    chunked, _ = run(budget=16)          # three dispatches
+    halved, _ = run(budget=32)
+    cached, eng = run(budget=64, warm=True)
+    assert base == chunked == halved == cached
     assert eng.pool.hits > 0             # the warm run's pages were hit
 
 
@@ -222,7 +220,7 @@ def test_prefix_cache_hit_skips_redundant_prefill_flops():
     FLOPs — the prefill-token counter advances only by the non-cached
     tail, and the generated tokens still match exactly (greedy)."""
     engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
-                           prefill_budget=64, decode_quantum=4)
+                           prefill_budget=64)
     rng = np.random.RandomState(4)
     prompt = rng.randint(1, 512, size=33).astype(np.int32)  # 2 pages + 1
     a = Request(rid=0, prompt=prompt.copy(), max_new_tokens=6)
@@ -243,8 +241,7 @@ def test_cached_pages_evicted_under_pool_pressure():
     sized for one request at a time still serves a sequence of requests
     with distinct prompts while the cache is on."""
     engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
-                           n_pages=1 + 4, prefill_budget=64,
-                           decode_quantum=2)
+                           n_pages=1 + 4, prefill_budget=64)
     rng = np.random.RandomState(5)
     reqs = [Request(rid=i,
                     prompt=rng.randint(1, 512, size=40).astype(np.int32),
@@ -258,7 +255,7 @@ def test_cached_pages_evicted_under_pool_pressure():
 
 def test_run_reports_occupancy_decomposition():
     engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
-                           prefill_budget=32, decode_quantum=2)
+                           prefill_budget=32)
     rng = np.random.RandomState(6)
     reqs = [Request(rid=i,
                     prompt=rng.randint(1, 512, size=24).astype(np.int32),

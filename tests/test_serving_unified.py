@@ -9,7 +9,6 @@ traffic shares its dispatches, whether its prefix came warm from the
 cache, and whether speculative verification is on (greedy-accept + keyed
 sampling make acceptance invisible to the stream)."""
 
-import warnings
 
 import numpy as np
 import pytest
@@ -214,17 +213,6 @@ def test_page_accounting_under_speculative_load_with_aborts():
 # int8 KV plane (serving_kv_quant)
 
 
-def test_decode_quantum_kwarg_deprecated_and_inert():
-    """Satellite: decode_quantum= must warn exactly once per ctor and
-    change nothing; omitting it must stay silent."""
-    with pytest.warns(DeprecationWarning, match="decode_quantum"):
-        ServingEngine(CFG, max_batch=1, page_size=16, max_seq=64,
-                      decode_quantum=4)
-    with warnings.catch_warnings():
-        warnings.simplefilter("error")
-        ServingEngine(CFG, max_batch=1, page_size=16, max_seq=64)
-
-
 def test_kv_quant_default_off_is_structurally_identical():
     """With the flag off (the default) the engine must build the exact
     pre-quant structures: fp pages, no scale planes, and the original
@@ -333,6 +321,76 @@ def test_kv_quant_capacity_doubles_at_fixed_bytes():
     assert on.kv_bytes_per_token() * 2 <= off.kv_bytes_per_token()
 
 
+# -- one step; what a page holds is the model's to say ----------------------
+
+def _engine_of(kind):
+    """The three serving models at toy size: LLaMA with fp pages, LLaMA
+    with int8 pages, and the latent (MLA) model with routed experts."""
+    if kind != "mla":
+        return ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
+                             kv_quant=kind == "int8")
+    from paddle_tpu.models.mla_moe import MlaMoeConfig, init_mla_moe_params
+
+    cfg = MlaMoeConfig(
+        vocab_size=128, hidden=32, n_layers=3, n_heads=2, q_lora_rank=24,
+        kv_lora_rank=16, qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8,
+        ffn_hidden=64, moe_hidden=16, n_routed_experts=8,
+        experts_per_token=2, n_mtp=0, max_seq_len=64, held=(2, 4))
+    params = jax.eval_shape(lambda k: init_mla_moe_params(cfg, k),
+                            jax.random.PRNGKey(0))    # shapes only
+    return ServingEngine(cfg, params=params, max_batch=2, page_size=8,
+                         max_seq=64, n_pages=1 + 8)
+
+
+MODELS = ["fp", "int8", "mla"]
+
+
+@pytest.mark.parametrize("kind", MODELS)
+def test_engine_allocates_what_the_cache_spec_declares(kind):
+    """The pool and the side planes have exactly the shapes and dtypes
+    ``model.cache_spec(page_size)`` declares, ``[L, P, *page_shape]``
+    each, and a page's bytes are the spec's sum: the engine knows no
+    page format of its own."""
+    engine = _engine_of(kind)
+    spec = engine.model.cache_spec(engine.bs)
+    assert spec == engine.cache_spec
+    lead = (engine.model.n_layers, engine.n_pages)
+    for pages, plane in zip((engine.k_pages, engine.v_pages), spec.planes):
+        assert pages.shape == lead + plane.page_shape
+        assert pages.dtype == spec.dtype
+    assert list(engine.side_planes) == [p.name for p in spec.side]
+    for plane in spec.side:
+        got = engine.side_planes[plane.name]
+        assert got.shape == lead + plane.page_shape
+        assert got.dtype == plane.dtype
+    per_page = sum(a.nbytes for a in (engine.k_pages, engine.v_pages,
+                                      *engine.side_planes.values())
+                   ) / engine.n_pages
+    assert engine.kv_bytes_per_page() == per_page
+    assert engine.kv_bytes_per_page() == spec.page_bytes(lead[0])
+    assert bool(spec.side) == (kind == "int8")
+    # the step's operands: the fixed fourteen, then the side planes
+    args = engine.unified_arg_shapes()
+    assert len(args) == 14 + len(spec.side)
+    assert [(a.shape, a.dtype) for a in args[14:]] == [
+        (lead + p.page_shape, p.dtype) for p in spec.side]
+
+
+@pytest.mark.parametrize("kind", MODELS)
+def test_every_engine_jits_the_one_step(kind):
+    """There is one step function: every engine's ``_unified`` is a jit
+    of ``ServingEngine._unified_step_impl``, whatever model it serves
+    and whatever its pages hold."""
+    engine = _engine_of(kind)
+    assert (engine._unified.__wrapped__.__func__
+            is ServingEngine._unified_step_impl)
+    closed = engine.trace_unified()
+    n_out = len(jax.tree.leaves(closed.out_avals))
+    n_ys = len(jax.tree.leaves(jax.eval_shape(
+        engine._unified_step_impl, *engine.unified_arg_shapes())[3]))
+    assert n_out == 3 + n_ys + len(engine.cache_spec.side)
+
+
 # -- the pool is a loop carry addressed by (layer, page) -------------------
 
 def _scans(jaxpr):
@@ -347,13 +405,12 @@ def _scans(jaxpr):
 @pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
 def test_pool_is_a_scan_carry_not_a_scanned_operand(kv_quant):
     """A scan reads its ``xs`` and stacks fresh ``ys``, so a pool that
-    travels that way is copied every step. Both step programs carry the
-    pool, flattened to ``[L*P, ...]``, and scan over nothing
-    pool-shaped."""
+    travels that way is copied every step. The step carries the pool,
+    flattened to ``[L*P, ...]``, whatever its pages hold, and scans over
+    nothing pool-shaped."""
     engine = ServingEngine(CFG, max_batch=2, page_size=16, max_seq=128,
                            kv_quant=kv_quant)
-    closed = (engine.trace_unified_quant() if kv_quant
-              else engine.trace_unified())
+    closed = engine.trace_unified()
     (scan,) = list(_scans(closed.jaxpr))
     nc, nk = scan.params["num_consts"], scan.params["num_carry"]
     carry = [v.aval.shape for v in scan.invars[nc:nc + nk]]
@@ -387,12 +444,10 @@ def _reference_step(engine, args):
                                       rescale_int8)
 
     cfg, bs, quant = engine.cfg, engine.bs, engine._kv_quant
+    (params, kp, vp, tokens, prev_out, cmask, crow, ptable, row_slot,
+     pos0, n_valid) = args[:11]
     if quant:
-        (params, kp, vp, ksc, vsc, tokens, prev_out, cmask, crow, ptable,
-         row_slot, pos0, n_valid) = args[:13]
-    else:
-        (params, kp, vp, tokens, prev_out, cmask, crow, ptable, row_slot,
-         pos0, n_valid) = args[:11]
+        ksc, vsc = args[14:16]       # side planes ride behind the seeds
     C, qb = tokens.shape
     nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
@@ -473,7 +528,8 @@ def test_pool_bit_identical_to_per_layer_reference(kv_quant):
     def recording(*args):
         host = jax.tree.map(np.asarray, args)      # before the donation
         out = inner(*args)
-        seen.append((host, [np.asarray(o) for o in out[1:]]))
+        seen.append((host, [np.asarray(o)
+                            for o in (out[1], out[2], *out[4])]))
         return out
 
     engine._unified = recording
@@ -482,7 +538,7 @@ def test_pool_bit_identical_to_per_layer_reference(kv_quant):
     assert len(seen) > 8
     mixed = 0
     for host, got in seen:
-        n_valid, row_slot = host[-4], host[-6]
+        n_valid, row_slot = host[10], host[8]
         live = n_valid[row_slot < engine.B]
         mixed += bool((live > 1).any() and (live == 1).any())
         want = reference(*jax.tree.map(jnp.asarray, host))

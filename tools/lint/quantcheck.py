@@ -33,8 +33,8 @@ propagated environment:
           trace to the same quantize/rescale/kv_scale_update event that
           produced the bytes.  This is exactly the PR 8 pre-fix bug —
           a reused KV page dequantized against the prior tenant's
-          absmax — rebuilt on demand via the
-          ``ServingEngine._zero_scale_on_alloc`` hook
+          absmax — rebuilt on demand by
+          ``kv_admit_first_write(_zero_scale_on_alloc=False)``
           (:func:`build_admit_entry` with ``zero_scale_on_alloc=False``)
           where it must fire exactly once; the shipped tree is clean.
    TPL304 unclamped-scale-divide  a divide by a scale that is not
@@ -343,25 +343,26 @@ def build_serving_mla_moe_entry() -> QuantEntry:
 
 
 def build_serving_int8_entry() -> QuantEntry:
-    """The int8-KV unified step: the page arrays are int8 invars paired
-    with their scale-plane invars — the engine's allocator maintains the
-    no-foreign-scale invariant (proven separately by the admit entries),
-    so the planes enter *trusted*."""
+    """The unified step over int8 pages: the page arrays are int8 invars
+    paired with their scale-plane invars, the side planes that ride
+    behind the step's fixed operands — the engine's allocator maintains
+    the no-foreign-scale invariant (proven separately by the admit
+    entries), so the planes enter *trusted*."""
     jax = _jax()
     import paddle_tpu  # noqa: F401
 
     eng = _tiny_engine(kv_quant=True)
-    closed = eng.trace_unified_quant()
+    closed = eng.trace_unified()
     n = len(jax.tree_util.tree_leaves(eng.params))
     names = (["params" + s for s in _flatten_names(eng.params)]
-             + ["k_pages", "v_pages", "k_scales", "v_scales", "tokens",
-                "prev_out", "chain_mask", "chain_row", "ptable",
-                "row_slot", "pos0", "n_valid", "temps", "topps", "seeds"])
+             + ["k_pages", "v_pages", "tokens", "prev_out", "chain_mask",
+                "chain_row", "ptable", "row_slot", "pos0", "n_valid",
+                "temps", "topps", "seeds", "k_scales", "v_scales"])
     return QuantEntry(name="serving_unified_int8kv", closed=closed,
-                      source="paddle_tpu/inference/serving.py",
+                      source="paddle_tpu/models/llama.py",
                       invar_names=names,
-                      scale_invars={n + 2, n + 3},
-                      page_pairs={n: n + 2, n + 1: n + 3})
+                      scale_invars={n + 13, n + 14},
+                      page_pairs={n: n + 13, n + 1: n + 14})
 
 
 def build_wire_entries() -> list:

@@ -25,8 +25,7 @@ def main() -> int:
                       n_kv_heads=2, ffn_hidden=128, max_seq_len=128,
                       dtype=jnp.float32, param_dtype=jnp.float32)
     engine = ServingEngine(cfg, max_batch=2, page_size=16, max_seq=96,
-                           n_pages=1 + 10, prefill_budget=32,
-                           decode_quantum=3)
+                           n_pages=1 + 10, prefill_budget=32)
     rng = np.random.RandomState(0)
     prefix = rng.randint(1, 256, size=16).astype(np.int32)
     prompts = [
