@@ -58,6 +58,7 @@ from jax import lax
 from ..core.flags import GLOBAL_FLAGS
 from ..models.llama import (LlamaConfig, LlamaServing,
                             quantize_weights_int8)
+from ..models.seam import token_layout
 from ..obs import clock as _clock
 from ..testing import chaos as _chaos
 from .. import obs as _obs
@@ -470,6 +471,20 @@ class ServingEngine:
         self.qb = max(1, qb)
         self.n_rows = max(1, prefill_budget // self.qb, max_batch)
         self.prefill_budget = self.n_rows * self.qb
+        # the step sizes, a rule of the grid alone: a tick's dense layers
+        # run over the smallest that holds its tokens, packed, and the
+        # largest is the grid itself (_dispatch_unified; seam.py: the
+        # packed axis). A quarter of the grid holds every decode-only
+        # tick (n_rows >= max_batch) and a short prompt's chunks beside
+        # it; under the MXU's ridge a size is bound by the weights'
+        # bytes, so a smaller one buys nothing, and each further size
+        # is a program to trace, lower and compile before the first
+        # tick (PERF.md, PR 32: a size between the two cost more set-up
+        # than its ticks gave back). The packed axis of each size, kept
+        # on the device, is made at the first dispatch.
+        self.rungs = tuple(sorted({max(1, self.prefill_budget // 4),
+                                   self.prefill_budget}))
+        self._places: dict = {}
         # a decode row holds 1 input token + up to qb-1 verified drafts
         self.spec_k = max(0, min(int(speculative_k), self.qb - 1))
         if self.spec_k:
@@ -572,7 +587,8 @@ class ServingEngine:
         # snapshot); _prev_out_dev chains row outputs on-device into the
         # next dispatch; _deferred_free holds page ids for one harvest
         # cycle (an in-flight program may still write them)
-        self._inflight = None       # (out_dev [C, 1|qb], snapshot, ys)
+        # (out_dev [C, 1|qb], snapshot, ys, step size)
+        self._inflight = None
         self._prev_out_dev = None
         self._deferred_free: list[int] = []
         # migration staging (inference/fleet/): pages allocated by
@@ -600,6 +616,9 @@ class ServingEngine:
             "waste_preempted_slot_tokens": 0,      # re-prefill after preempt
             "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
             "preemptions": 0,
+            # what the dense layers computed against what the ticks
+            # carried: sum of the step sizes taken, sum of the tokens
+            "token_places": 0, "tokens_packed": 0,
             # migration-wire observability: host milliseconds this
             # engine spent materializing export payloads (the donor-side
             # wire cost the overlapped path shrinks to a buffer swap)
@@ -627,8 +646,11 @@ class ServingEngine:
                            prev_out, chain_mask, chain_row, ptable,
                            row_slot, pos0, n_valid, temps, topps, seeds,
                            *rest):
-        """THE engine step: one ``[n_rows, qb]`` unified ragged-paged-
-        attention program serving an arbitrary prefill/decode mix. Row c
+        """THE engine step: one unified ragged-paged-attention program
+        serving an arbitrary prefill/decode mix on an ``[n_rows, qb]``
+        grid of rows, its dense layers over the tick's tokens packed
+        ``[T, H]`` (models/seam.py: the packed axis; ``T`` is the length
+        of the last operand, one of ``rungs``). Row c
         holds n_valid[c] tokens of request row_slot[c] starting at
         position pos0[c] — a decode row is n_valid == 1 (plus drafts
         when speculating), a prefill slice up to qb, an idle row targets
@@ -659,14 +681,15 @@ class ServingEngine:
         model = self.model
         C, qb = tokens.shape
 
-        # what only some engines have rides as trailing varargs, so the
-        # default trace is literally the legacy trace: the page format's
-        # side planes (cache_spec.side), then the multi-tenant operands —
-        # row adapter slot ids + the four adapter stacks (serving_lora),
-        # then the per-row [C, V] vocab legality mask
-        # (serving_constrained)
+        # what only some engines have rides as trailing varargs: the
+        # page format's side planes (cache_spec.side), then the
+        # multi-tenant operands — row adapter slot ids + the four adapter
+        # stacks (serving_lora), then the per-row [C, V] vocab legality
+        # mask (serving_constrained). Behind them all, ``places``: the
+        # packed axis arange(T), whose LENGTH is the step size this tick
+        # runs at (``rungs``): one jit, one program a length
         n_side = len(self.cache_spec.side)
-        side, mt = rest[:n_side], list(rest[n_side:])
+        side, mt, places = rest[:n_side], list(rest[n_side:-1]), rest[-1]
         if self._lora_on:
             aid, ast = mt.pop(0), mt.pop(0)
         vmask = mt.pop(0) if self._constr_on else None
@@ -675,7 +698,13 @@ class ServingEngine:
         tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
         rows = ptable[row_slot]                      # [C, max_blocks]
         positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
-        x, ctx = model.embed(params, tokens, positions)
+        # the tick's tokens packed: an idle row (the sink's) carries none
+        lay = token_layout(
+            jnp.where(row_slot == ptable.shape[0] - 1, 0, n_valid), qb,
+            places)
+        tok_positions = lay.to_packed(positions)
+        x, ctx = model.embed(params, lay.to_packed(tokens), tok_positions)
+        ctx["layout"] = lay
         if self._lora_on:
             ctx["aid"] = aid
             groups = model.layer_groups(params, ast)
@@ -688,21 +717,20 @@ class ServingEngine:
 
         x, ks, vs, ys, side = _run_layer_groups(body, x, k_pages, v_pages,
                                                 groups, side)
-        x = model.head(params, x)
         if self.spec_k:
             # speculative verify needs the model's pick at EVERY draft
             # position; keying on each input position keeps the accepted
             # stream identical to one-token-at-a-time decoding
-            logits = model.logits(params, x)
-            picks = _pick_tokens(
-                logits.reshape(C * qb, -1), jnp.repeat(temps, qb),
-                jnp.repeat(topps, qb), jnp.repeat(seeds, qb),
-                positions.reshape(-1))
-            out = picks.reshape(C, qb)
+            logits = model.logits(params, model.head(params, x))
+            temps, topps, seeds = (
+                lay.to_packed(jnp.broadcast_to(a[:, None], (C, qb)))
+                for a in (temps, topps, seeds))
+            out = lay.to_grid(_pick_tokens(logits, temps, topps, seeds,
+                                           tok_positions))
         else:
             with jax.named_scope("head"):
-                last = x[jnp.arange(C), n_valid - 1]     # [C, H]
-            logits = model.logits(params, last[:, None])[:, 0]
+                last = x.at[lay.last].get(mode="promise_in_bounds")  # [C, H]
+            logits = model.logits(params, model.head(params, last))
             if self._constr_on:
                 # constrained rows only see schema-legal logits;
                 # unconstrained rows carry an all-True mask, and
@@ -717,16 +745,20 @@ class ServingEngine:
         # the layers' counters ride out with the picks
         return out, ks, vs, ys, side
 
-    def unified_arg_shapes(self) -> tuple:
+    def unified_arg_shapes(self, rung: Optional[int] = None) -> tuple:
         """Shape-only arguments of the unified step, mirroring the live
         dispatch (``_dispatch_unified``) exactly — for tracing it
         (``trace_unified``) or lowering it (``lower_unified``) with no
-        device executing anything."""
+        device executing anything. ``rung`` is the step size, one of
+        ``rungs``; None means the largest, the whole grid."""
         if self._lora_on or self._constr_on:
             raise NotImplementedError(
                 "unified_arg_shapes covers the non-multitenant programs; "
                 "register a dedicated entry for variant engines")
         C, qb, B = self.n_rows, self.qb, self.B
+        rung = self.rungs[-1] if rung is None else rung
+        if rung not in self.rungs:
+            raise ValueError(f"step size {rung} is not one of {self.rungs}")
 
         def sds(a):
             return jax.ShapeDtypeStruct(a.shape, a.dtype)
@@ -742,21 +774,23 @@ class ServingEngine:
         return (jax.tree.map(sds, self.params), sds(self.k_pages),
                 sds(self.v_pages), tokens, prev, cmask, crow, ptab, col_i,
                 col_i, col_i, col_f, col_f, col_i,
-                *map(sds, self.side_planes.values()))
+                *map(sds, self.side_planes.values()),
+                jax.ShapeDtypeStruct((rung,), i32))
 
-    def lower_unified(self):
+    def lower_unified(self, rung: Optional[int] = None):
         """The live jitted unified step lowered at its dispatch shapes
-        (``.compile().as_text()`` is the program the chip runs)."""
-        return self._unified.lower(*self.unified_arg_shapes())
+        (``.compile().as_text()`` is the program the chip runs), at the
+        step size ``rung`` (None: the largest)."""
+        return self._unified.lower(*self.unified_arg_shapes(rung))
 
-    def trace_unified(self):
+    def trace_unified(self, rung: Optional[int] = None):
         """Trace the unified step to a closed jaxpr, shape-only — the
         entry program tools/lint/shardcheck.py propagates partition
         specs through and tools/lint/quantcheck.py interprets over the
-        precision lattice (an int8 engine's scale planes, the last two
-        operands, are the TPL303 provenance roots)."""
+        precision lattice (an int8 engine's scale planes, the two
+        operands before the last, are the TPL303 provenance roots)."""
         return jax.make_jaxpr(self._unified_step_impl)(
-            *self.unified_arg_shapes())
+            *self.unified_arg_shapes(rung))
 
     # -- scheduler ----------------------------------------------------------
 
@@ -1175,10 +1209,13 @@ class ServingEngine:
             self._dispatch_unified(now)
         # what this tick put on the device, recorded on engine.step's
         # end: decode and prefill rows of the grid, requests left waiting
-        rows = self._inflight[1] if self._inflight is not prev else ()
+        # and the step size it ran at against the tokens it carried
+        _, rows, _, places = (self._inflight if self._inflight is not prev
+                              else (None, (), None, 0))
         n_dec = sum(1 for r in rows if r[3] == "dec")
         tick = dict(rows_decode=n_dec, rows_prefill=len(rows) - n_dec,
-                    queued=len(self.queue))
+                    queued=len(self.queue), places=places,
+                    tokens=sum(r[4] for r in rows))
         sp.set(**tick)
         # synchronous modes (spec, constrained): drafts and vocab masks
         # are host state derived from the previous step's tokens, so each
@@ -1386,14 +1423,35 @@ class ServingEngine:
             extra += [jnp.array(aidv), self.adapters.stacks()]
         if self._constr_on:
             extra.append(jnp.array(vm))
-        out, self.k_pages, self.v_pages, ys, side = self._unified(
-            self.params, self.k_pages, self.v_pages, jnp.array(tokens),
-            prev_out, jnp.array(cmask), jnp.array(crow), jnp.array(ptab),
-            jnp.array(rs), jnp.array(p0), jnp.array(nv), jnp.array(tt),
-            jnp.array(tp), jnp.array(tsd), *self.side_planes.values(),
-            *extra)
-        self.side_planes = dict(zip(self.side_planes, side))
-        self._inflight = (out, snap, ys)
+        fixed = (jnp.array(tokens), prev_out, jnp.array(cmask),
+                 jnp.array(crow), jnp.array(ptab))
+        per_row = (jnp.array(p0), jnp.array(nv), jnp.array(tt),
+                   jnp.array(tp), jnp.array(tsd))
+
+        def launch(row_slot, rung):
+            out, self.k_pages, self.v_pages, ys, side = self._unified(
+                self.params, self.k_pages, self.v_pages, *fixed, row_slot,
+                *per_row, *self.side_planes.values(), *extra,
+                self._places[rung])
+            self.side_planes = dict(zip(self.side_planes, side))
+            return out, ys
+
+        # the smallest step size that holds the tick's tokens
+        n_tok = sum(m for _s, _k, _p, m, _d in sched)
+        rung = next(r for r in self.rungs if r >= n_tok)
+        if not self._places:
+            # the first dispatch compiles every size, so that no later
+            # tick does: each other size runs once with every row the
+            # sink's, which writes what idle rows write and nothing else
+            self._places = {r: jnp.arange(r, dtype=jnp.int32)
+                            for r in self.rungs}
+            for r in self.rungs:
+                if r != rung:
+                    launch(jnp.full((C,), self.B, jnp.int32), r)
+        out, ys = launch(jnp.array(rs), rung)
+        self.stats["token_places"] += rung
+        self.stats["tokens_packed"] += n_tok
+        self._inflight = (out, snap, ys, rung)
         self._prev_out_dev = out
         # post-dispatch bookkeeping: prefix-cache offers for pages this
         # step completed, prefill flips, decode position advance
@@ -1455,7 +1513,7 @@ class ServingEngine:
         """Fetch a completed step's row outputs (the only host sync of
         the serving path) and apply them; release pages freed one cycle
         ago — no in-flight program can reference them anymore."""
-        out_dev, snap, ys = inflight
+        out_dev, snap, ys, _rung = inflight
         with _obs.span("engine.harvest.wait", engine=self.engine_id):
             # [C, 1] or [C, qb], and the layers' counters in the same
             # fetch: one sync
@@ -1622,7 +1680,12 @@ class ServingEngine:
         tokens = np.ascontiguousarray(full[:n_exp * self.bs], np.int32)
         salt = self._cache_salt(req)
         hashes = self._page_hashes(tokens, salt)
-        pg = np.asarray(self._full_rows[slot][:n_exp], np.int32)
+        # the export's OWN copy of the page ids: the overlapped wire
+        # hands them to a gather that runs asynchronously, and the slot's
+        # row of _full_rows is zeroed as soon as the slot is released —
+        # a view (jnp.asarray may alias an aligned host buffer) would
+        # let a late gather read page 0 for every page
+        pg = np.array(self._full_rows[slot][:n_exp], np.int32)
         return slot, tokens, salt, hashes, pg
 
     def _shipment_header(self, rid: int, tokens, salt, hashes) -> dict:

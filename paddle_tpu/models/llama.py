@@ -504,7 +504,10 @@ class LlamaForCausalLM:
 class LlamaServing:
     """LLaMA behind the serving engine's model seam: k pages d-major and
     v pages token-major per kv head, the per-token page write, unified
-    ragged-paged attention, SwiGLU.  ``lora`` adds the per-row q/v
+    ragged-paged attention, SwiGLU.  Norms, projections, rotary and
+    SwiGLU run over the tick's packed tokens ``[T, H]``; q, k and v are
+    laid onto the rows' grid for the write and the attention, whose
+    output comes back packed.  ``lora`` adds the per-row q/v
     low-rank deltas (``ctx["aid"]`` and four stacks beside the blocks).
 
     ``kv_quant``: the pages are symmetric int8 and each carries an fp32
@@ -556,9 +559,9 @@ class LlamaServing:
     def embed(self, params, tokens, positions):
         cfg = self.cfg
         with jax.named_scope("embed"):
-            x = params["wte"][tokens].astype(cfg.dtype)  # [C, qb, H]
-            cos, sin = rope_angles(cfg, positions)       # [C, qb, dH/2]
-            cos, sin = cos[:, :, None, :], sin[:, :, None, :]
+            x = params["wte"][tokens].astype(cfg.dtype)  # [T, H]
+            cos, sin = rope_angles(cfg, positions)       # [T, dH/2]
+            cos, sin = cos[:, None, :], sin[:, None, :]
         return x, {"cos": cos, "sin": sin}
 
     def layer_groups(self, params, lora_stacks=None):
@@ -578,7 +581,7 @@ class LlamaServing:
             ragged_paged_attention
 
         cfg = self.cfg
-        C, qb = x.shape[:2]
+        T, lay = x.shape[0], ctx["layout"]
         nH, nKV, dH = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
         cos, sin = ctx["cos"], ctx["sin"]
         sm_scale = 1.0 / math.sqrt(dH)
@@ -590,17 +593,22 @@ class LlamaServing:
         with jax.named_scope("layer/qkv"):
             h = rms_norm(x, bp["attn_norm"], cfg.rms_eps)
             q = _mm(h, bp["wq"], cfg)
-            k = _mm(h, bp["wk"], cfg).reshape(C, qb, nKV, dH)
+            k = _mm(h, bp["wk"], cfg).reshape(T, nKV, dH)
             v = _mm(h, bp["wv"], cfg)
             if self.lora:
-                # grouped BGMV: each packed row through ITS adapter's
-                # q/v low-rank delta (slot 0 = exact +0.0 identity)
-                q = q + lora_matmul(h, aq_l, bq_l, aid).astype(q.dtype)
-                v = v + lora_matmul(h, av_l, bv_l, aid).astype(v.dtype)
-            q = q.reshape(C, qb, nH, dH)
-            v = v.reshape(C, qb, nKV, dH)
-            q = apply_rope(q, cos, sin)
+                # grouped BGMV: an adapter is a row's, so the deltas are
+                # made on the grid, each row through ITS adapter's q/v
+                # low-rank pair (slot 0 = exact +0.0 identity)
+                hg = lay.to_grid(h)
+                q = q + lay.to_packed(
+                    lora_matmul(hg, aq_l, bq_l, aid)).astype(q.dtype)
+                v = v + lay.to_packed(
+                    lora_matmul(hg, av_l, bv_l, aid)).astype(v.dtype)
+            q = apply_rope(q.reshape(T, nH, dH), cos, sin)
             k = apply_rope(k, cos, sin)
+            # the write and the attention work by rows
+            q, k, v = (lay.to_grid(a) for a in
+                       (q, k, v.reshape(T, nKV, dH)))
         if self.kv_quant:
             o, kp, vp, scales = self._write_attend_int8(
                 q, k, v, kp, vp, base, rows, pos0, n_valid, sm_scale,
@@ -615,7 +623,8 @@ class LlamaServing:
                                            n_valid, sm_scale,
                                            k_layout="d_major")
         with jax.named_scope("layer/attn"):
-            x = x + _mm(o.reshape(C, qb, nH * dH), bp["wo"], cfg)
+            o = lay.to_packed(o).reshape(T, nH * dH)
+            x = x + _mm(o, bp["wo"], cfg)
         with jax.named_scope("layer/mlp"):
             h = rms_norm(x, bp["ffn_norm"], cfg.rms_eps)
             x = x + _mm(jax.nn.silu(

@@ -308,9 +308,9 @@ EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 
 def moe_ffn(h, lp, cfg: MlaMoeConfig, valid=None, stack=None):
     """The expert layer's feed-forward on normed ``h [N, H]``: this
-    chip's part (module docstring).  ``valid [N]`` marks the grid's real
-    tokens; padding is routed nowhere.  Returns ``(y [N, H], tokens per
-    held expert [count] int32)``.
+    chip's part (module docstring).  ``valid [N]`` marks the places that
+    hold a token; padding is routed nowhere.  Returns ``(y [N, H], tokens
+    per held expert [count] int32)``.
 
     The held experts' matrices are ``lp``'s ``we_*`` ``[count, ...]``, or
     with ``stack = (weights, i)`` those of ALL expert layers flattened
@@ -433,7 +433,9 @@ def mtp_logits(params, hidden, tokens, cfg: MlaMoeConfig):
 
 class MlaMoeServing:
     """Absorbed MLA over latent pages and the held experts' part of each
-    expert layer, one layer at a time on the engine's ``[C, qb]`` grid.
+    expert layer, one layer at a time: projections, the router and the
+    experts over the tick's packed tokens ``[T, H]``, the latent write
+    and the attention on the engine's ``[C, qb]`` grid (models/seam.py).
 
     A token stores ``c_kv`` (kv_rank values) and ``k_rope`` (d_rope
     values) per layer and nothing else.  The two live in the engine's
@@ -494,16 +496,18 @@ class MlaMoeServing:
         from ..ops.pallas.mla_paged_attention import mla_paged_attention
         from ..ops.pallas.paged_kv_write import paged_kv_write
 
-        cfg, lp = self.cfg, inp
+        cfg, lp, lay = self.cfg, inp, ctx["layout"]
         kind = "moe" if "router" in lp else "dense"
-        C, qb = x.shape[:2]
         f32 = jnp.float32
         h = rms_norm(x, lp["attn_norm"], cfg.rms_eps)
         q_nope, q_rope, c_kv, k_rope = mla_project(
             h, lp, cfg, ctx["cos"], ctx["sin"])
         with jax.named_scope("layer/mla_q"):
-            q_lat = jnp.einsum("cqhd,lhd->cqhl", q_nope, lp["wk_b"],
+            q_lat = jnp.einsum("thd,lhd->thl", q_nope, lp["wk_b"],
                                preferred_element_type=f32).astype(cfg.dtype)
+        # the write and the attention work by rows
+        q_lat, q_rope, c_kv, k_rope = (
+            lay.to_grid(a) for a in (q_lat, q_rope, c_kv, k_rope))
         with jax.named_scope("layer/latent_write"):
             # paged_kv_write's k is d-major, its v token-major: k_rope
             # and c_kv ride them as one "head" each
@@ -512,13 +516,13 @@ class MlaMoeServing:
                 c_kv[:, :, None], rows + base, pos0, n_valid, sink=base)
             kp, vp = kp4[:, 0], vp4[:, 0]
         with jax.named_scope("layer/attn"):
-            o_lat = mla_paged_attention(q_lat, q_rope, vp, kp, rows + base,
-                                        pos0, n_valid, _sm_scale(cfg))
-            o = jnp.einsum("cqhl,lhd->cqhd", o_lat, lp["wv_b"],
+            o_lat = lay.to_packed(mla_paged_attention(
+                q_lat, q_rope, vp, kp, rows + base, pos0, n_valid,
+                _sm_scale(cfg)))
+            o = jnp.einsum("thl,lhd->thd", o_lat, lp["wv_b"],
                            preferred_element_type=f32).astype(cfg.dtype)
-            x = x + _mm(o.reshape(C, qb, -1), lp["wo"], cfg)
-        valid = jnp.arange(qb, dtype=jnp.int32)[None, :] < n_valid[:, None]
-        x, sizes = _ffn(x, lp, cfg, kind, valid,
+            x = x + _mm(o.reshape(o.shape[0], -1), lp["wo"], cfg)
+        x, sizes = _ffn(x, lp, cfg, kind, lay.valid,
                         (ctx["experts"], lp["index"]) if kind == "moe"
                         else None)
         return x, kp, vp, sizes

@@ -18,23 +18,44 @@ inside a layer.  A *serving model* is any object with:
   the prefix hash, so pages of two formats never alias in the cache;
 - ``unsupported``: names of engine features this model does not serve
   (the engine fails with one error when asked for one);
-- ``embed(params, tokens, positions) -> (x, ctx)``: the residual stream
-  ``[C, qb, H]`` and whatever every layer shares (rotary angles);
+- ``embed(params, tokens, positions) -> (x, ctx)``: from the tick's
+  tokens and their positions on the *packed* axis ``[T]`` (below), the
+  residual stream ``[T, H]`` and whatever every layer shares (rotary
+  angles, per packed token).  The engine then puts the tick's
+  ``TokenLayout`` into ``ctx["layout"]``;
 - ``layer_groups(params) -> [LayerGroup]``: runs of alike layers.  A
   stacked group is scanned with the pool as the loop's carry, a single
   layer is applied where it stands;
 - ``apply(x, k_pool, v_pool, base, layer_xs, rows, pos0, n_valid, ctx,
-  *side) -> (x, k_pool, v_pool, ys, *side)``: one layer on the grid.
-  The pools are flattened ``[L*P, ...]`` and ``base = l*P`` is added to
-  every page id written or attended (``serving._run_layer_groups``
-  states the rule); ``ys`` is the layer's counters or None.  ``side``
+  *side) -> (x, k_pool, v_pool, ys, *side)``: one layer.  ``x`` is
+  packed, ``[T, H]``: what a token does alone (norms, projections, the
+  feed-forward, experts) runs over the packed axis, and
+  ``ctx["layout"].valid`` says which of its places hold a token.  What
+  works by rows (the page write, the paged attention, a per-row
+  adapter) wants the rows' grid ``[C, qb, ...]`` that ``rows``,
+  ``pos0`` and ``n_valid`` describe: ``ctx["layout"].to_grid`` lays a
+  packed array onto it and ``.to_packed`` brings one back, and they are
+  the one way between the layouts.  The pools are flattened
+  ``[L*P, ...]`` and ``base = l*P`` is added to every page id written
+  or attended (``serving._run_layer_groups`` states the rule); ``ys``
+  is the layer's counters or None.  ``side``
   is this layer's ``[P, *page_shape]`` slice of each side plane in the
   spec's order, under the layer's own page ids (no ``base``), and comes
   back updated behind ``ys``; a model that declares none gets and
   returns none, and its traced program has no operand for them;
-- ``head(params, x)`` (the final norm) and ``logits(params, h)``;
+- ``head(params, x)`` (the final norm) and ``logits(params, h)``, both
+  over ``[N, H]``: each row's last token, or every packed token when
+  the engine verifies drafts;
 - ``tick_stats(ys, n_valid_tokens) -> dict`` where ``ys`` is not None:
   what a tick's harvested counters add to the engine's ``stats``.
+
+**The packed axis.**  A tick carries ``sum(m)`` tokens, row ``c`` of the
+grid ``m[c]`` of them (an idle row none), and the grid has ``C * qb``
+places whatever it carries.  The packed axis holds the tick's tokens in
+row-major order, row 0's first, and padding behind them up to ``T``,
+one of the engine's step sizes (``ServingEngine.rungs``); the
+largest is ``C * qb``, and there the packed axis IS the grid flattened,
+so both maps are reshapes.
 """
 
 from __future__ import annotations
@@ -43,9 +64,11 @@ import dataclasses
 import math
 from typing import Any
 
+import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["CachePlane", "CacheSpec", "LayerGroup", "SidePlane"]
+__all__ = ["CachePlane", "CacheSpec", "LayerGroup", "SidePlane",
+           "TokenLayout", "token_layout"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -84,3 +107,53 @@ class LayerGroup:
     count: int
     xs: Any                    # what ``apply`` gets as ``layer_xs``
     stacked: bool = True       # leading dim ``count`` on every leaf of xs
+
+
+@dataclasses.dataclass(frozen=True)
+class TokenLayout:
+    """Where a tick's tokens lie on the packed axis ``[T]`` and on the
+    rows' grid ``[C, qb]`` (module docstring).  ``dst`` and ``src`` are
+    None where ``T == C * qb``: the packed axis is then the grid."""
+    grid: tuple                # (C, qb)
+    valid: Any                 # [T] bool: packed places that hold a token
+    last: Any                  # [C] int32: packed place of a row's last token
+    dst: Any = None            # [C, qb] int32: packed place a grid place has
+    src: Any = None            # [T] int32: flat grid place a packed place has
+
+    def to_grid(self, a):
+        """``a [T, ...]`` on the grid ``[C, qb, ...]``.  A place of the
+        grid that holds no token reads some token's values: finite, and
+        masked by ``n_valid`` or written to the sink as padding is."""
+        if self.dst is None:
+            return a.reshape(self.grid + a.shape[1:])
+        return a.at[self.dst].get(mode="promise_in_bounds")
+
+    def to_packed(self, a):
+        """``a [C, qb, ...]`` on the packed axis ``[T, ...]``."""
+        a = a.reshape((-1,) + a.shape[2:])
+        if self.src is None:
+            return a
+        return a.at[self.src].get(mode="promise_in_bounds")
+
+
+def token_layout(m, qb: int, places) -> TokenLayout:
+    """The layout of a tick whose row ``c`` carries ``m[c]`` tokens (``m
+    [C]`` int32, 0 for a row without any) on a packed axis of
+    ``places.shape[0]`` places; ``places`` is that axis, ``arange(T)``."""
+    C, T = m.shape[0], places.shape[0]
+    j = jnp.arange(qb, dtype=jnp.int32)
+    held = j[None, :] < m[:, None]                         # [C, qb]
+    if T == C * qb:
+        return TokenLayout(
+            (C, qb), held.reshape(-1),
+            jnp.arange(C, dtype=jnp.int32) * qb + jnp.maximum(m, 1) - 1)
+    end = jnp.cumsum(m, dtype=jnp.int32)
+    start = end - m
+    dst = jnp.where(held, start[:, None] + j[None, :], 0)
+    # the row a packed place belongs to: how many rows end at or before it
+    row = jnp.minimum(
+        (end[None, :] <= places[:, None]).sum(1, dtype=jnp.int32), C - 1)
+    valid = places < end[-1]
+    src = jnp.where(valid, row * qb + places - start[row], 0)
+    return TokenLayout((C, qb), valid, jnp.where(m > 0, end - 1, 0),
+                       dst, src)

@@ -62,6 +62,10 @@ SERVING_STATS_SCHEMA = {
     "spec_proposed_tokens": ("counter", "speculative tokens proposed"),
     "spec_accepted_tokens": ("counter", "speculative tokens accepted"),
     "preemptions": ("counter", "requests preempted for pages"),
+    "token_places": ("counter",
+                     "token places the ticks' dense layers computed (sum "
+                     "of the step sizes taken)"),
+    "tokens_packed": ("counter", "tokens the ticks carried"),
     "wire_export_ms": ("counter",
                        "donor-side host ms materializing migration-wire "
                        "export payloads"),

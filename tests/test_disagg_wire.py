@@ -136,6 +136,25 @@ def _first_shipment(donor_quant=False, overlap=False):
 # -- overlapped wire: staging, deferred commit, bit-identity ----------------
 
 
+def test_export_owns_its_page_ids():
+    """The staged export's gather runs asynchronously while the engine
+    goes on: releasing the slot zeroes its row of ``_full_rows`` at
+    once, so the page ids an export hands to the device are a copy, not
+    a view of that row (a late gather through a view read page 0 for
+    every page and shipped the sink: under six busy workers the fleet's
+    stream then left the solo run's at its second token)."""
+    donor, req = _first_shipment(overlap=True)
+    slot, _tokens, _salt, _hashes, pg = donor._export_meta(req.rid)
+    assert len(pg) == 2 and not np.shares_memory(pg, donor._full_rows)
+    want = donor.export_request_pages(req.rid)
+    staged = donor.stage_request_pages(req.rid)
+    donor._full_rows[slot] = 0             # what releasing the slot does
+    got = donor.finalize_shipment(staged)
+    for name in ("k", "v"):
+        np.testing.assert_array_equal(got[name], want[name])
+    assert got["crc"] == want["crc"]
+
+
 def test_overlap_flag_defaults_off_and_solo_engine_unaffected():
     assert GLOBAL_FLAGS.get("serving_wire_overlap") is False
     assert GLOBAL_FLAGS.get("serving_disagg_dynamic") is False

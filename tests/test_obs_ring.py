@@ -105,7 +105,10 @@ def test_engine_tick_spans_nest_and_lifecycles_are_ordered(ring):
                                  and a <= s[1] and s[2] <= b]
     harvested = 0
     for _, a, b, attrs in steps:
-        assert set(attrs) == {"rows_decode", "rows_prefill", "queued"}
+        assert set(attrs) == {"rows_decode", "rows_prefill", "queued",
+                              "places", "tokens"}
+        assert attrs["places"] in (0,) + eng.rungs
+        assert attrs["tokens"] <= attrs["places"]
         assert len(inside("engine.admit", a, b)) == 1
         assert len(inside("engine.dispatch", a, b)) == 1
         harvests = inside("engine.harvest", a, b)
@@ -120,7 +123,8 @@ def test_engine_tick_spans_nest_and_lifecycles_are_ordered(ring):
     assert sum(s[3]["rows_prefill"] for s in steps) > 0
     assert sum(s[3]["rows_decode"] for s in steps) > 0
     assert steps[-1][3] == {"rows_decode": 0, "rows_prefill": 0,
-                            "queued": 0}
+                            "queued": 0, "places": 0, "tokens": 0}
+    assert sum(s[3]["tokens"] for s in steps) == eng.stats["tokens_packed"]
 
     at = {}
     for e in events:

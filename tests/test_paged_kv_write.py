@@ -144,15 +144,18 @@ def compiled_kernels(monkeypatch):
     compilation_cache.reset_cache()
 
 
-@pytest.mark.parametrize("kv_quant", [False, True], ids=["fp", "int8"])
+@pytest.mark.parametrize("kv_quant,size", [
+    (False, -1), (True, -1), (False, 0)], ids=["fp", "int8", "fp-quarter"])
 def test_step_compiled_for_v5e_updates_the_pool_in_place(
-        one_chip, compiled_kernels, kv_quant):
+        one_chip, compiled_kernels, kv_quant, size):
     """mistral-7b widths, 2 layers x 448 pages of 128 tokens: the
     compiled step aliases both pools to its outputs, holds the write and
     the attention kernels, and its temporaries are smaller than ONE
     layer's pages (fp), or hold no more than the int8 step's one copy of
     a layer's pages for the attention — no second pool, no transposed
-    layer."""
+    layer. At the whole grid (``rungs[-1]``, 512 token places) and at
+    the smallest step size (128), whose gathers between the packed
+    tokens and the grid must not cost the pool its place either."""
     from paddle_tpu.inference.serving import ServingEngine
     from paddle_tpu.models.llama import LlamaConfig, init_llama_params
 
@@ -166,7 +169,8 @@ def test_step_compiled_for_v5e_updates_the_pool_in_place(
                            prefix_cache=True, qb=16, kv_quant=kv_quant)
     args = jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
-        engine.unified_arg_shapes())
+        engine.unified_arg_shapes(engine.rungs[size]))
+    assert engine.rungs == (128, 512)
     compiled = engine._unified.lower(*args).compile()
     text = compiled.as_text()
     assert "paged_kv_write" in text and "ragged_paged_attention" in text

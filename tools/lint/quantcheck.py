@@ -307,7 +307,7 @@ def build_serving_fp32_entry() -> QuantEntry:
     names = (["params" + n for n in _flatten_names(eng.params)]
              + ["k_pages", "v_pages", "tokens", "prev_out", "chain_mask",
                 "chain_row", "ptable", "row_slot", "pos0", "n_valid",
-                "temps", "topps", "seeds"])
+                "temps", "topps", "seeds", "places"])
     return QuantEntry(name="serving_unified_fp32", closed=closed,
                       source="paddle_tpu/inference/serving.py",
                       invar_names=names)
@@ -336,7 +336,7 @@ def build_serving_mla_moe_entry() -> QuantEntry:
     names = (["params" + n for n in _flatten_names(eng.params)]
              + ["k_pages", "v_pages", "tokens", "prev_out", "chain_mask",
                 "chain_row", "ptable", "row_slot", "pos0", "n_valid",
-                "temps", "topps", "seeds"])
+                "temps", "topps", "seeds", "places"])
     return QuantEntry(name="serving_unified_mla_moe", closed=closed,
                       source="paddle_tpu/models/mla_moe.py",
                       invar_names=names)
@@ -357,7 +357,8 @@ def build_serving_int8_entry() -> QuantEntry:
     names = (["params" + s for s in _flatten_names(eng.params)]
              + ["k_pages", "v_pages", "tokens", "prev_out", "chain_mask",
                 "chain_row", "ptable", "row_slot", "pos0", "n_valid",
-                "temps", "topps", "seeds", "k_scales", "v_scales"])
+                "temps", "topps", "seeds", "k_scales", "v_scales",
+                "places"])
     return QuantEntry(name="serving_unified_int8kv", closed=closed,
                       source="paddle_tpu/models/llama.py",
                       invar_names=names,
