@@ -430,11 +430,21 @@ def _serve_kernel_parity(leg: ServeLeg, engine: ServingEngine,
     sm = 1.0 / math.sqrt(dH)
     want = jax.jit(lambda *o: RPA._ragged_paged_xla(*o, sm, "d_major"))(
         q, kp, vp, rows, pos0, n_valid)
+    # and under a sliding window that is no multiple of the page, where
+    # most rows have groups behind it to skip
+    window = 3 * bs + bs // 2
+    want_w = jax.jit(lambda *o: RPA._ragged_paged_xla(
+        *o, sm, "d_major", window=window))(q, kp, vp, rows, pos0, n_valid)
     for impl in RPA.candidates_for(kp.shape, nH, qb, mb)[:-1]:
+        pps = int(impl.split("_p")[1])
         check_close(checks, f"ragged_paged_attention_{impl}",
                     RPA.ragged_paged_attention_kernel(
-                        q, kp, vp, rows, pos0, n_valid, sm,
-                        pps=int(impl.split("_p")[1])), want, TOL_BF16)
+                        q, kp, vp, rows, pos0, n_valid, sm, pps=pps),
+                    want, TOL_BF16)
+        check_close(checks, f"ragged_paged_attention_window_{impl}",
+                    RPA.ragged_paged_attention_kernel(
+                        q, kp, vp, rows, pos0, n_valid, sm, pps=pps,
+                        window=window), want_w, TOL_BF16)
 
     # the write into the pages, on as many chunks of that grid as can own
     # the two pages a chunk may touch (the kernel's contract: one writer

@@ -58,7 +58,7 @@ from jax import lax
 from ..core.flags import GLOBAL_FLAGS
 from ..models.llama import (LlamaConfig, LlamaServing,
                             quantize_weights_int8)
-from ..models.seam import token_layout
+from ..models.seam import cache_classes, token_layout
 from ..obs import clock as _clock
 from ..testing import chaos as _chaos
 from .. import obs as _obs
@@ -171,39 +171,47 @@ def wire_scatter_pages(pages, pg, payload):
     return pages.at[:, pg].set(payload)
 
 
-def _run_layer_groups(body, x, k_pages, v_pages, groups, side=()):
+def _run_layer_groups(body, x, pools, groups, side=()):
     """The unified step's layer loop, with the KV pool as a loop CARRY.
 
-    The addressing rule, stated once: outside the program the pool is
-    ``[L, P, ...]``; inside it is ``[L*P, ...]`` (a reshape of the two
-    leading dims) and layer ``l`` finds page ``p`` at ``l*P + p``, so
-    the sink — page 0, where padding writes — is page ``l*P`` of each
-    layer. A ``lax.scan`` reads its ``xs`` and stacks fresh ``ys``, so a
-    pool scanned that way can never be updated where it lies; the carry
-    can, and the donated input buffer becomes the output.
+    The addressing rule, stated once: outside the program a cache
+    class's pool (models/seam.py) is ``[L, P, ...]``; inside it is
+    ``[L*P, ...]`` (a reshape of the two leading dims) and layer ``l``
+    of the class finds page ``p`` at ``l*P + p``, so the sink — page 0,
+    where padding writes — is page ``l*P`` of each layer. A ``lax.scan``
+    reads its ``xs`` and stacks fresh ``ys``, so a pool scanned that way
+    can never be updated where it lies; the carry can, and the donated
+    input buffer becomes the output.
 
-    ``body(x, k_pool, v_pool, base, layer_xs, *side) -> (x, k_pool,
-    v_pool, ys, *side)`` sees the flattened pools and ``base = l*P`` to
-    add to every page id it scatters to or attends over. ``groups``
-    (models/seam.py: LayerGroup) cover the layers in order: a stacked
-    group is scanned over its own layers ``first .. first+count-1``, a
-    single unstacked layer is applied where it stands. The side planes
-    (``[L, P, ...]``, small) do travel scanned: a layer gets its own
-    ``[P, ...]`` slice under the layer's own page ids and its update is
-    stacked. Returns ``(x, k_pages, v_pages, [ys per group], side)``
-    with the pools back in their ``[L, P, ...]`` shape."""
-    L, P = k_pages.shape[:2]
-
-    def step(carry, inp):
-        l, layer_xs, side_l = inp
-        x, kp, vp, ys, *side_l = body(*carry, l * P, layer_xs, *side_l)
-        return (x, kp, vp), (ys, tuple(side_l))
-
-    carry = (x, k_pages.reshape((L * P,) + k_pages.shape[2:]),
-             v_pages.reshape((L * P,) + v_pages.shape[2:]))
+    ``pools`` holds a ``(k_pages, v_pages)`` pair per cache class, in
+    the classes' order; a group's layers see the pair of ITS class
+    (``LayerGroup.cache``) and no other rides in its loop.
+    ``body(group, x, k_pool, v_pool, base, layer_xs, *side) -> (x,
+    k_pool, v_pool, ys, *side)`` sees the class's flattened pools and
+    ``base = l*P`` to add to every page id it scatters to or attends
+    over. ``groups`` (models/seam.py: LayerGroup) cover the layers in
+    the model's order: a stacked group is scanned over its own layers,
+    ``first .. first+count-1`` of its class, a single unstacked layer is
+    applied where it stands. The side planes (``[L, P, ...]``, small,
+    class 0's alone) do travel scanned: a layer gets its own ``[P,
+    ...]`` slice under the layer's own page ids and its update is
+    stacked. Returns ``(x, pools, [ys per group], side)`` with the
+    pools back in their ``[L, P, ...]`` shapes."""
+    flat = [tuple(a.reshape((-1,) + a.shape[2:]) for a in kv)
+            for kv in pools]
     all_ys, parts = [], []
     for g in groups:
-        side_g = tuple(s[g.first:g.first + g.count] for s in side)
+        P = pools[g.cache][0].shape[1]
+
+        def step(carry, inp, g=g, P=P):
+            l, layer_xs, side_l = inp
+            x, kp, vp, ys, *side_l = body(g, *carry, l * P, layer_xs,
+                                          *side_l)
+            return (x, kp, vp), (ys, tuple(side_l))
+
+        carry = (x,) + flat[g.cache]
+        side_g = (tuple(s[g.first:g.first + g.count] for s in side)
+                  if g.cache == 0 else ())
         if g.stacked:
             carry, (ys, side_g) = lax.scan(
                 step, carry,
@@ -213,13 +221,14 @@ def _run_layer_groups(body, x, k_pages, v_pages, groups, side=()):
             carry, (ys, side_g) = step(
                 carry, (g.first, g.xs, tuple(s[0] for s in side_g)))
             side_g = tuple(s[None] for s in side_g)
+        x, flat[g.cache] = carry[0], carry[1:]
         all_ys.append(ys)
-        parts.append(side_g)
-    x, kp, vp = carry
+        if g.cache == 0:
+            parts.append(side_g)
     side = tuple(p[0] if len(p) == 1 else jnp.concatenate(p)
                  for p in zip(*parts))
-    return (x, kp.reshape(k_pages.shape), vp.reshape(v_pages.shape),
-            all_ys, side)
+    return (x, [tuple(a.reshape(b.shape) for a, b in zip(f, kv))
+                for f, kv in zip(flat, pools)], all_ys, side)
 
 
 def kv_scale_reset(scales, page_ids, axis: int = 0):
@@ -313,6 +322,14 @@ class _PagePool:
         self.misses += len(hashes) - len(out)
         return out
 
+    def peek(self, hashes: list[bytes]) -> int:
+        """Length of the longest cached prefix of ``hashes``; claims
+        nothing."""
+        n = 0
+        while n < len(hashes) and hashes[n] in self.cache:
+            n += 1
+        return n
+
     def insert(self, h: bytes, page: int) -> bool:
         """Register an (already-written) page under its prefix hash at
         refcount 1; False if the hash is already cached (the caller
@@ -350,6 +367,88 @@ class _PagePool:
         return done
 
 
+class _ClassPages:
+    """A further cache class's pools, table, allocator and page
+    ownership (models/seam.py: cache classes; class 0's are the engine's
+    own ``k_pages``, ``pool``, ``_full_rows``, ``_slot_owned`` ...). A
+    request holds pages here only for the blocks its layers can still
+    read: ``owned`` / ``shared`` map a slot's logical blocks to pages,
+    ``tail[slot]`` is its first block not yet let go, and ``peak[slot]``
+    what it may hold at once (admission reserves that much)."""
+
+    def __init__(self, cls, n_pages: int, B: int, max_blocks: int,
+                 cache_limit: int):
+        self.cls, self.n_pages = cls, n_pages
+        self.k_pages, self.v_pages = (
+            jnp.zeros((cls.n_layers, n_pages) + plane.page_shape,
+                      cls.spec.dtype) for plane in cls.spec.planes)
+        self.pool = _PagePool(n_pages, cache_limit=cache_limit)
+        self.full_rows = np.zeros((B, max_blocks), np.int32)
+        self.owned: list[dict] = [{} for _ in range(B)]
+        self.shared: list[dict] = [{} for _ in range(B)]
+        self.tail = [0] * B
+        self.peak = [0] * B
+        self.deferred_free: list[int] = []
+
+    def room(self) -> int:
+        """Pages an admission may still reserve: what no live request
+        has reserved and no finished one has left waiting for a harvest
+        (the rest is free or evictable, or will be when asked for)."""
+        return (self.n_pages - 1 - sum(self.peak) - len(self.deferred_free)
+                - len(self.pool.pending_evict))
+
+    def live_pages(self) -> int:
+        return sum(map(len, self.owned)) + sum(map(len, self.shared))
+
+    def alloc(self, n: int) -> Optional[list[int]]:
+        """``n`` pages, reclaiming idle cached ones where the free list
+        runs short (the engine's _alloc_pages, for this class)."""
+        if len(self.pool.free) < n:
+            self.pool.evict(n - len(self.pool.free))
+        return self.pool.alloc(n)
+
+    def settle(self) -> None:
+        """Once no in-flight program can read them: deferred pages to
+        the free list, refcount-0 cached pages to the evictable set."""
+        self.pool.release(self.deferred_free)
+        self.deferred_free = []
+        self.pool.commit_evictable()
+
+    def release_block(self, slot: int, blk: int) -> bool:
+        """Let go of what ``slot`` holds for logical block ``blk`` (the
+        table slot goes to the sink); the page waits for ``settle``."""
+        self.full_rows[slot, blk] = 0
+        page = self.owned[slot].pop(blk, None)
+        if page is not None:
+            self.deferred_free.append(page)
+            return True
+        page = self.shared[slot].pop(blk, None)
+        if page is not None:
+            self.pool.decref([page])
+        return page is not None
+
+    def release_slot(self, slot: int, defer: bool) -> None:
+        owned = list(self.owned[slot].values())
+        self.pool.decref(list(self.shared[slot].values()))
+        self.owned[slot], self.shared[slot] = {}, {}
+        self.full_rows[slot] = 0
+        self.tail[slot] = self.peak[slot] = 0
+        self.deferred_free.extend(owned)
+        if not defer:
+            self.settle()
+
+    def accounting(self) -> dict:
+        counts = {
+            "free": len(self.pool.free),
+            "slot_owned": sum(map(len, self.owned)),
+            "slot_shared": len({p for d in self.shared
+                                for p in d.values()}),
+            "cache_idle": sum(1 for r in self.pool.ref.values() if r == 0),
+            "deferred_free": len(self.deferred_free)}
+        counts["total"] = sum(counts.values())
+        return counts
+
+
 class ServingEngine:
     """Continuous-batching serving over paged KV, for any model behind
     the seam (models/seam.py).
@@ -384,7 +483,8 @@ class ServingEngine:
                  constrained: Optional[bool] = None,
                  engine_id: int = 0,
                  prefill_only: bool = False,
-                 wire_overlap: Optional[bool] = None):
+                 wire_overlap: Optional[bool] = None,
+                 class_pages: Optional[dict] = None):
         # fleet identity: names this replica in router health/stats and
         # targets chaos specs (fire(..., ctx={"engine": id})); a lone
         # engine keeps the default 0 and never consults it otherwise
@@ -522,18 +622,33 @@ class ServingEngine:
         # everywhere outside the step program; inside, layer l finds
         # page p at l*P + p (_run_layer_groups), and page 0 of each
         # layer is its sink.
-        L = self.model.n_layers
-        self.cache_spec = spec = self.model.cache_spec(self.bs)
+        # A model whose layers do not all keep a token equally long
+        # declares cache classes (models/seam.py): class 0, whose layers
+        # read everything, is what this engine always had (k_pages,
+        # v_pages, pool, _full_rows, _slot_owned ...); each further
+        # class has its own of each in ``_extra``, ``class_pages[name]``
+        # pages of it (n_pages where not given).
+        self.classes = cache_classes(self.model, self.bs)
+        L = self.classes[0].n_layers
+        self.cache_spec = spec = self.classes[0].spec
         self.k_pages, self.v_pages = (
             jnp.zeros((L, self.n_pages) + plane.page_shape, spec.dtype)
             for plane in spec.planes)
         self.side_planes = {
             plane.name: jnp.zeros((L, self.n_pages) + plane.page_shape,
                                   plane.dtype) for plane in spec.side}
-        _obs.instant("engine.cache_spec", engine=self.engine_id,
-                     bytes_per_token=self.kv_bytes_per_token(),
-                     planes=",".join(f"{p.name}:{p.width}"
-                                     for p in spec.planes))
+        self._extra = [
+            _ClassPages(c, int((class_pages or {}).get(c.name,
+                                                       self.n_pages)),
+                        self.B, self.max_blocks, prefix_cache_pages)
+            for c in self.classes[1:]]
+        for i, c in enumerate(self.classes):
+            _obs.instant("engine.cache_spec", engine=self.engine_id,
+                         bytes_per_token=self.kv_bytes_per_token(i),
+                         planes=",".join(f"{p.name}:{p.width}"
+                                         for p in c.spec.planes),
+                         cache_class=c.name, layers=c.n_layers,
+                         window=c.window or 0)
         self.table = np.zeros((self.B, self.max_blocks), np.int32)  # sink
         self.seq_lens = np.zeros((self.B,), np.int32)
         self.cur_tok = np.zeros((self.B,), np.int32)
@@ -579,9 +694,12 @@ class ServingEngine:
         self._schemas: dict = {}           # schema id -> ConstraintState factory
         # the pools and the side planes (the first of ``rest``) are the
         # step's to update where they lie
+        x0 = 14 + len(spec.side)       # the further classes' first operand
         self._unified = jax.jit(
             self._unified_step_impl,
-            donate_argnums=(1, 2) + tuple(range(14, 14 + len(spec.side))))
+            donate_argnums=(1, 2) + tuple(range(14, x0)) + tuple(
+                x0 + 3 * i + j for i in range(len(self._extra))
+                for j in (0, 1)))
         # pipelining state (see step() docstring): _inflight holds the
         # dispatched-but-unharvested program's (output tokens, row
         # snapshot); _prev_out_dev chains row outputs on-device into the
@@ -630,6 +748,19 @@ class ServingEngine:
         self.stats.update(dict.fromkeys(
             getattr(self.model, "stats_keys", ()), 0))
         self._tick_stats: dict = {}
+        if self._extra:
+            # what the classes do, summed over ticks: pages live
+            # requests hold in each class and the context tokens they
+            # stand for (bytes a context token: the benchmark's
+            # kv_live_bytes_per_context_token), pages the windows let
+            # go, and tokens of prefix hits that class 0 had and a
+            # windowed class had lost
+            self.stats.update(dict.fromkeys(
+                [f"pages_live.{c.name}" for c in self.classes]
+                + ["context_tokens_live", "pages_released_by_window",
+                   "prefill_window_lost_tokens"], 0))
+        # a dispatched tick's share of them, for engine.step's end
+        self._class_tick: dict = {}
 
     def _require(self, feature: str) -> None:
         """One error for every engine feature the served model's seam
@@ -642,6 +773,12 @@ class ServingEngine:
 
     # -- compiled program ---------------------------------------------------
 
+    # The first eleven operands keep their places and class 0's pools and
+    # table are k_pages, v_pages and ptable whatever classes the model
+    # declares: the benchmark's systems wrap this function by position
+    # (benchmark/systems/llama_serve.py: record_dispatches). Everything
+    # only some engines have rides in ``rest``, ahead of its last
+    # operand, whose LENGTH is the step size.
     def _unified_step_impl(self, params, k_pages, v_pages, tokens,
                            prev_out, chain_mask, chain_row, ptable,
                            row_slot, pos0, n_valid, temps, topps, seeds,
@@ -666,10 +803,12 @@ class ServingEngine:
         serializing with it (its cost is not measured on a locally
         attached chip).
 
-        Returns (out, k_pages, v_pages, ys, side); ys holds the layers'
-        counters, one entry per layer group, None for a group that keeps
-        none (models/seam.py), side the updated side planes (an empty
-        tuple for a page format without any): out [C, 1] — each row's
+        Returns (out, k_pages, v_pages, ys, side, further); ys holds the
+        layers' counters, one entry per layer group, None for a group
+        that keeps none (models/seam.py), side the updated side planes
+        (an empty tuple for a page format without any), further the
+        updated pools of the cache classes beyond class 0, k and v a
+        class (empty for a model with one class): out [C, 1] — each row's
         pick after its last valid token — or [C, qb] with per-position
         picks when speculative verification needs the full ladder.
         Per-token KV write (ops/pallas/paged_kv_write.py): valid tokens
@@ -682,21 +821,27 @@ class ServingEngine:
         C, qb = tokens.shape
 
         # what only some engines have rides as trailing varargs: the
-        # page format's side planes (cache_spec.side), then the
+        # page format's side planes (cache_spec.side), then each further
+        # cache class's k pool, v pool and page table, then the
         # multi-tenant operands — row adapter slot ids + the four adapter
         # stacks (serving_lora), then the per-row [C, V] vocab legality
         # mask (serving_constrained). Behind them all, ``places``: the
         # packed axis arange(T), whose LENGTH is the step size this tick
         # runs at (``rungs``): one jit, one program a length
         n_side = len(self.cache_spec.side)
-        side, mt, places = rest[:n_side], list(rest[n_side:-1]), rest[-1]
+        n_x = n_side + 3 * len(self._extra)
+        side, further = rest[:n_side], rest[n_side:n_x]
+        mt, places = list(rest[n_x:-1]), rest[-1]
+        pools = [(k_pages, v_pages)] + [
+            (further[i], further[i + 1]) for i in range(0, len(further), 3)]
         if self._lora_on:
             aid, ast = mt.pop(0), mt.pop(0)
         vmask = mt.pop(0) if self._constr_on else None
 
         tok0 = jnp.where(chain_mask, prev_out[chain_row, 0], tokens[:, 0])
         tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
-        rows = ptable[row_slot]                      # [C, max_blocks]
+        # a row's block-table row in each class      # [C, max_blocks]
+        rows = [t[row_slot] for t in (ptable, *further[2::3])]
         positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
         # the tick's tokens packed: an idle row (the sink's) carries none
         lay = token_layout(
@@ -711,12 +856,14 @@ class ServingEngine:
         else:
             groups = model.layer_groups(params)
 
-        def body(x, kp, vp, base, inp, *side_l):
-            return model.apply(x, kp, vp, base, inp, rows, pos0, n_valid,
-                               ctx, *side_l)
+        def body(g, x, kp, vp, base, inp, *side_l):
+            return model.apply(x, kp, vp, base, inp, rows[g.cache], pos0,
+                               n_valid, dict(ctx, cache_class=g.cache),
+                               *side_l)
 
-        x, ks, vs, ys, side = _run_layer_groups(body, x, k_pages, v_pages,
-                                                groups, side)
+        x, pools, ys, side = _run_layer_groups(body, x, pools, groups, side)
+        (ks, vs), further = pools[0], tuple(a for kv in pools[1:]
+                                             for a in kv)
         if self.spec_k:
             # speculative verify needs the model's pick at EVERY draft
             # position; keying on each input position keeps the accepted
@@ -743,7 +890,7 @@ class ServingEngine:
             out = _pick_tokens(logits, temps, topps, seeds,
                                pos0 + n_valid - 1)[:, None]
         # the layers' counters ride out with the picks
-        return out, ks, vs, ys, side
+        return out, ks, vs, ys, side, further
 
     def unified_arg_shapes(self, rung: Optional[int] = None) -> tuple:
         """Shape-only arguments of the unified step, mirroring the live
@@ -775,6 +922,8 @@ class ServingEngine:
                 sds(self.v_pages), tokens, prev, cmask, crow, ptab, col_i,
                 col_i, col_i, col_f, col_f, col_i,
                 *map(sds, self.side_planes.values()),
+                *(a for x in self._extra
+                  for a in (sds(x.k_pages), sds(x.v_pages), ptab)),
                 jax.ShapeDtypeStruct((rung,), i32))
 
     def lower_unified(self, rung: Optional[int] = None):
@@ -835,6 +984,13 @@ class ServingEngine:
             raise ValueError(
                 f"request {req.rid}: needs {n_blk} pages but the pool "
                 f"holds {self.n_pages - 1} — it could never be admitted")
+        for x in self._extra:
+            if self._class_peak(x, n_blk) > x.n_pages - 1:
+                raise ValueError(
+                    f"request {req.rid}: holds up to "
+                    f"{self._class_peak(x, n_blk)} pages of class "
+                    f"'{x.cls.name}' at once but its pool holds "
+                    f"{x.n_pages - 1} — it could never be admitted")
         if req.adapter_id is not None:
             if not self._lora_on:
                 raise ValueError(
@@ -965,6 +1121,85 @@ class ServingEngine:
                 for name, plane in self.side_planes.items()}
         return pages
 
+    # -- further cache classes (models/seam.py) ---------------------------
+
+    def _class_peak(self, x: _ClassPages, n_blk: int) -> int:
+        """Pages of a windowed class a request of ``n_blk`` blocks may
+        hold at once: the blocks a query can still read (the window,
+        and one more where it straddles), those the ticks in flight and
+        being built write (a tick writes ``prefill_budget`` tokens at
+        most), and those the last tick let go of, which wait for its
+        harvest."""
+        per_tick = -(-self.prefill_budget // self.bs)
+        return min(n_blk, -(-x.cls.window // self.bs) + 2 * per_tick + 2)
+
+    def _next_query(self, slot: int) -> int:
+        """The position of a live slot's next query: how far its context
+        has been dispatched."""
+        return (self._prefilling[slot] if slot in self._prefilling
+                else int(self.seq_lens[slot]))
+
+    def _usable_hit(self, hashes: list[bytes]) -> tuple:
+        """(n, n0): the longest prefix hit every class can honour, in
+        pages, and the longest class 0 alone has. A hit of ``n`` pages
+        needs class 0 to hold pages ``[0, n)`` and every windowed class
+        the pages a query at ``n * bs`` can still read."""
+        n0 = self.pool.peek(hashes)
+        runs = []                   # per class: cached pages in a row
+        for x in self._extra:       # ending at page j, for j < n0
+            run, r = [], 0
+            for h in hashes[:n0]:
+                r = r + 1 if h in x.pool.cache else 0
+                run.append(r)
+            runs.append(run)
+        n = n0
+        while n and any(
+                run[n - 1] < n - x.cls.live_from(n * self.bs) // self.bs
+                for x, run in zip(self._extra, runs)):
+            n -= 1
+        return n, n0
+
+    def _grow_classes(self, sched: list) -> None:
+        """Give every row of the tick being built the pages it writes in
+        the further classes (class 0 took its own at admission). The
+        room was reserved at admission (_ClassPages.room)."""
+        for x in self._extra:
+            need = [(s, b) for s, _kind, pos, m, _d in sched
+                    for b in range(pos // self.bs,
+                                   (pos + m - 1) // self.bs + 1)
+                    if not x.full_rows[s, b]]
+            need = list(dict.fromkeys(need))
+            if not need:
+                continue
+            pages = x.alloc(len(need))
+            if pages is None:
+                raise RuntimeError(
+                    f"cache class '{x.cls.name}' has no {len(need)} pages "
+                    f"for a tick although admission reserved them: "
+                    f"{x.accounting()}")
+            for (s, b), page in zip(need, pages):
+                x.full_rows[s, b] = page
+                x.owned[s][b] = page
+
+    def _release_behind_windows(self) -> int:
+        """Between ticks: in every windowed class, let go of the blocks
+        no query of a live request can read any more (seam.py: the
+        rules). The tick in flight may still read them, so the pages
+        wait for its harvest as a finished request's do. Returns the
+        pages let go."""
+        n = 0
+        for s in range(self.B):
+            if self.slots[s] is None:
+                continue
+            nq = self._next_query(s)
+            for x in self._extra:
+                first = x.cls.live_from(nq) // self.bs
+                for b in range(x.tail[s], first):
+                    n += x.release_block(s, b)
+                x.tail[s] = max(x.tail[s], first)
+        self.stats["pages_released_by_window"] += n
+        return n
+
     def _admit(self, now: float) -> None:
         """Admit arrived requests into free slots, FIFO with skip: a
         pool-blocked request is stepped over so smaller requests behind
@@ -972,7 +1207,10 @@ class ServingEngine:
         (skip count) exceeds ``admit_aging`` it becomes a barrier —
         nothing behind it is admitted, so every freed page goes to it
         and it cannot starve. Admission maps cached prefix pages into
-        the block table (incref) and allocates only the rest."""
+        the block table (incref) and allocates only the rest. With
+        further cache classes it needs room in each (_ClassPages.room)
+        and takes the longest hit every class can honour
+        (_usable_hit)."""
         free_slots = [s for s in range(self.B) if self.slots[s] is None]
         cand = list(self.queue)
         if self._prio_on:
@@ -1012,14 +1250,23 @@ class ServingEngine:
             aslot = 0
             if self._lora_on and req.adapter_id is not None:
                 aslot = self.adapters.acquire(req.adapter_id)
-            if aslot is None:              # adapter-blocked == pool-blocked
+            if aslot is None or any(      # adapter- or class-blocked
+                    x.room() < self._class_peak(x, n_blk)
+                    for x in self._extra):       # == pool-blocked
                 shared, pages = [], None
             else:
                 # never look up the page holding the last prompt token:
                 # its chunk must run to produce the first-token logits
                 hashes = (self._page_hashes(P, self._cache_salt(req))
                           if self._cache_on else [])
-                shared = self.pool.lookup(hashes[:(T - 1) // self.bs])
+                limit = hashes[:(T - 1) // self.bs]
+                if self._extra and limit:
+                    n_hit, n0 = self._usable_hit(limit)
+                    self.pool.misses += len(limit) - n_hit
+                    self.stats["prefill_window_lost_tokens"] += (
+                        (n0 - n_hit) * self.bs)
+                    limit = limit[:n_hit]
+                shared = self.pool.lookup(limit)
                 pages = self._alloc_pages(n_blk - len(shared))
             if pages is None:
                 self.pool.decref(shared)
@@ -1062,6 +1309,16 @@ class ServingEngine:
             row[:n_shared] = shared
             row[n_shared:n_blk] = pages
             self._full_rows[slot] = row
+            for x in self._extra:
+                # the pages a query at the hit's end can still read,
+                # claimed under the same hashes; the rest come as the
+                # request grows (_grow_classes)
+                first = x.cls.live_from(n_shared * self.bs) // self.bs
+                got = x.pool.lookup(hashes[first:n_shared])
+                x.shared[slot] = dict(zip(range(first, n_shared), got))
+                x.full_rows[slot, first:n_shared] = got
+                x.tail[slot] = first
+                x.peak[slot] = self._class_peak(x, n_blk)
             self.table[slot] = 0           # decode view: sink until flip
             self.seq_lens[slot] = 0
             self.cur_tok[slot] = 0
@@ -1127,6 +1384,8 @@ class ServingEngine:
             self.pool.release(owned)
             self.pool.commit_evictable()
         self._full_rows[slot] = 0
+        for x in self._extra:
+            x.release_slot(slot, defer)
         # adapter refcount rides slot residency: every teardown path
         # (finish / abort / preempt / predictive release) lands here.
         # Idempotent — the id is cleared on first release.
@@ -1205,6 +1464,7 @@ class ServingEngine:
         with _obs.span("engine.admit", engine=self.engine_id):
             self._admit(now)
         prev = self._inflight
+        self._class_tick = {}
         with _obs.span("engine.dispatch", engine=self.engine_id):
             self._dispatch_unified(now)
         # what this tick put on the device, recorded on engine.step's
@@ -1215,7 +1475,7 @@ class ServingEngine:
         n_dec = sum(1 for r in rows if r[3] == "dec")
         tick = dict(rows_decode=n_dec, rows_prefill=len(rows) - n_dec,
                     queued=len(self.queue), places=places,
-                    tokens=sum(r[4] for r in rows))
+                    tokens=sum(r[4] for r in rows), **self._class_tick)
         sp.set(**tick)
         # synchronous modes (spec, constrained): drafts and vocab masks
         # are host state derived from the previous step's tokens, so each
@@ -1228,11 +1488,18 @@ class ServingEngine:
                 self._harvest(harvest)
             if self._tick_stats:
                 # and what the harvested step's layers counted
-                sp.set(**tick, **self._tick_stats)
+                tick.update(self._tick_stats)
+                sp.set(**tick)
         if self.prefill_only:
             self._export_completed()
-        if self._inflight is None and (self._deferred_free
-                                       or self.pool.pending_evict):
+        if self._extra:
+            # and what the windows let go of behind the tick
+            sp.set(**tick, pages_released_by_window=(
+                self._release_behind_windows()))
+        if self._inflight is None and (
+                self._deferred_free or self.pool.pending_evict or any(
+                    x.deferred_free or x.pool.pending_evict
+                    for x in self._extra)):
             # nothing in flight: deferred/pending pages can only be
             # touched by programs already chained BEFORE any future
             # consumer (the donated page arrays serialize every
@@ -1241,6 +1508,8 @@ class ServingEngine:
             self.pool.release(self._deferred_free)
             self._deferred_free = []
             self.pool.commit_evictable()
+            for x in self._extra:
+                x.settle()
         # predictive release: each in-flight token-bearing row yields
         # exactly one token (speculation off), so a request the just-
         # dispatched step completes can give up its SLOT now — the next
@@ -1366,6 +1635,8 @@ class ServingEngine:
             pref_touched[slot] = pos
         if not sched:
             return
+        if self._extra:
+            self._grow_classes(sched)
         tokens = np.zeros((C, qb), np.int32)
         rs = np.full((C,), self.B, np.int32)       # idle rows -> sink row
         p0 = np.zeros((C,), np.int32)
@@ -1425,15 +1696,25 @@ class ServingEngine:
             extra.append(jnp.array(vm))
         fixed = (jnp.array(tokens), prev_out, jnp.array(cmask),
                  jnp.array(crow), jnp.array(ptab))
+        # each further class's table, as class 0's: the slots' rows and
+        # the sink's row for idle rows
+        xtabs = [jnp.array(np.concatenate(
+            [x.full_rows, np.zeros((1, self.max_blocks), np.int32)]))
+            for x in self._extra]
         per_row = (jnp.array(p0), jnp.array(nv), jnp.array(tt),
                    jnp.array(tp), jnp.array(tsd))
 
         def launch(row_slot, rung):
-            out, self.k_pages, self.v_pages, ys, side = self._unified(
-                self.params, self.k_pages, self.v_pages, *fixed, row_slot,
-                *per_row, *self.side_planes.values(), *extra,
-                self._places[rung])
+            out, self.k_pages, self.v_pages, ys, side, further = (
+                self._unified(
+                    self.params, self.k_pages, self.v_pages, *fixed,
+                    row_slot, *per_row, *self.side_planes.values(),
+                    *(a for x, t in zip(self._extra, xtabs)
+                      for a in (x.k_pages, x.v_pages, t)),
+                    *extra, self._places[rung]))
             self.side_planes = dict(zip(self.side_planes, side))
+            for i, x in enumerate(self._extra):
+                x.k_pages, x.v_pages = further[2 * i:2 * i + 2]
             return out, ys
 
         # the smallest step size that holds the tick's tokens
@@ -1467,6 +1748,12 @@ class ServingEngine:
                 if self.pool.insert(hashes[j], page):
                     self._slot_owned[slot].remove(page)
                     self._slot_shared[slot].append(page)
+                for x in self._extra:
+                    # and in every class that still holds the block
+                    page = int(x.full_rows[slot, j])
+                    if j in x.owned[slot] and x.pool.insert(hashes[j],
+                                                            page):
+                        x.shared[slot][j] = x.owned[slot].pop(j)
             self._slot_offered[slot] = max(self._slot_offered[slot], j1)
         for idx, s, req, kind, m, drafts in snap:
             if kind == "fin":
@@ -1503,6 +1790,20 @@ class ServingEngine:
             sum(m for _s, kind, _p, m, _d in sched if kind == "dec")
             + len(fin_slots) + n_mid_slots + n_idle)
         self.stats["unified_steps"] += 1
+        if self._extra:
+            # what live requests hold in each class against the context
+            # it stands for, this tick and summed
+            live = {f"pages_live.{self.classes[0].name}": sum(
+                len(self._slot_owned[s]) + len(self._slot_shared[s])
+                for s in range(self.B) if self.slots[s] is not None)}
+            live.update({f"pages_live.{x.cls.name}": x.live_pages()
+                         for x in self._extra})
+            live["context_tokens_live"] = sum(
+                self._next_query(s) for s in range(self.B)
+                if self.slots[s] is not None)
+            for k, v in live.items():
+                self.stats[k] += v
+            self._class_tick = live
         if decoding:
             self.stats["decode_steps"] += 1
         if n_pf_rows:
@@ -1528,6 +1829,8 @@ class ServingEngine:
         self.pool.release(self._deferred_free)
         self._deferred_free = []
         self.pool.commit_evictable()
+        for x in self._extra:
+            x.settle()
         now = _clock.now()
         for idx, s, req, kind, m, drafts in snap:
             if kind == "mid":
@@ -2113,17 +2416,20 @@ class ServingEngine:
             self.abort_adopt(handle)
             return 0
 
-    def kv_bytes_per_page(self) -> float:
-        """HBM bytes one KV page costs across all layers, including the
-        page's share of the side planes. The structural capacity
+    def kv_bytes_per_page(self, cls: int = 0) -> float:
+        """HBM bytes one KV page of cache class ``cls`` costs across the
+        class's layers (all layers, for a model with one class),
+        including the page's share of the side planes. The capacity
         argument for serving_kv_quant: at a fixed page-pool byte budget
         the pool holds bytes_bf16/bytes_int8 ~ 2x the pages, hence ~2x
         the concurrent sequences."""
-        return float(self.cache_spec.page_bytes(self.model.n_layers))
+        c = self.classes[cls]
+        return float(c.spec.page_bytes(c.n_layers))
 
-    def kv_bytes_per_token(self) -> float:
-        """Amortized KV bytes per cached token (page bytes / page size)."""
-        return self.kv_bytes_per_page() / self.bs
+    def kv_bytes_per_token(self, cls: int = 0) -> float:
+        """Amortized KV bytes per cached token of class ``cls`` (page
+        bytes / page size)."""
+        return self.kv_bytes_per_page(cls) / self.bs
 
     def page_accounting(self) -> dict:
         """Page census for the leak invariant: every non-sink page is in
@@ -2132,7 +2438,11 @@ class ServingEngine:
         evictable) / deferred-free / adapter (resident LoRA weights) /
         in-flight (migration pages staged by begin_adopt, not yet
         committed or rolled back); the counts sum to n_pages - 1 —
-        per engine, and therefore fleet-wide by summation."""
+        per engine, and therefore fleet-wide by summation. These are
+        class 0's pages; an engine with further cache classes adds
+        ``classes``: each class's own ledger by name (free / slot-owned /
+        slot-shared / idle-cached / deferred-free, summing to its
+        pool's pages less the sink)."""
         owned = [p for lst in self._slot_owned for p in lst]
         shared = {p for lst in self._slot_shared for p in lst}
         cache_idle = [p for p, r in self.pool.ref.items() if r == 0]
@@ -2147,6 +2457,10 @@ class ServingEngine:
             "in_flight": sum(len(h["staged"]) for h in self._adopting),
         }
         counts["total"] = sum(counts.values())
+        if self._extra:
+            counts["classes"] = {
+                self.classes[0].name: dict(counts),
+                **{x.cls.name: x.accounting() for x in self._extra}}
         return counts
 
     def run(self, requests: list[Request]) -> dict:
@@ -2172,13 +2486,16 @@ class ServingEngine:
                 wait = max(0.0, nxt - (_clock.now() - t0))
                 time.sleep(min(max(wait, 0.001), 0.05))
         wall = _clock.now() - t0
-        if self._deferred_free or self.pool.pending_evict:
+        if (self._deferred_free or self.pool.pending_evict
+                or self._extra):
             # nothing is in flight after the drive loop: settle deferred
             # frees (e.g. a final-step abort) so page_accounting sees
             # steady state
             self.pool.release(self._deferred_free)
             self._deferred_free = []
             self.pool.commit_evictable()
+            for x in self._extra:
+                x.settle()
         done = [r for r in requests if not r.aborted]
         lat = [r.t_done - (t0 + r.arrival) for r in done
                if r.t_done is not None]

@@ -18,15 +18,11 @@ router and logits):
   exact arithmetic, and a token stores ``(c_kv, k_rope)`` only.
 - Dense layers (the leading ``n_dense_layers``): SwiGLU of width
   ``ffn_hidden``.
-- Expert layers: ``s = sigmoid(x W_r)`` in fp32; chosen = top-k of
-  ``s + b`` (``b``: the correction bias, selection only); ``w =
-  s[chosen] / sum s[chosen] * routed_scaling``; ``y = sum_chosen w_e
-  E_e(x) + S(x)``.  No token is dropped.  **The layer is told which
-  experts it holds** (``cfg.held = (first, count)``): the router keeps
-  its full width, the weights are normalised over all chosen, and the
-  sum runs over the chosen experts that are held; the shared expert is
-  always computed.  On one chip of an expert-parallel deployment this is
-  the chip's part of the layer, without the exchange.
+- Expert layers (``models/routed_experts.py``, shared with the other
+  families): sigmoid scores with a correction bias in the selection,
+  weights normalised over the chosen and scaled by ``routed_scaling``,
+  one shared expert added whole.  **The layer is told which experts it
+  holds** (``cfg.held = (first, count)``).
 - MTP module: ``h' = [RMSNorm(Emb(t_{i+1})) ; RMSNorm(h_i)] W_eh``, one
   expert-kind layer, its own final norm, the shared embedding and head.
 
@@ -42,13 +38,14 @@ import dataclasses
 import math
 from typing import Any
 
-import numpy as np
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import routed_experts
 from .llama import rms_norm
+from .routed_experts import EXPERT_STACKS
 from .seam import CachePlane, CacheSpec, LayerGroup
 
 __all__ = ["MlaMoeConfig", "init_mla_moe_params", "mla_moe_apply",
@@ -123,6 +120,12 @@ class MlaMoeConfig:
             max_seq_len=c["max_position_embeddings"])
         kw.update(over)
         return cls(**kw)
+
+    @property
+    def routing(self) -> routed_experts.Routing:
+        return routed_experts.Routing(
+            k=self.experts_per_token, held=self.held, dtype=self.dtype,
+            scaling=self.routed_scaling, norm_topk=self.norm_topk_prob)
 
     def serving_model(self) -> "MlaMoeServing":
         return MlaMoeServing(self)
@@ -217,14 +220,11 @@ def init_mla_moe_params(cfg: MlaMoeConfig, key) -> dict:
 # ---------------------------------------------------------------------------
 
 def _mm(x, w, cfg):
-    return jnp.einsum("...h,hk->...k", x.astype(cfg.dtype),
-                      w.astype(cfg.dtype),
-                      preferred_element_type=jnp.float32).astype(cfg.dtype)
+    return routed_experts.mm(x, w, cfg.dtype)
 
 
 def _swiglu(h, w_gate, w_up, w_down, cfg):
-    return _mm(jax.nn.silu(_mm(h, w_gate, cfg).astype(jnp.float32)).astype(
-        cfg.dtype) * _mm(h, w_up, cfg), w_down, cfg)
+    return routed_experts.swiglu(h, w_gate, w_up, w_down, cfg.dtype)
 
 
 def rope_angles(cfg: MlaMoeConfig, positions):
@@ -289,75 +289,9 @@ def mla_expanded(h, lp, cfg: MlaMoeConfig, cos, sin):
     return _mm(o.reshape(B, T, -1), lp["wo"], cfg)
 
 
-def route(h, lp, cfg: MlaMoeConfig):
-    """Top-k routing of ``h [N, H]`` over ALL routed experts, in fp32:
-    (expert ids ``[N, k]`` int32, weights ``[N, k]`` fp32)."""
-    with jax.named_scope("layer/router"):
-        s = jax.nn.sigmoid(jnp.matmul(
-            h.astype(jnp.float32), lp["router"].astype(jnp.float32),
-            precision=lax.Precision.HIGHEST))
-        _, idx = lax.top_k(s + lp["router_bias"], cfg.experts_per_token)
-        w = jnp.take_along_axis(s, idx, axis=-1)
-        if cfg.norm_topk_prob:
-            w = w / w.sum(-1, keepdims=True)
-        return idx.astype(jnp.int32), w * cfg.routed_scaling
-
-
-EXPERT_STACKS = ("we_gate", "we_up", "we_down")
-
-
 def moe_ffn(h, lp, cfg: MlaMoeConfig, valid=None, stack=None):
-    """The expert layer's feed-forward on normed ``h [N, H]``: this
-    chip's part (module docstring).  ``valid [N]`` marks the places that
-    hold a token; padding is routed nowhere.  Returns ``(y [N, H], tokens
-    per held expert [count] int32)``.
-
-    The held experts' matrices are ``lp``'s ``we_*`` ``[count, ...]``, or
-    with ``stack = (weights, i)`` those of ALL expert layers flattened
-    ``[layers * count, ...]`` and this layer's index among them: the
-    products then find the layer's experts where they lie (groups of
-    other layers are empty), because a grouped product is a custom call,
-    and a layer sliced out of a scanned stack would be copied for it.
-
-    Tokens are grouped by expert: the ``N*k`` assignments are sorted by
-    held expert (assignments to experts held elsewhere sort last, into
-    no group), the three products run once per group over its own rows
-    (``lax.ragged_dot``), and each token sums its chosen experts' rows
-    under its routing weights."""
-    N, H = h.shape
-    k, (first, count) = cfg.experts_per_token, cfg.held
-    idx, w = route(h, lp, cfg)
-    with jax.named_scope("layer/experts"):
-        local = idx - first
-        held = (local >= 0) & (local < count)
-        if valid is not None:
-            held &= valid[:, None]
-        e = jnp.where(held, local, count).reshape(-1)            # [N*k]
-        order = jnp.argsort(e, stable=True)
-        sizes = jnp.zeros((count + 1,), jnp.int32).at[e].add(1)[:count]
-        we, groups = lp, sizes
-        if stack is not None:
-            we, i = stack
-            groups = lax.dynamic_update_slice(
-                jnp.zeros((we["we_up"].shape[0],), jnp.int32), sizes,
-                (i * count,))
-        xs = h.astype(cfg.dtype)[order // k]                     # [N*k, H]
-        f32 = jnp.float32
-        act = (jax.nn.silu(lax.ragged_dot(
-            xs, we["we_gate"], groups, preferred_element_type=f32)).astype(
-                cfg.dtype)
-            * lax.ragged_dot(xs, we["we_up"], groups,
-                             preferred_element_type=f32).astype(cfg.dtype))
-        out = lax.ragged_dot(act, we["we_down"], groups,
-                             preferred_element_type=f32).astype(cfg.dtype)
-        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
-            jnp.arange(N * k, dtype=jnp.int32))
-        rows = out[back].reshape(N, k, H).astype(f32)
-        y = jnp.where(held[..., None], rows * w[..., None], 0.0).sum(1)
-    with jax.named_scope("layer/shared_expert"):
-        y = y.astype(cfg.dtype) + _swiglu(h, lp["ws_gate"], lp["ws_up"],
-                                          lp["ws_down"], cfg)
-    return y, sizes
+    """``routed_experts.moe_ffn`` under this family's routing."""
+    return routed_experts.moe_ffn(h, lp, cfg.routing, valid, stack)
 
 
 def _ffn(x, lp, cfg, kind, valid=None, stack=None):
@@ -446,8 +380,7 @@ class MlaMoeServing:
 
     unsupported = ("kv_quant", "lora", "constrained", "speculative",
                    "page_shipment", "weight_only_int8")
-    stats_keys = ("moe_assigned_held", "moe_assigned_all",
-                  "moe_assigned_at_max", "moe_load_max_over_mean")
+    stats_keys = routed_experts.STATS_KEYS
 
     def __init__(self, cfg: MlaMoeConfig):
         if cfg.n_dense_layers > 1:
@@ -540,16 +473,6 @@ class MlaMoeServing:
 
     def tick_stats(self, ys, n_tokens: int) -> dict:
         """From a tick's tokens per held expert per layer (the layer
-        groups' counters; dense layers have none).  ``moe_assigned_at_max``
-        is what the held experts would hold if each held as much as its
-        layer's most loaded one, so over ``moe_assigned_held`` it is the
-        layers' max over mean weighted by their assignments (a tick, or
-        summed over ticks, a run)."""
-        per = np.concatenate([np.asarray(y).reshape(-1, self.cfg.held[1])
-                              for y in ys if y is not None])
-        held, at_max = int(per.sum()), int(per.max(1).sum()) * per.shape[1]
-        return {"moe_assigned_held": held,
-                "moe_assigned_all": (self.cfg.experts_per_token * n_tokens
-                                     * per.shape[0]),
-                "moe_assigned_at_max": at_max,
-                "moe_load_max_over_mean": at_max / held if held else 0.0}
+        groups' counters; dense layers have none)."""
+        return routed_experts.held_expert_stats(ys, n_tokens,
+                                                self.cfg.routing)

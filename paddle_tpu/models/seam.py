@@ -16,6 +16,11 @@ inside a layer.  A *serving model* is any object with:
   the page's state, and a fresh page is zeros), counts it in a page's
   bytes, donates it to the step and keeps what the step returns.  ``spec.hash_tag`` joins
   the prefix hash, so pages of two formats never alias in the cache;
+- optionally ``cache_classes(page_size) -> (CacheClass, ...)``: a model
+  whose layers do not all keep a token equally long declares *cache
+  classes* (below).  A model without the method has one class, all its
+  layers under ``cache_spec``, and nothing in the step it traces says
+  otherwise;
 - ``unsupported``: names of engine features this model does not serve
   (the engine fails with one error when asked for one);
 - ``embed(params, tokens, positions) -> (x, ctx)``: from the tick's
@@ -23,9 +28,9 @@ inside a layer.  A *serving model* is any object with:
   residual stream ``[T, H]`` and whatever every layer shares (rotary
   angles, per packed token).  The engine then puts the tick's
   ``TokenLayout`` into ``ctx["layout"]``;
-- ``layer_groups(params) -> [LayerGroup]``: runs of alike layers.  A
-  stacked group is scanned with the pool as the loop's carry, a single
-  layer is applied where it stands;
+- ``layer_groups(params) -> [LayerGroup]``: runs of alike layers, in the
+  model's order.  A stacked group is scanned with its class's pool as
+  the loop's carry, a single layer is applied where it stands;
 - ``apply(x, k_pool, v_pool, base, layer_xs, rows, pos0, n_valid, ctx,
   *side) -> (x, k_pool, v_pool, ys, *side)``: one layer.  ``x`` is
   packed, ``[T, H]``: what a token does alone (norms, projections, the
@@ -38,7 +43,9 @@ inside a layer.  A *serving model* is any object with:
   the one way between the layouts.  The pools are flattened
   ``[L*P, ...]`` and ``base = l*P`` is added to every page id written
   or attended (``serving._run_layer_groups`` states the rule); ``ys``
-  is the layer's counters or None.  ``side``
+  is the layer's counters or None.  Pools, ``rows`` and ``base`` are
+  those of the layer's cache class, and ``ctx["cache_class"]`` is its
+  index.  ``side``
   is this layer's ``[P, *page_shape]`` slice of each side plane in the
   spec's order, under the layer's own page ids (no ``base``), and comes
   back updated behind ``ys``; a model that declares none gets and
@@ -48,6 +55,32 @@ inside a layer.  A *serving model* is any object with:
   the engine verifies drafts;
 - ``tick_stats(ys, n_valid_tokens) -> dict`` where ``ys`` is not None:
   what a tick's harvested counters add to the engine's ``stats``.
+
+**Cache classes.**  A class is a set of layers that share one pool
+``[L_c, P_c, *page_shape]``, one page table and one free list: a page id
+of the class costs a page in each of ITS layers and in no other.  Every
+class's table is indexed by the request's logical block, as the one
+table always was, and a request takes pages in every class as it grows.
+``CacheClass.window`` is how many tokens back a layer of the class can
+still read (``None``: all of them).  The rules, stated once:
+
+- class 0 is the one whose layers read everything (``window is None``);
+  its pools and table are the engine's ``k_pages``, ``v_pages`` and the
+  ``ptable`` operand, as for a model with one class.  Further classes'
+  pools and tables ride behind the side planes in the step's varargs;
+- in a windowed class a page whose last token is ``window`` or more
+  behind the request's next query is let go between ticks: its table
+  slot gets the sink (page 0 of the class), the page goes where a
+  finished request's goes (a cached page to the evictable set once no
+  in-flight program can read it, another to the free list).  The
+  model's attention must mask what the window hides: slots behind the
+  window hold the sink or anything else;
+- one hash chain serves every class; each class keeps its pages under
+  the chain's hashes (its spec's ``hash_tag`` joins them).  A prefix
+  hit of ``b`` tokens needs class 0 to hold ``[0, b)`` and every
+  windowed class the pages that cover ``[max(0, b - window), b)``;
+- admission needs room in every class; preemption, abort and finish
+  release in every class; side planes are class 0's alone.
 
 **The packed axis.**  A tick carries ``sum(m)`` tokens, row ``c`` of the
 grid ``m[c]`` of them (an idle row none), and the grid has ``C * qb``
@@ -62,13 +95,13 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any
+from typing import Any, Optional
 
 import jax.numpy as jnp
 import numpy as np
 
-__all__ = ["CachePlane", "CacheSpec", "LayerGroup", "SidePlane",
-           "TokenLayout", "token_layout"]
+__all__ = ["CacheClass", "CachePlane", "CacheSpec", "LayerGroup",
+           "SidePlane", "TokenLayout", "cache_classes", "token_layout"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,11 +135,41 @@ class CacheSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class CacheClass:
+    name: str
+    n_layers: int              # layers whose pages live in this pool
+    spec: CacheSpec
+    window: Optional[int] = None   # tokens back a layer reads; None: all
+
+    def live_from(self, next_query: int) -> int:
+        """The first position a query at ``next_query`` (and any later
+        one) can still read in this class."""
+        return 0 if self.window is None else max(
+            0, next_query - self.window + 1)
+
+
+def cache_classes(model, page_size: int) -> tuple:
+    """The model's cache classes; one, over every layer, for a model
+    that declares none."""
+    if hasattr(model, "cache_classes"):
+        classes = tuple(model.cache_classes(page_size))
+        if classes[0].window is not None or any(
+                c.spec.side for c in classes[1:]):
+            raise ValueError(
+                "class 0 reads everything and owns the side planes "
+                "(models/seam.py: cache classes)")
+        return classes
+    return (CacheClass("global", model.n_layers,
+                       model.cache_spec(page_size)),)
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerGroup:
-    first: int                 # index of the group's first layer
+    first: int                 # the group's first layer, among its class's
     count: int
     xs: Any                    # what ``apply`` gets as ``layer_xs``
     stacked: bool = True       # leading dim ``count`` on every leaf of xs
+    cache: int = 0             # index of the group's cache class
 
 
 @dataclasses.dataclass(frozen=True)

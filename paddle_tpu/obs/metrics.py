@@ -23,14 +23,14 @@ import math
 from typing import Optional
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
-           "SERVING_STATS_SCHEMA", "FLEET_STATS_SCHEMA",
-           "TRAIN_STATS_SCHEMA"]
+           "SERVING_STATS_SCHEMA", "CACHE_CLASS_STATS_SCHEMA",
+           "FLEET_STATS_SCHEMA", "TRAIN_STATS_SCHEMA"]
 
 
 # -- declared stats schemas (name -> (kind, help)) ---------------------------
-# TPL010 collects every ``*_STATS_SCHEMA`` dict in the tree; these three
-# declare the per-engine, fleet-router and resilient-train counter
-# families respectively.
+# TPL010 collects every ``*_STATS_SCHEMA`` dict in the tree; these
+# declare the per-engine, cache-class, fleet-router and resilient-train
+# counter families.
 
 SERVING_STATS_SCHEMA = {
     "unified_steps": ("counter", "unified scheduler steps executed"),
@@ -69,6 +69,21 @@ SERVING_STATS_SCHEMA = {
     "wire_export_ms": ("counter",
                        "donor-side host ms materializing migration-wire "
                        "export payloads"),
+}
+
+# what an engine adds to its stats when its model declares more than one
+# cache class (models/seam.py); with them ``pages_live.<class>``, one a
+# class, summed over ticks
+CACHE_CLASS_STATS_SCHEMA = {
+    "context_tokens_live": ("counter",
+                            "context tokens of the live requests, summed "
+                            "over ticks"),
+    "pages_released_by_window": ("counter",
+                                 "pages windowed cache classes let go "
+                                 "behind their windows"),
+    "prefill_window_lost_tokens": ("counter",
+                                   "tokens of prefix hits class 0 had and "
+                                   "a windowed class had lost"),
 }
 
 FLEET_STATS_SCHEMA = {

@@ -40,6 +40,13 @@ kpos <= pos0 + i; padding rows i >= n_valid come out as ZEROS from both
 arms (mla_paged_attention's contract), so callers may compare full
 outputs across arms.  The engine reads rows < n_valid only.
 
+``window`` (static; None or an int >= 1) adds a lower bound: query row i
+attends ``pos0 + i - window < kpos <= pos0 + i``, itself and the
+``window - 1`` keys before it.  Block-table slots wholly behind the
+window of the chunk's FIRST query are never read by the kernel and may
+hold any id, as future slots may (the XLA arm gathers them, so there
+they must name pages of the pool: the engine keeps the sink there).
+
 Returns o [C, qb, nH, d].  Callers read rows < n_valid (the engine
 samples at offset n_valid - 1, or at every offset when verifying
 speculative drafts).
@@ -55,9 +62,11 @@ accumulator a head a group; every head's value dots.  A group past the
 chunk's last valid position computes nothing and names the next row's
 first blocks, so it moves no byte of its own and the next row's pages
 arrive early (_steer); a dead page slot inside a live group repeats a
-block the row already named (_slot).  A decode row (n_valid == 1) runs
-each head's first G query rows alone (padded to the sublane tile) with
-small accumulators of its own.
+block the row already named (_slot).  With a window the groups wholly
+behind it are dead the same way: they compute nothing and name the row's
+first live group, whose blocks are then there when it starts.  A decode
+row (n_valid == 1) runs each head's first G query rows alone (padded to
+the sublane tile) with small accumulators of its own.
 
 Which form runs is the autotune's choice among ``"kernel_p4"``,
 ``"kernel_p2"``, ``"kernel_p1"`` (those that divide max_blocks and whose
@@ -151,7 +160,14 @@ def _slot(j, i: int, pps: int, last_page):
     return jnp.where(kb < 0, last_page, kb)
 
 
-def _steer(c, j, pos0_ref, nval_ref, n_rows: int, pps: int, bs: int):
+def _first_page(pos0, window: int, bs: int):
+    """The page that holds the first key a chunk starting at ``pos0``
+    can see under ``window``: key ``pos0 - window + 1``, or key 0."""
+    return jax.lax.div(jnp.maximum(pos0 - window + 1, 0), bs)
+
+
+def _steer(c, j, pos0_ref, nval_ref, n_rows: int, pps: int, bs: int,
+           window=None):
     """(row, group, last live page of that row) whose blocks step (c, j)
     names.  A live group names its own.  A group past the chunk's last
     valid position computes nothing, so it names the NEXT row's first
@@ -160,13 +176,19 @@ def _steer(c, j, pos0_ref, nval_ref, n_rows: int, pps: int, bs: int):
     step before), every further dead step and the next row's first step
     find the same blocks named and issue no copy.  The last row's dead
     groups repeat its own last blocks.  So a dead step moves no byte
-    that a live step would not have moved, whatever the table holds."""
+    that a live step would not have moved, whatever the table holds.
+    With a ``window`` a row's first LIVE group is the one that holds the
+    first key its first query sees: the groups before it are dead too
+    and name it, and a row ahead is entered at its first live group."""
     last_page = jax.lax.div(pos0_ref[c] + nval_ref[c] - 1, bs)
     ahead = jnp.logical_and(j * pps > last_page, c + 1 < n_rows)
     cc = jnp.where(ahead, c + 1, c)
-    return (cc, jnp.where(ahead, 0, j),
-            jnp.where(ahead, jax.lax.div(pos0_ref[cc] + nval_ref[cc] - 1,
-                                         bs), last_page))
+    last_cc = jnp.where(ahead, jax.lax.div(pos0_ref[cc] + nval_ref[cc] - 1,
+                                           bs), last_page)
+    if window is None:
+        return cc, jnp.where(ahead, 0, j), last_cc
+    first = jax.lax.div(_first_page(pos0_ref[cc], window, bs), pps)
+    return cc, jnp.where(ahead, first, jnp.maximum(j, first)), last_cc
 
 
 def _decode_rows(G: int, rows: int, q_itemsize: int) -> int:
@@ -177,7 +199,7 @@ def _decode_rows(G: int, rows: int, q_itemsize: int) -> int:
 
 
 def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
-                pps, sm_scale, quant, mb, nkv, rd):
+                pps, sm_scale, quant, mb, nkv, rd, window=None):
     """One (chunk, page-group) program: every kv head's qb*G query rows
     (row r = query token r//G, group head r%G) against ``pps``
     table-selected pages at once — a head's scores of the group's live
@@ -188,7 +210,12 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
     position are skipped — their keys would be fully masked, and
     exp(-1e30 - m) == 0 in fp32, so skipping is exact, not an
     approximation.  A decode row (n_valid == 1) runs on each head's
-    first ``rd`` query rows and its own small accumulators.
+    first ``rd`` query rows and its own small accumulators.  Under a
+    ``window`` the groups and slots wholly behind the first query's
+    window are skipped as well; a later query row may find a live
+    group's keys all behind ITS window, and what it accumulates from
+    them under the running max's initial -1e30 is wiped by the rescale
+    (alpha == 0) at its first real key, which always comes: its own.
 
     ``quant``: int8 pages — two extra scalar-prefetch refs carry the
     flattened [P * nKV] scale planes; the k/v tiles are dequantized in
@@ -207,6 +234,13 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
     n = nval_ref[c]
     p0 = pos0_ref[c]
     last = p0 + n - 1                               # last valid position
+    # first key the chunk's first query sees (may be negative)
+    lo = None if window is None else p0 - window + 1
+
+    def live(k0, k1):
+        """Whether pages [k0, k1) hold a key some query row can see."""
+        ok = k0 * bs <= last
+        return ok if window is None else jnp.logical_and(ok, k1 * bs > lo)
 
     def heads(fn):
         """fn(h) for every kv head.  Traced once and unrolled when
@@ -227,13 +261,14 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
 
         def live_pages(fn):
             """fn(i) for each page slot of the group that is live: the
-            group's first always is (the caller's guard); a later one
-            may lie past ``last``, and its two dots are not made."""
+            group's first always is (the caller's guard; not under a
+            window); another may lie past ``last`` or behind the window,
+            and its two dots are not made."""
             for i in range(pps):
-                if i == 0:
+                if i == 0 and window is None:
                     fn(i)
                 else:
-                    pl.when((j * pps + i) * bs <= last)(
+                    pl.when(live(j * pps + i, j * pps + i + 1))(
                         functools.partial(fn, i))
 
         def page(ref, sc_ref, i, h):
@@ -248,7 +283,7 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
         # the chunk's first page is never skipped (0 <= last always since
         # n_valid >= 1), so every query row keeps >= 1 real key and l
         # never normalizes junk
-        @pl.when(j * pps * bs <= last)
+        @pl.when(live(j * pps, (j + 1) * pps))
         def _pages():
             # three passes, each over every head, so that the MXU runs
             # the heads' independent dots back to back instead of
@@ -269,7 +304,10 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
                 jnp.int32, shape, 1)
             # a dead slot's scores are whatever the scratch held: every
             # key position of it is past ``last``, so the select drops it
-            mask = kpos <= p0 + jnp.minimum(tok, n - 1)
+            qpos = p0 + jnp.minimum(tok, n - 1)
+            mask = kpos <= qpos
+            if window is not None:
+                mask = jnp.logical_and(mask, kpos > qpos - window)
             def softmax(h):
                 s = jnp.where(mask, s_sc[h, 0:R, :] * sm_scale, -1e30)
                 m_prev = m_ref[h]                   # [R, 128], lanes alike
@@ -322,18 +360,20 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
         tier(qb * G, m_sc, l_sc, acc_sc)
 
 
-@functools.partial(jax.jit, static_argnames=("sm_scale", "pps"))
+@functools.partial(jax.jit, static_argnames=("sm_scale", "pps", "window"))
 def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
                                   n_valid, sm_scale: float,
                                   k_scales=None, v_scales=None,
-                                  pps: int = 1):
+                                  pps: int = 1, window=None):
     """MXU unified-RPA kernel (d-major k pages).  See module docstring
     for the contract; gate with ragged_paged_supported().  ``pps`` pages
     a grid step, a divisor of max_blocks: the pool rides as ``pps`` k
     and ``pps`` v operands of the same two buffers, each with its own
     table-steered block of a whole page across heads.  int8 pages take
     the per-page scale planes as two extra scalar-prefetch operands
-    (flattened [P * nKV]) riding next to the block-table rows."""
+    (flattened [P * nKV]) riding next to the block-table rows.  With a
+    ``window`` the call carries a name of its own, so that a trace tells
+    the two forms apart."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -362,12 +402,17 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
         return (c, 0, 0, 0)
 
     def _qmap(c, j, rf, p0, nv, *_):
-        return (_steer(c, j, p0, nv, C, pps, bs)[0], 0, 0, 0)
+        return (_steer(c, j, p0, nv, C, pps, bs, window)[0], 0, 0, 0)
 
     def _pmap(i):
         def index(c, j, rf, p0, nv, *_):
-            cc, jj, last_page = _steer(c, j, p0, nv, C, pps, bs)
-            return (rf[cc * mb + _slot(jj, i, pps, last_page)], 0, 0, 0)
+            cc, jj, last_page = _steer(c, j, p0, nv, C, pps, bs, window)
+            slot = _slot(jj, i, pps, last_page)
+            if window is not None:
+                # a slot of the first live group that lies behind the
+                # window names the row's first live page
+                slot = jnp.maximum(slot, _first_page(p0[cc], window, bs))
+            return (rf[cc * mb + slot], 0, 0, 0)
         return index
 
     f32 = jnp.float32
@@ -394,13 +439,14 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
     call = pl.pallas_call(
         functools.partial(_rpa_kernel, qb=qb, bs=bs, G=G, n_steps=n_steps,
                           pps=pps, sm_scale=sm_scale, quant=quant, mb=mb,
-                          nkv=nkv, rd=rd),
+                          nkv=nkv, rd=rd, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((C, nkv, R, d), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name="ragged_paged_attention",
+        name=("ragged_paged_attention" if window is None
+              else "ragged_paged_attention_window"),
     )
     pre = (rows_flat, pos0.astype(jnp.int32), n_valid.astype(jnp.int32))
     if quant:
@@ -412,7 +458,7 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
 
 
 def _ragged_paged_xla(q, k_pages, v_pages, rows, pos0, n_valid, sm_scale,
-                      k_layout, k_scales=None, v_scales=None):
+                      k_layout, k_scales=None, v_scales=None, window=None):
     """XLA gather fallback (and the kernel's numerics reference): gather
     each chunk's pages, one masked softmax over the flattened context.
     The same mask as the kernel, and the same zeros in padding rows.
@@ -446,6 +492,8 @@ def _ragged_paged_xla(q, k_pages, v_pages, rows, pos0, n_valid, sm_scale,
                                        n_valid[:, None] - 1)
     kpos = jnp.arange(mb * bs, dtype=jnp.int32)
     mask = kpos[None, None, :] <= qpos[:, :, None]  # [C, qb, S]
+    if window is not None:
+        mask &= kpos[None, None, :] > qpos[:, :, None] - window
     s = s + jnp.where(mask[:, None, None, :, :], 0.0, -1e30)
     # max-subtracted exp/sum (not jax.nn.softmax) to mirror the kernel's
     # online-softmax epilogue: acc / max(l, 1e-30)
@@ -466,7 +514,8 @@ def _autotune_source() -> str:
     if _SRC is None:
         from . import autotune
 
-        _SRC = autotune.source_hash(_slot, _steer, _decode_rows, _rpa_kernel,
+        _SRC = autotune.source_hash(_slot, _first_page, _steer, _decode_rows,
+                                    _rpa_kernel,
                                     ragged_paged_attention_kernel,
                                     _ragged_paged_xla)
     return _SRC
@@ -474,7 +523,7 @@ def _autotune_source() -> str:
 
 def _tuned_impl(C: int, qb: int, nH: int, d: int, nkv: int, mb: int,
                 bs: int, dtype, candidates: list,
-                quant: bool = False) -> str:
+                quant: bool = False, window=None) -> str:
     """Impl choice via the autotune registry: the kernel at 4, 2 or 1
     pages a grid step (what ``candidates_for`` admits, the largest
     first, so a backend that never sweeps runs the largest group) or the
@@ -483,7 +532,8 @@ def _tuned_impl(C: int, qb: int, nH: int, d: int, nkv: int, mb: int,
     serving cells' geometry is in the committed table, so no run of them
     sweeps.  Quantized pages tune their own bucket — dequant shifts the
     arms' cost balance (the kernel dequantizes per VMEM tile, the XLA
-    arm on the full gathered context)."""
+    arm on the full gathered context), and so does a window, which
+    skips most of a long context's groups."""
     from . import autotune
 
     def measure(impl):
@@ -497,11 +547,12 @@ def _tuned_impl(C: int, qb: int, nH: int, d: int, nkv: int, mb: int,
         sc = jnp.ones((mb + 1, nkv), jnp.float32) if quant else None
         if impl == "xla":
             arm = lambda x: _ragged_paged_xla(x, ktz, vz, rz, pz, nz,  # noqa: E731
-                                              1.0, "d_major", sc, sc)
+                                              1.0, "d_major", sc, sc,
+                                              window)
         else:
             arm = lambda x: ragged_paged_attention_kernel(  # noqa: E731
                 x, ktz, vz, rz, pz, nz, 1.0, sc, sc,
-                pps=int(impl.split("_p")[1]))
+                pps=int(impl.split("_p")[1]), window=window)
         # eight calls chained in one program: one call alone is under
         # the host's dispatch floor, where every form reads alike
         chain = jax.jit(lambda x: jax.lax.fori_loop(
@@ -511,17 +562,18 @@ def _tuned_impl(C: int, qb: int, nH: int, d: int, nkv: int, mb: int,
     return str(autotune.tuned(
         "ragged_paged_attention",
         f"c{C}_qb{qb}_h{nH}_d{d}_kv{nkv}_mb{mb}_bs{bs}"
-        + ("_q8" if quant else ""),
+        + ("_q8" if quant else "") + (f"_w{window}" if window else ""),
         str(jnp.dtype(dtype)), candidates,
         measure=measure, source=_autotune_source()))
 
 
 def ragged_paged_attention(q, k_pages, v_pages, rows, pos0, n_valid,
                            sm_scale: float, k_layout: str = "d_major",
-                           k_scales=None, v_scales=None):
+                           k_scales=None, v_scales=None, window=None):
     """Unified ragged-paged attention: dispatches the MXU Pallas kernel
     when the page geometry supports it, else the XLA gather path.  See
-    module docstring for shapes; int8 pages require both scale planes."""
+    module docstring for shapes; int8 pages require both scale planes;
+    ``window`` (static) is the sliding window in tokens, None for all."""
     quant = k_pages.dtype == jnp.int8
     if quant and (k_scales is None or v_scales is None):
         raise ValueError("int8 KV pages need k_scales and v_scales "
@@ -532,10 +584,11 @@ def ragged_paged_attention(q, k_pages, v_pages, rows, pos0, n_valid,
         k_pages.shape, nH, qb, mb, k_pages.dtype.itemsize)
     if len(cands) > 1:
         impl = _tuned_impl(C, qb, nH, d, k_pages.shape[1], mb,
-                           k_pages.shape[3], q.dtype, cands, quant)
+                           k_pages.shape[3], q.dtype, cands, quant, window)
         if impl != "xla":
             return ragged_paged_attention_kernel(
                 q, k_pages, v_pages, rows, pos0, n_valid, sm_scale,
-                k_scales, v_scales, pps=int(impl.split("_p")[1]))
+                k_scales, v_scales, pps=int(impl.split("_p")[1]),
+                window=window)
     return _ragged_paged_xla(q, k_pages, v_pages, rows, pos0, n_valid,
-                             sm_scale, k_layout, k_scales, v_scales)
+                             sm_scale, k_layout, k_scales, v_scales, window)

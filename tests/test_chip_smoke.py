@@ -69,7 +69,10 @@ def test_serve_leg_tiny():
     assert r["kernels"] == {} and r["apply_kernels"] == {}
     assert set(r["kernel_vs_xla"]) == {
         "ragged_paged_attention_kernel_p4", "ragged_paged_attention_kernel_p2",
-        "ragged_paged_attention_kernel_p1", "paged_kv_write_k",
+        "ragged_paged_attention_kernel_p1",
+        "ragged_paged_attention_window_kernel_p4",
+        "ragged_paged_attention_window_kernel_p2",
+        "ragged_paged_attention_window_kernel_p1", "paged_kv_write_k",
         "paged_kv_write_v",
         "rms_epilogue_r", "rms_epilogue_y", "swiglu", "rope_attention"}
     f = r["first_token_vs_llama_apply"]

@@ -331,7 +331,8 @@ def test_dispatcher_obeys_the_autotune_choice(monkeypatch, impl):
     n_valid = jnp.asarray([1, qb], jnp.int32)
     asked = []
 
-    def fake(C_, qb_, nH_, d_, nkv_, mb_, bs_, dtype, cands, quant=False):
+    def fake(C_, qb_, nH_, d_, nkv_, mb_, bs_, dtype, cands, quant=False,
+             window=None):
         asked.append((C_, qb_, tuple(cands)))
         return impl
     monkeypatch.setattr(mod, "_tuned_impl", fake)
@@ -339,7 +340,7 @@ def test_dispatcher_obeys_the_autotune_choice(monkeypatch, impl):
     inner = mod.ragged_paged_attention_kernel
     monkeypatch.setattr(
         mod, "ragged_paged_attention_kernel",
-        lambda *a, pps: ran.append(pps) or inner(*a, pps=pps))
+        lambda *a, pps, window=None: ran.append(pps) or inner(*a, pps=pps))
     got = mod.ragged_paged_attention(q, kp, vp, rows, pos0, n_valid, 0.5)
     if impl == "xla":
         want = _ragged_paged_xla(q, kp, vp, rows, pos0, n_valid, 0.5,
@@ -369,15 +370,18 @@ def test_default_without_a_sweep_is_the_largest_group(monkeypatch):
                                cands) == want
 
 
-@pytest.mark.parametrize("mb", [24, 16])
-def test_committed_table_serves_the_cells_geometry(mb):
-    """The serving cells (mb 24) and chip_smoke.py (mb 16) must find
-    their form in the tracked table under the kernel's CURRENT source
-    hash: a stale entry is a clean miss, every run's set-up then sweeps,
-    and the XLA arm's temporaries set the run's memory peak.  After an
-    edit to the kernel, set the entries' ``source`` to what
-    ``_autotune_source()`` returns (and re-measure if the form of the
-    step changed)."""
+@pytest.mark.parametrize("nH,mb,window", [
+    (32, 24, None), (32, 16, None), (128, 196, None), (128, 196, 4096)])
+def test_committed_table_serves_the_cells_geometry(nH, mb, window):
+    """The Mistral cells (32 heads, mb 24), chip_smoke.py (mb 16) and
+    the command-a-plus cell (128 heads, mb 196: its global layer and,
+    under the window, its window layers) must find their form in the
+    tracked table under the kernel's CURRENT source hash: a stale entry
+    is a clean miss, every run's set-up then sweeps, and the XLA arm's
+    temporaries set the run's memory peak (at mb 196 they do not fit
+    the chip at all).  After an edit to the kernel, sweep on the chip
+    and commit the entries (``source`` is what ``_autotune_source()``
+    returns)."""
     import json
 
     from paddle_tpu.ops.pallas import autotune
@@ -386,9 +390,10 @@ def test_committed_table_serves_the_cells_geometry(mb):
 
     entries = json.load(open(autotune.COMMITTED_PATH))["entries"]
     entry = entries["ragged_paged_attention|TPU v5 lite|"
-                    f"c32_qb16_h32_d128_kv8_mb{mb}_bs128|bfloat16"]
+                    f"c32_qb16_h{nH}_d128_kv8_mb{mb}_bs128"
+                    + (f"_w{window}" if window else "") + "|bfloat16"]
     assert entry["source"] == _autotune_source()
-    assert entry["config"] in candidates_for((448, 8, 128, 128), 32, 16,
+    assert entry["config"] in candidates_for((448, 8, 128, 128), nH, 16,
                                              mb)[:-1]
 
 

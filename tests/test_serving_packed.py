@@ -112,7 +112,7 @@ def _replay(engine, tick, rung):
     """The recorded tick run at step size ``rung``: (picks, pools and
     side planes, counters), all on the host."""
     args = jax.tree.map(jnp.asarray, tick[:-1])
-    out, k, v, ys, side = engine._unified(
+    out, k, v, ys, side, _further = engine._unified(
         *args, jnp.arange(rung, dtype=jnp.int32))
     return jax.tree.map(np.asarray, (out, (k, v, *side), ys))
 
