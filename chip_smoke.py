@@ -58,6 +58,7 @@ from paddle_tpu.ops.pallas import fused_bias_act as BA
 from paddle_tpu.ops.pallas import fused_ce as CE
 from paddle_tpu.ops.pallas import fused_norm_epilogue as NE
 from paddle_tpu.ops.pallas import fused_rope_attention as RA
+from paddle_tpu.ops.pallas import grouped_expert_matmul as GEM
 from paddle_tpu.ops.pallas import mla_paged_attention as MPA
 from paddle_tpu.ops.pallas import paged_kv_write as KVW
 from paddle_tpu.ops.pallas import ragged_paged_attention as RPA
@@ -131,6 +132,10 @@ class LatentLeg:
     page_size: int = 128
     max_blocks: int = 52
     n_pages: int = 256
+    # the held experts' products on a chunk tick, (rows, hidden, expert
+    # width) of each routed cell: command-a-plus-ep8, joyai-flash-ep16
+    expert_shapes: tuple = ((4096, 4096, 4096), (4096, 2048, 768))
+    n_held: int = 16
 
 
 def full_train_leg() -> TrainLeg:
@@ -621,7 +626,39 @@ def latent_leg(leg: LatentLeg) -> dict:
                                             pos0[:W], n_valid[:W], 0)
     check_close(checks, "latent_write_k_rope", got[0][1:], want[0][1:], 0.0)
     check_close(checks, "latent_write_c_kv", got[1][1:], want[1][1:], 0.0)
+    _grouped_expert_parity(leg, checks, rnd)
     return {"geometry": dataclasses.asdict(leg), "kernel_vs_xla": checks}
+
+
+def _grouped_expert_parity(leg: LatentLeg, checks: dict, rnd) -> None:
+    """The held experts' gate-and-up product (``grouped_expert_matmul``
+    on the arm the autotune table names for the shape, else its kernel
+    candidate) against ``lax.ragged_dot``: the second layer of a stack
+    of two, a skewed draw of group sizes with an empty group and seven
+    eighths of the rows behind the last group; then with no row held at
+    all (a decode tick of one or two tokens): the visit axis is empty,
+    nothing of ``out`` is written, and the caller's ``where(held)`` is
+    all that stands before it."""
+    count = leg.n_held
+    for m, H, F in leg.expert_shapes:
+        impl = GEM.choose_impl(m, H, F, count, jnp.bfloat16, gated=True)
+        if impl == "xla":
+            impl = GEM.candidates_for(m, H, F, 2)[1]
+        skewed = np.full(count, m // (8 * count), np.int32)
+        skewed[1], skewed[0] = 0, skewed[0] + skewed[1]
+        gate, up = (rnd((2 * count, H, F), scale=H ** -0.5)
+                    for _ in range(2))
+        x = rnd((m, H))
+        for tag, sizes in (("", skewed), ("_none_held", skewed * 0)):
+            got = GEM.grouped_expert_matmul(x, gate, jnp.asarray(sizes), up,
+                                            base=count, impl=impl)
+            want = jax.jit(GEM._grouped_xla)(x, gate, jnp.asarray(sizes), up,
+                                             count)
+            # the rows behind the last group are the caller's to mask
+            held = (jnp.arange(m) < sizes.sum())[:, None]
+            check_close(checks, f"grouped_expert_matmul_h{H}_f{F}_{impl}{tag}",
+                        jnp.where(held, got, 0.0),
+                        jnp.where(held, want, 0.0), TOL_BF16)
 
 
 # ---------------------------------------------------------------------------

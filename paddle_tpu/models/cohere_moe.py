@@ -394,10 +394,11 @@ class CohereMoeServing:
                 1.0 / math.sqrt(cfg.head_dim), k_layout="d_major",
                 window=cfg.sliding_window if kind == "window" else None)
             a = _mm(lay.to_packed(o).reshape(x.shape[0], -1), lp["wo"], cfg)
-        y, sizes = routed_experts.moe_ffn(
-            h, lp, cfg.routing, lay.valid,
-            (ctx["experts"][kind], lp["index"]))
-        return _join(x, a, y, cfg), kp, vp, sizes
+        experts = ctx["experts"][kind]
+        y, sizes = routed_experts.moe_ffn(h, lp, cfg.routing, lay.valid,
+                                          (experts, lp["index"]))
+        return _join(x, a, y, cfg), kp, vp, (
+            sizes, routed_experts.row_tile(h.shape[0], experts, cfg.routing))
 
     def head(self, params, x):
         with jax.named_scope("head"):

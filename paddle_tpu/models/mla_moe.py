@@ -458,6 +458,9 @@ class MlaMoeServing:
         x, sizes = _ffn(x, lp, cfg, kind, lay.valid,
                         (ctx["experts"], lp["index"]) if kind == "moe"
                         else None)
+        if kind == "moe":
+            sizes = sizes, routed_experts.row_tile(
+                x.shape[0], ctx["experts"], cfg.routing)
         return x, kp, vp, sizes
 
     def head(self, params, x):

@@ -27,12 +27,15 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.pallas import grouped_expert_matmul as gem
+
 __all__ = ["EXPERT_STACKS", "Routing", "held_expert_stats", "mm",
-           "moe_ffn", "route", "swiglu"]
+           "moe_ffn", "route", "row_tile", "swiglu"]
 
 EXPERT_STACKS = ("we_gate", "we_up", "we_down")
 STATS_KEYS = ("moe_assigned_held", "moe_assigned_all",
-              "moe_assigned_at_max", "moe_load_max_over_mean")
+              "moe_assigned_at_max", "moe_load_max_over_mean",
+              "moe_tile_rows")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,6 +76,15 @@ def route(h, lp, r: Routing):
         return idx.astype(jnp.int32), w
 
 
+def _gate_up_arm(n_places: int, we, r: Routing) -> str:
+    """The arm of ``grouped_expert_matmul`` that ``moe_ffn``'s
+    gate-and-up product over ``n_places`` places of the experts ``we``
+    (their ``we_up`` ``[..., H, F]``) runs on."""
+    H, F = we["we_up"].shape[-2:]
+    return gem.choose_impl(n_places * r.k, H, F, r.held[1], r.dtype,
+                           gated=True)
+
+
 def moe_ffn(h, lp, r: Routing, valid=None, stack=None):
     """The expert layer's feed-forward on normed ``h [N, H]``: this
     chip's part (module docstring).  ``valid [N]`` marks the places that
@@ -82,15 +94,16 @@ def moe_ffn(h, lp, r: Routing, valid=None, stack=None):
     The held experts' matrices are ``lp``'s ``we_*`` ``[count, ...]``, or
     with ``stack = (weights, i)`` those of ALL expert layers flattened
     ``[layers * count, ...]`` and this layer's index among them: the
-    products then find the layer's experts where they lie (groups of
-    other layers are empty), because a grouped product is a custom call,
-    and a layer sliced out of a scanned stack would be copied for it.
+    products then find the layer's experts where they lie, because a
+    grouped product is a custom call, and a layer sliced out of a
+    scanned stack would be copied for it.
 
     Tokens are grouped by expert: the ``N*k`` assignments are sorted by
     held expert (assignments to experts held elsewhere sort last, into
-    no group), the three products run once per group over its own rows
-    (``lax.ragged_dot``), and each token sums its chosen experts' rows
-    under its routing weights."""
+    no group), the products run once per group over its own rows
+    (``ops/pallas/grouped_expert_matmul.py``: gate and up in one call,
+    then down; rows behind the last group are never visited), and each
+    token sums its chosen experts' rows under its routing weights."""
     N, H = h.shape
     k, (first, count) = r.k, r.held
     idx, w = route(h, lp, r)
@@ -102,21 +115,16 @@ def moe_ffn(h, lp, r: Routing, valid=None, stack=None):
         e = jnp.where(held, local, count).reshape(-1)            # [N*k]
         order = jnp.argsort(e, stable=True)
         sizes = jnp.zeros((count + 1,), jnp.int32).at[e].add(1)[:count]
-        we, groups = lp, sizes
+        we, base = lp, None
         if stack is not None:
             we, i = stack
-            groups = lax.dynamic_update_slice(
-                jnp.zeros((we["we_up"].shape[0],), jnp.int32), sizes,
-                (i * count,))
+            base = i * count
         xs = h.astype(r.dtype)[order // k]                       # [N*k, H]
         f32 = jnp.float32
-        act = (jax.nn.silu(lax.ragged_dot(
-            xs, we["we_gate"], groups, preferred_element_type=f32)).astype(
-                r.dtype)
-            * lax.ragged_dot(xs, we["we_up"], groups,
-                             preferred_element_type=f32).astype(r.dtype))
-        out = lax.ragged_dot(act, we["we_down"], groups,
-                             preferred_element_type=f32).astype(r.dtype)
+        act = gem.grouped_expert_matmul(xs, we["we_gate"], sizes,
+                                        we["we_up"], base,
+                                        impl=_gate_up_arm(N, we, r))
+        out = gem.grouped_expert_matmul(act, we["we_down"], sizes, base=base)
         back = jnp.zeros((N * k,), jnp.int32).at[order].set(
             jnp.arange(N * k, dtype=jnp.int32))
         rows = out[back].reshape(N, k, H).astype(f32)
@@ -131,17 +139,34 @@ def moe_ffn(h, lp, r: Routing, valid=None, stack=None):
     return y, sizes
 
 
+def row_tile(n_places: int, we, r: Routing):
+    """Rows a met expert pays for in ``moe_ffn``'s gate-and-up product
+    over ``n_places`` places of the experts ``we``, on the arm that
+    product runs here: static a compiled step, an int32 scalar to send
+    out beside the layer's tokens per held expert."""
+    return jnp.int32(gem.row_tile(_gate_up_arm(n_places, we, r)))
+
+
 def held_expert_stats(ys, n_tokens: int, r: Routing) -> dict:
-    """A tick's counters (``STATS_KEYS``) from its tokens per held expert
-    per layer (the layer groups' ``ys``; a group without experts gives
-    None).  ``moe_assigned_at_max`` is what the held experts would hold
-    if each held as much as its layer's most loaded one, so over
+    """A tick's counters (``STATS_KEYS``) from its ``(tokens per held
+    expert, row_tile())`` per layer (the layer groups' ``ys``; a group
+    without experts gives None).
+    ``moe_assigned_at_max`` is what the held experts would hold if each
+    held as much as its layer's most loaded one, so over
     ``moe_assigned_held`` it is the layers' max over mean weighted by
-    their assignments (a tick, or summed over ticks, a run)."""
-    per = np.concatenate([np.asarray(y).reshape(-1, r.held[1])
-                          for y in ys if y is not None])
-    held, at_max = int(per.sum()), int(per.max(1).sum()) * per.shape[1]
+    their assignments (a tick, or summed over ticks, a run).
+    ``moe_tile_rows`` is the rows the gate-and-up product paid for, each
+    met expert's rounded up to the layer's row tile, so
+    ``moe_assigned_held`` over it is how full the tiles were."""
+    count = r.held[1]
+    layers = [y for y in ys if y is not None]
+    per = np.concatenate([np.asarray(sizes).reshape(-1, count)
+                          for sizes, _ in layers])
+    tile = np.concatenate([np.asarray(tm).reshape(-1, 1)
+                           for _, tm in layers])
+    held, at_max = int(per.sum()), int(per.max(1).sum()) * count
     return {"moe_assigned_held": held,
             "moe_assigned_all": r.k * n_tokens * per.shape[0],
             "moe_assigned_at_max": at_max,
-            "moe_load_max_over_mean": at_max / held if held else 0.0}
+            "moe_load_max_over_mean": at_max / held if held else 0.0,
+            "moe_tile_rows": int((-(-per // tile) * tile).sum())}

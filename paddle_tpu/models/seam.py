@@ -43,9 +43,9 @@ inside a layer.  A *serving model* is any object with:
   the one way between the layouts.  The pools are flattened
   ``[L*P, ...]`` and ``base = l*P`` is added to every page id written
   or attended (``serving._run_layer_groups`` states the rule); ``ys``
-  is the layer's counters or None.  Pools, ``rows`` and ``base`` are
-  those of the layer's cache class, and ``ctx["cache_class"]`` is its
-  index.  ``side``
+  is the layer's counters (any pytree of arrays) or None.  Pools,
+  ``rows`` and ``base`` are those of the layer's cache class, and
+  ``ctx["cache_class"]`` is its index.  ``side``
   is this layer's ``[P, *page_shape]`` slice of each side plane in the
   spec's order, under the layer's own page ids (no ``base``), and comes
   back updated behind ``ys``; a model that declares none gets and

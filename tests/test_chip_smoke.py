@@ -82,10 +82,13 @@ def test_serve_leg_tiny():
 def test_latent_leg_tiny():
     r = chip_smoke.latent_leg(chip_smoke.LatentLeg(
         n_rows=4, qb=4, n_heads=2, kv_rank=32, rope_dim=8, page_size=16,
-        max_blocks=4, n_pages=12))
+        max_blocks=4, n_pages=12, expert_shapes=((64, 128, 128),),
+        n_held=4))
     assert set(r["kernel_vs_xla"]) == {
         "mla_paged_attention_kernel_p4", "latent_write_k_rope",
-        "latent_write_c_kv"}
+        "latent_write_c_kv",
+        "grouped_expert_matmul_h128_f128_kernel_m64_k128_n128",
+        "grouped_expert_matmul_h128_f128_kernel_m64_k128_n128_none_held"}
     assert all(c["rel_err"] <= c["tol"] for c in r["kernel_vs_xla"].values())
 
 

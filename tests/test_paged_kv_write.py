@@ -180,3 +180,32 @@ def test_step_compiled_for_v5e_updates_the_pool_in_place(
     layer = pools // cfg.n_layers
     assert ma.temp_size_in_bytes < (1.25 * layer if kv_quant
                                     else 0.5 * layer), ma
+
+
+@pytest.mark.parametrize("m", [1024, 4096])
+@pytest.mark.parametrize("K,N,gated,layers", [
+    (4096, 4096, True, 4), (4096, 4096, False, 4),      # command-a-plus-ep8
+    (2048, 768, True, 39), (768, 2048, False, 39)],     # joyai-flash-ep16
+    ids=["cohere-gate_up", "cohere-down", "joyai-gate_up", "joyai-down"])
+def test_grouped_expert_matmul_compiled_for_v5e_at_the_routed_cells_shapes(
+        one_chip, compiled_kernels, m, K, N, gated, layers):
+    """The held experts' products of both routed cells at both step sizes
+    (128 and 512 places x top-8), on the tiling the kernel's rule gives,
+    one layer of the whole stack by a traced offset: Mosaic takes it
+    (VMEM, tile alignment), and the compiled call holds no temporary the
+    size of an expert, let alone of a layer sliced out of the stack."""
+    from paddle_tpu.ops.pallas import grouped_expert_matmul as gem
+
+    tiling = gem._tiling(gem.candidates_for(m, K, N, 2)[1])
+    bf16 = jnp.bfloat16
+
+    def shape(*dims, dtype=bf16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    stack = shape(layers * 16, K, N)
+    compiled = jax.jit(
+        lambda *a: gem._grouped_kernel(*a, tiling=tiling)).lower(
+            shape(m, K), stack, shape(16, dtype=jnp.int32),
+            stack if gated else None, shape(dtype=jnp.int32)).compile()
+    assert "grouped_expert_matmul" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < K * N * 2

@@ -230,7 +230,7 @@ def test_moe_counters_count_the_ticks_tokens_at_every_size():
         for rung in (r for r in engine.rungs if r >= n_tok):
             ys = _replay(engine, tick, rung)[2]
             per_layer = np.concatenate(
-                [np.asarray(y).reshape(-1, cfg.held[1])
+                [np.asarray(y[0]).reshape(-1, cfg.held[1])   # (sizes, tile)
                  for y in ys if y is not None]).sum(1)
             assert (per_layer <= cfg.experts_per_token * n_tok).all()
 

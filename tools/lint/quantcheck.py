@@ -148,6 +148,7 @@ PALLAS_KERNEL_MODULES = (
     "paddle_tpu.ops.pallas.decode_attention",
     "paddle_tpu.ops.pallas.flash_attention",
     "paddle_tpu.ops.pallas.fused_ce",
+    "paddle_tpu.ops.pallas.grouped_expert_matmul",
     "paddle_tpu.ops.pallas.lora_matmul",
     "paddle_tpu.ops.pallas.mla_paged_attention",
     "paddle_tpu.ops.pallas.quant_matmul",
