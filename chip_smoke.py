@@ -61,7 +61,9 @@ from paddle_tpu.ops.pallas import fused_rope_attention as RA
 from paddle_tpu.ops.pallas import grouped_expert_matmul as GEM
 from paddle_tpu.ops.pallas import mla_paged_attention as MPA
 from paddle_tpu.ops.pallas import paged_kv_write as KVW
+from paddle_tpu.ops.pallas import ragged_causal_conv as RCC
 from paddle_tpu.ops.pallas import ragged_paged_attention as RPA
+from paddle_tpu.ops.pallas import ragged_ssm_scan as SSM
 from paddle_tpu.parallel import make_sharded_train_step
 
 # what the chip tool brings back from a run (gitignored)
@@ -136,6 +138,20 @@ class LatentLeg:
     # width) of each routed cell: command-a-plus-ep8, joyai-flash-ep16
     expert_shapes: tuple = ((4096, 4096, 4096), (4096, 2048, 768))
     n_held: int = 16
+
+
+@dataclasses.dataclass(frozen=True)
+class StateLeg:
+    """The state-space scan at one grid geometry: the benchmark's
+    granite-4.0-h-micro cell by default."""
+    n_rows: int = 40
+    qb: int = 16
+    n_heads: int = 64
+    head_dim: int = 64
+    d_state: int = 128
+    n_slots: int = 80
+    conv_dim: int = 4352
+    d_conv: int = 4
 
 
 def full_train_leg() -> TrainLeg:
@@ -662,6 +678,62 @@ def _grouped_expert_parity(leg: LatentLeg, checks: dict, rnd) -> None:
 
 
 # ---------------------------------------------------------------------------
+# state leg: the state-space scan against its XLA form
+# ---------------------------------------------------------------------------
+
+def state_leg(leg: StateLeg) -> dict:
+    """``ragged_ssm_scan`` at every head block the gate admits against
+    its XLA form on one grid: decode rows (one a fresh request's, from
+    the zero slot), a request of three chained chunk rows whose last is
+    partial and which leaves its state in another slot than it read, a
+    request whose only row is a full chunk, idle rows behind them.  Both
+    the read-outs and every slot of the pool are compared (the dump
+    apart).  Then ``ragged_causal_conv`` on the same rows."""
+    C, qb, nH, hd, N, S = (leg.n_rows, leg.qb, leg.n_heads, leg.head_dim,
+                           leg.d_state, leg.n_slots)
+    rnd = _normal(17, jnp.bfloat16)
+    rs = np.random.RandomState(7)
+    n_dec = C - 6
+    read = np.r_[2 + np.arange(n_dec), [S - 3] * 3, S - 4, 1, 1]
+    write = np.r_[2 + np.arange(n_dec), [S - 2] * 3, S - 4, 1, 1]
+    read[1] = 0                                   # a fresh request
+    n_valid = np.r_[np.ones(n_dec), qb, qb, qb // 2 + 1, qb, 1, 1]
+    pool = rnd((S,) + SSM.state_shape(nH, hd, N), jnp.float32).at[0].set(0.0)
+    x, Bm, Cm = rnd((C * qb, nH * hd)), rnd((C * qb, N)), rnd((C * qb, N))
+    dt = jnp.asarray(np.exp(rs.uniform(np.log(1e-3), np.log(0.2),
+                                       (C, qb, nH))), jnp.float32)
+    A = -jnp.asarray(rs.uniform(1.0, 16.0, (nH,)), jnp.float32)
+    ops = (x, dt, A, Bm, Cm, jnp.asarray(read, jnp.int32),
+           jnp.asarray(write, jnp.int32), jnp.asarray(n_valid, jnp.int32))
+    held = (np.arange(qb)[None] < n_valid[:, None]) & (write != 1)[:, None]
+    held = jnp.asarray(held).reshape(C * qb, 1)
+    checks: dict = {}
+    y0, p0 = SSM.ragged_ssm_scan(pool, *ops, dump=1, impl="xla")
+    for impl in SSM.candidates_for(pool.shape, hd, qb)[1:]:
+        y, p = SSM.ragged_ssm_scan(pool, *ops, dump=1, impl=impl)
+        check_close(checks, f"ragged_ssm_scan_{impl}_y",
+                    jnp.where(held, y, 0.0), jnp.where(held, y0, 0.0),
+                    TOL_F32)
+        check_close(checks, f"ragged_ssm_scan_{impl}_pool",
+                    p.at[1].set(0.0), p0.at[1].set(0.0), TOL_F32)
+    # the mixer's convolution on the same rows, its states in a pool of
+    # the same slots
+    K, Dc = leg.d_conv - 1, leg.conv_dim
+    cpool = rnd((S, K * Dc)).at[0].set(0.0)
+    xc, w, b = rnd((C * qb, Dc)), rnd((Dc, K + 1), jnp.float32), \
+        rnd((Dc,), jnp.float32)
+    conv = {impl: RCC.ragged_causal_conv(
+        cpool, xc, w, b, *ops[5:], qb=qb, zero=0, dump=1, impl=impl)
+        for impl in ("xla", "kernel")}
+    check_close(checks, "ragged_causal_conv_act",
+                jnp.where(held, conv["kernel"][0], 0.0),
+                jnp.where(held, conv["xla"][0], 0.0), TOL_BF16)
+    check_close(checks, "ragged_causal_conv_pool", conv["kernel"][1],
+                conv["xla"][1], 0.0)
+    return {"geometry": dataclasses.asdict(leg), "kernel_vs_xla": checks}
+
+
+# ---------------------------------------------------------------------------
 # mesh leg (>= 4 devices)
 # ---------------------------------------------------------------------------
 
@@ -734,7 +806,8 @@ def main() -> int:
 
     legs = [("train", train_leg, full_train_leg()),
             ("serve", serve_leg, full_serve_leg()),
-            ("latent", latent_leg, LatentLeg())]
+            ("latent", latent_leg, LatentLeg()),
+            ("state", state_leg, StateLeg())]
     if len(jax.devices()) >= 4:
         # first, so that its per-device peaks are its own: the one-chip
         # legs that follow all land on device 0

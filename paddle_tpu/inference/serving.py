@@ -29,7 +29,13 @@ for the paged cache). TPU design:
   later requests map the cached pages into their block tables and skip
   those tokens entirely (the prefill-token counter proves zero redundant
   FLOPs). Only the page holding the last prompt token is always
-  re-prefilled — its logits produce the first token.
+  re-prefilled — its logits produce the first token. The hit rule,
+  stated once (models/seam.py has the classes): a hit of ``b`` tokens
+  needs (1) cache class 0 to hold pages ``[0, b)``, (2) every windowed
+  class the pages a query at ``b`` can still read, and (3) the state
+  class, where the model has one, a snapshot taken at exactly ``b``;
+  admission takes the longest ``b`` every class can honour
+  (``_usable_hit``) and counts what class 0 had beyond it.
 - Continuous batching: the scheduler admits queued requests into free
   slots every step (admission is page-pool-bound only — no prompt
   buckets), and a pool-blocked large request is skipped (with an aging
@@ -58,7 +64,8 @@ from jax import lax
 from ..core.flags import GLOBAL_FLAGS
 from ..models.llama import (LlamaConfig, LlamaServing,
                             quantize_weights_int8)
-from ..models.seam import cache_classes, token_layout
+from ..models.seam import (STATE_DUMP, STATE_ZERO, CacheClass, cache_classes,
+                           token_layout)
 from ..obs import clock as _clock
 from ..testing import chaos as _chaos
 from .. import obs as _obs
@@ -449,6 +456,97 @@ class _ClassPages:
         return counts
 
 
+class _StateSlots:
+    """The state class's pools, slot table and snapshots (models/seam.py:
+    the state class). Slot ids: ``STATE_ZERO``, ``STATE_DUMP``, then one
+    live slot an engine row (``live(s)``), then the snapshot slots,
+    which live under a ``_PagePool``'s rules (its page ``p`` is slot
+    ``snap0 + p``): free list, hash -> page, refcounts, pending, LRU.
+    Per engine row: ``at`` is the slot its request's state stands in (its
+    next tick reads it), ``held`` the cached snapshot it holds a
+    reference on (the one it hit, then the newest it wrote), ``pending``
+    a snapshot ``(page, hash index)`` the tick in flight writes and the
+    host has not yet hashed, ``private`` one it wrote that the cache
+    already had under another page. ``k_pages`` / ``v_pages`` are the two
+    planes' pools, named as the paged classes' are."""
+
+    def __init__(self, cls, B: int, n_snapshots: int):
+        # The step flattens a pool [L, S, ...] to [L * S, ...]. A plane
+        # of one row a slot has the slots down its sublanes, and the
+        # flattening moves no byte only where S fills whole tiles: the
+        # snapshot slots asked for are rounded up to that (none stays
+        # none).
+        tile = max([32 // jnp.dtype(p.dtype).itemsize
+                    for p in cls.planes if len(p.shape) == 1] or [1])
+        if n_snapshots:
+            n_snapshots += -(2 + B + n_snapshots) % tile
+        self.cls, self.B, self.n_snapshots = cls, B, n_snapshots
+        self.snap0 = 1 + B                  # slot of snapshot page 1, less 1
+        self.n_slots = 2 + B + n_snapshots
+        self.k_pages, self.v_pages = (
+            jnp.zeros((cls.n_layers, self.n_slots) + plane.shape,
+                      plane.dtype) for plane in cls.planes)
+        self.pool = _PagePool(n_snapshots + 1)
+        self.at = [STATE_ZERO] * B
+        self.held = [0] * B
+        self.pending: list = [None] * B
+        self.private = [0] * B
+        self.deferred_free: list[int] = []
+
+    def live(self, slot: int) -> int:
+        return 2 + slot
+
+    def key(self, h: bytes) -> bytes:
+        return h + self.cls.hash_tag
+
+    def has(self, h: bytes) -> bool:
+        return self.key(h) in self.pool.cache
+
+    def alloc(self) -> int:
+        """A snapshot page to write, 0 when none can be had: a free one,
+        else the least recently used that nobody holds."""
+        if not self.pool.free:
+            self.pool.evict(1)
+        got = self.pool.alloc(1)
+        return got[0] if got else 0
+
+    def settle(self) -> None:
+        """Once no in-flight program can read them (_ClassPages.settle)."""
+        self.pool.release(self.deferred_free)
+        self.deferred_free = []
+        self.pool.commit_evictable()
+
+    def release_slot(self, slot: int, defer: bool) -> None:
+        if self.held[slot]:
+            self.pool.decref([self.held[slot]])
+        if self.pending[slot] is not None:
+            self.deferred_free.append(self.pending[slot][0])
+        if self.private[slot]:
+            self.deferred_free.append(self.private[slot])
+        self.held[slot] = self.private[slot] = 0
+        self.pending[slot] = None
+        self.at[slot] = STATE_ZERO
+        if not defer:
+            self.settle()
+
+    def slots_of(self, slot: int) -> int:
+        """Slots the request in engine row ``slot`` holds: its live slot
+        and the snapshots it holds or writes."""
+        return (1 + bool(self.held[slot]) + (self.pending[slot] is not None)
+                + bool(self.private[slot]))
+
+    def accounting(self) -> dict:
+        counts = {
+            "free": len(self.pool.free),
+            "held": len({p for p in self.held if p}),
+            "pending": sum(p is not None for p in self.pending),
+            "private": sum(map(bool, self.private)),
+            "cache_idle": sum(1 for r in self.pool.ref.values() if r == 0),
+            "deferred_free": len(self.deferred_free)}
+        counts["total"] = sum(counts.values())
+        return counts
+
+
 class ServingEngine:
     """Continuous-batching serving over paged KV, for any model behind
     the seam (models/seam.py).
@@ -628,6 +726,9 @@ class ServingEngine:
         # v_pages, pool, _full_rows, _slot_owned ...); each further
         # class has its own of each in ``_extra``, ``class_pages[name]``
         # pages of it (n_pages where not given).
+        # A model with layers whose cache is a slot of fixed size a
+        # request declares a state class behind its paged ones
+        # (``_state``: _StateSlots, ``class_pages[name]`` snapshot slots).
         self.classes = cache_classes(self.model, self.bs)
         L = self.classes[0].n_layers
         self.cache_spec = spec = self.classes[0].spec
@@ -641,14 +742,32 @@ class ServingEngine:
             _ClassPages(c, int((class_pages or {}).get(c.name,
                                                        self.n_pages)),
                         self.B, self.max_blocks, prefix_cache_pages)
-            for c in self.classes[1:]]
+            for c in self.classes[1:] if isinstance(c, CacheClass)]
+        self._state = None
         for i, c in enumerate(self.classes):
+            if isinstance(c, CacheClass):
+                _obs.instant("engine.cache_spec", engine=self.engine_id,
+                             bytes_per_token=self.kv_bytes_per_token(i),
+                             planes=",".join(f"{p.name}:{p.width}"
+                                             for p in c.spec.planes),
+                             cache_class=c.name, layers=c.n_layers,
+                             window=c.window or 0)
+                continue
+            # where not given: two snapshot slots a row
+            self._state = _StateSlots(c, self.B, int(
+                (class_pages or {}).get(c.name, 2 * self.B)))
             _obs.instant("engine.cache_spec", engine=self.engine_id,
-                         bytes_per_token=self.kv_bytes_per_token(i),
-                         planes=",".join(f"{p.name}:{p.width}"
-                                         for p in c.spec.planes),
+                         slot_bytes=c.slot_bytes(),
+                         planes=",".join(
+                             f"{p.name}:{'x'.join(map(str, p.shape))}"
+                             for p in c.planes),
                          cache_class=c.name, layers=c.n_layers,
-                         window=c.window or 0)
+                         live_slots=self.B,
+                         snapshot_slots=self._state.n_snapshots)
+        # every class beyond class 0, in the order its three operands
+        # (two pools and a table) ride in the step
+        self._further = self._extra + (
+            [self._state] if self._state is not None else [])
         self.table = np.zeros((self.B, self.max_blocks), np.int32)  # sink
         self.seq_lens = np.zeros((self.B,), np.int32)
         self.cur_tok = np.zeros((self.B,), np.int32)
@@ -665,6 +784,9 @@ class ServingEngine:
         self._slot_owned: list[list[int]] = [[] for _ in range(self.B)]
         self._slot_shared: list[list[int]] = [[] for _ in range(self.B)]
         self._slot_hashes: list[list[bytes]] = [[] for _ in range(self.B)]
+        # with a state class: the chain's hasher behind the last hash of
+        # _slot_hashes, which then grows past the prompt as answers do
+        self._slot_chain: list = [None] * self.B
         self._slot_nshared: list[int] = [0] * self.B
         self._slot_offered: list[int] = [0] * self.B
         self._full_rows = np.zeros((self.B, self.max_blocks), np.int32)
@@ -698,7 +820,7 @@ class ServingEngine:
         self._unified = jax.jit(
             self._unified_step_impl,
             donate_argnums=(1, 2) + tuple(range(14, x0)) + tuple(
-                x0 + 3 * i + j for i in range(len(self._extra))
+                x0 + 3 * i + j for i in range(len(self._further))
                 for j in (0, 1)))
         # pipelining state (see step() docstring): _inflight holds the
         # dispatched-but-unharvested program's (output tokens, row
@@ -759,6 +881,24 @@ class ServingEngine:
                 [f"pages_live.{c.name}" for c in self.classes]
                 + ["context_tokens_live", "pages_released_by_window",
                    "prefill_window_lost_tokens"], 0))
+        if self._state is not None:
+            # what the state class does: slots (and their bytes) live
+            # requests hold, summed over ticks as the pages are;
+            # snapshots written, hit at admission, evicted for room, and
+            # ticks that stood on a page boundary and found no slot for
+            # one; tokens of prefix hits that the paged classes had and
+            # no snapshot stood at; admissions that found a prefix of
+            # theirs in class 0 (the hits' denominator); preempted
+            # requests that resumed from a snapshot
+            self.stats.update(dict.fromkeys(
+                ([] if self._extra else
+                 [f"pages_live.{self.classes[0].name}",
+                  "context_tokens_live"])
+                + ["state_slots_live", "state_bytes_live",
+                   "state_snapshots_taken", "state_snapshots_hit",
+                   "state_snapshots_evicted", "state_snapshots_unavailable",
+                   "prefix_state_lost_tokens", "admitted_with_cached_prefix",
+                   "preempt_resumed_from_snapshot"], 0))
         # a dispatched tick's share of them, for engine.step's end
         self._class_tick: dict = {}
 
@@ -829,7 +969,7 @@ class ServingEngine:
         # packed axis arange(T), whose LENGTH is the step size this tick
         # runs at (``rungs``): one jit, one program a length
         n_side = len(self.cache_spec.side)
-        n_x = n_side + 3 * len(self._extra)
+        n_x = n_side + 3 * len(self._further)
         side, further = rest[:n_side], rest[n_side:n_x]
         mt, places = list(rest[n_x:-1]), rest[-1]
         pools = [(k_pages, v_pages)] + [
@@ -840,7 +980,8 @@ class ServingEngine:
 
         tok0 = jnp.where(chain_mask, prev_out[chain_row, 0], tokens[:, 0])
         tokens = jnp.concatenate([tok0[:, None], tokens[:, 1:]], axis=1)
-        # a row's block-table row in each class      # [C, max_blocks]
+        # a row's block-table row in each paged class [C, max_blocks],
+        # its request's two slots in the state class [C, 2]
         rows = [t[row_slot] for t in (ptable, *further[2::3])]
         positions = pos0[:, None] + jnp.arange(qb, dtype=jnp.int32)
         # the tick's tokens packed: an idle row (the sink's) carries none
@@ -924,6 +1065,9 @@ class ServingEngine:
                 *map(sds, self.side_planes.values()),
                 *(a for x in self._extra
                   for a in (sds(x.k_pages), sds(x.v_pages), ptab)),
+                *(() if self._state is None else (
+                    sds(self._state.k_pages), sds(self._state.v_pages),
+                    jax.ShapeDtypeStruct((B + 1, 2), i32))),
                 jax.ShapeDtypeStruct((rung,), i32))
 
     def lower_unified(self, rung: Optional[int] = None):
@@ -1072,14 +1216,33 @@ class ServingEngine:
         # argument to per-request LoRA: the v-projection delta changes
         # the page bytes, so the adapter's content digest joins the preimage
         # (same-adapter requests still share; cross-adapter never alias).
-        h = hashlib.sha1(b"pt-prefix:%d" % self.bs
-                         + self.cache_spec.hash_tag + salt)
+        h = self._hash_chain(salt)
         for j in range(n_full):
             h.update(np.ascontiguousarray(
                 prompt[j * self.bs:(j + 1) * self.bs],
                 dtype=np.int32).tobytes())
             out.append(h.digest())
         return out
+
+    def _hash_chain(self, salt: bytes = b""):
+        """The prefix chain's hasher before its first page."""
+        return hashlib.sha1(b"pt-prefix:%d" % self.bs
+                            + self.cache_spec.hash_tag + salt)
+
+    def _extend_hashes(self, slot: int) -> list[bytes]:
+        """With a state class a request's chain grows past its prompt:
+        the hashes of every full page of what the host knows of it (its
+        prompt and the tokens harvested so far)."""
+        req, hashes = self.slots[slot], self._slot_hashes[slot]
+        n_known = (len(req.prompt) + len(req.out_tokens)) // self.bs
+        if n_known > len(hashes) and self._slot_chain[slot] is not None:
+            toks = np.concatenate([np.asarray(req.prompt, np.int32),
+                                   np.asarray(req.out_tokens, np.int32)])
+            for j in range(len(hashes), n_known):
+                self._slot_chain[slot].update(np.ascontiguousarray(
+                    toks[j * self.bs:(j + 1) * self.bs]).tobytes())
+                hashes.append(self._slot_chain[slot].digest())
+        return hashes
 
     def _cache_salt(self, req: Request) -> bytes:
         """The per-request prefix-cache hash salt: the LoRA adapter's
@@ -1139,11 +1302,13 @@ class ServingEngine:
         return (self._prefilling[slot] if slot in self._prefilling
                 else int(self.seq_lens[slot]))
 
-    def _usable_hit(self, hashes: list[bytes]) -> tuple:
+    def _usable_hit(self, hashes: list[bytes], state: bool = True) -> tuple:
         """(n, n0): the longest prefix hit every class can honour, in
         pages, and the longest class 0 alone has. A hit of ``n`` pages
-        needs class 0 to hold pages ``[0, n)`` and every windowed class
-        the pages a query at ``n * bs`` can still read."""
+        needs class 0 to hold pages ``[0, n)``, every windowed class
+        the pages a query at ``n * bs`` can still read, and the state
+        class (left out with ``state`` false: what the paged classes
+        could honour alone) a snapshot taken at ``n * bs``."""
         n0 = self.pool.peek(hashes)
         runs = []                   # per class: cached pages in a row
         for x in self._extra:       # ending at page j, for j < n0
@@ -1153,9 +1318,11 @@ class ServingEngine:
                 run.append(r)
             runs.append(run)
         n = n0
-        while n and any(
+        st = self._state if state else None
+        while n and (any(
                 run[n - 1] < n - x.cls.live_from(n * self.bs) // self.bs
-                for x, run in zip(self._extra, runs)):
+                for x, run in zip(self._extra, runs))
+                or (st is not None and not st.has(hashes[n - 1]))):
             n -= 1
         return n, n0
 
@@ -1260,11 +1427,17 @@ class ServingEngine:
                 hashes = (self._page_hashes(P, self._cache_salt(req))
                           if self._cache_on else [])
                 limit = hashes[:(T - 1) // self.bs]
-                if self._extra and limit:
+                if self._further and limit:
                     n_hit, n0 = self._usable_hit(limit)
                     self.pool.misses += len(limit) - n_hit
-                    self.stats["prefill_window_lost_tokens"] += (
-                        (n0 - n_hit) * self.bs)
+                    n_paged = n_hit
+                    if self._state is not None:
+                        n_paged, _ = self._usable_hit(limit, state=False)
+                        self.stats["prefix_state_lost_tokens"] += (
+                            (n_paged - n_hit) * self.bs)
+                    if self._extra:
+                        self.stats["prefill_window_lost_tokens"] += (
+                            (n0 - n_paged) * self.bs)
                     limit = limit[:n_hit]
                 shared = self.pool.lookup(limit)
                 pages = self._alloc_pages(n_blk - len(shared))
@@ -1327,6 +1500,29 @@ class ServingEngine:
             # only tokens actually run)
             self._prefilling[slot] = n_shared * self.bs
             self.stats["prefill_cached_tokens"] += n_shared * self.bs
+            if self._state is not None:
+                self._admit_state(slot, req, P, n_shared)
+
+    def _admit_state(self, slot: int, req: Request, P, n_shared: int) -> None:
+        """The state class's part of an admission: the request's state
+        stands in the snapshot its hit ends on (which it now holds), or
+        in the zero slot; its chain is kept so that it can grow."""
+        st, hashes = self._state, self._slot_hashes[slot]
+        st.at[slot], st.held[slot] = STATE_ZERO, 0
+        if n_shared:
+            st.held[slot], = st.pool.lookup([st.key(hashes[n_shared - 1])])
+            st.at[slot] = st.snap0 + st.held[slot]
+            self.stats["state_snapshots_hit"] += 1
+            self.stats["preempt_resumed_from_snapshot"] += bool(
+                req.n_preempted)
+        if hashes and hashes[0] in self.pool.cache:
+            self.stats["admitted_with_cached_prefix"] += 1
+        self._slot_chain[slot] = None
+        if self._cache_on:
+            chain = self._hash_chain(self._cache_salt(req))
+            chain.update(np.ascontiguousarray(
+                P[:len(hashes) * self.bs], dtype=np.int32).tobytes())
+            self._slot_chain[slot] = chain
 
     def _preempt_for(self, req: Request) -> bool:
         """Evict the weakest strictly-lower-priority resident so ``req``
@@ -1384,7 +1580,7 @@ class ServingEngine:
             self.pool.release(owned)
             self.pool.commit_evictable()
         self._full_rows[slot] = 0
-        for x in self._extra:
+        for x in self._further:
             x.release_slot(slot, defer)
         # adapter refcount rides slot residency: every teardown path
         # (finish / abort / preempt / predictive release) lands here.
@@ -1492,6 +1688,8 @@ class ServingEngine:
                 sp.set(**tick)
         if self.prefill_only:
             self._export_completed()
+        if self._state is not None:
+            self._publish_snapshots()
         if self._extra:
             # and what the windows let go of behind the tick
             sp.set(**tick, pages_released_by_window=(
@@ -1499,7 +1697,7 @@ class ServingEngine:
         if self._inflight is None and (
                 self._deferred_free or self.pool.pending_evict or any(
                     x.deferred_free or x.pool.pending_evict
-                    for x in self._extra)):
+                    for x in self._further)):
             # nothing in flight: deferred/pending pages can only be
             # touched by programs already chained BEFORE any future
             # consumer (the donated page arrays serialize every
@@ -1508,7 +1706,7 @@ class ServingEngine:
             self.pool.release(self._deferred_free)
             self._deferred_free = []
             self.pool.commit_evictable()
-            for x in self._extra:
+            for x in self._further:
                 x.settle()
         # predictive release: each in-flight token-bearing row yields
         # exactly one token (speculation off), so a request the just-
@@ -1626,8 +1824,15 @@ class ServingEngine:
                 break
             T = len(self._slot_prompt[slot])   # prompt (+ resumed history)
             pos = self._prefilling[slot]
-            while pos < T and len(sched) < C:
-                n = min(qb, T - pos)
+            end = T
+            if self._state is not None and self._cache_on:
+                # a tick's last chunk ends on a page boundary where it
+                # can: the state is snapshotted there (seam.py)
+                hi = min(T, pos + (C - len(sched)) * qb)
+                cut = hi // self.bs * self.bs
+                end = cut if hi % self.bs and cut > pos else hi
+            while pos < end and len(sched) < C:
+                n = min(qb, end - pos)
                 sched.append((slot, "fin" if pos + n >= T else "mid",
                               pos, n, None))
                 pos += n
@@ -1637,6 +1842,7 @@ class ServingEngine:
             return
         if self._extra:
             self._grow_classes(sched)
+        stab = self._state_table(sched) if self._state is not None else None
         tokens = np.zeros((C, qb), np.int32)
         rs = np.full((C,), self.B, np.int32)       # idle rows -> sink row
         p0 = np.zeros((C,), np.int32)
@@ -1701,6 +1907,8 @@ class ServingEngine:
         xtabs = [jnp.array(np.concatenate(
             [x.full_rows, np.zeros((1, self.max_blocks), np.int32)]))
             for x in self._extra]
+        if stab is not None:
+            xtabs.append(jnp.array(stab))
         per_row = (jnp.array(p0), jnp.array(nv), jnp.array(tt),
                    jnp.array(tp), jnp.array(tsd))
 
@@ -1709,11 +1917,11 @@ class ServingEngine:
                 self._unified(
                     self.params, self.k_pages, self.v_pages, *fixed,
                     row_slot, *per_row, *self.side_planes.values(),
-                    *(a for x, t in zip(self._extra, xtabs)
+                    *(a for x, t in zip(self._further, xtabs)
                       for a in (x.k_pages, x.v_pages, t)),
                     *extra, self._places[rung]))
             self.side_planes = dict(zip(self.side_planes, side))
-            for i, x in enumerate(self._extra):
+            for i, x in enumerate(self._further):
                 x.k_pages, x.v_pages = further[2 * i:2 * i + 2]
             return out, ys
 
@@ -1790,17 +1998,22 @@ class ServingEngine:
             sum(m for _s, kind, _p, m, _d in sched if kind == "dec")
             + len(fin_slots) + n_mid_slots + n_idle)
         self.stats["unified_steps"] += 1
-        if self._extra:
+        if self._further:
             # what live requests hold in each class against the context
             # it stands for, this tick and summed
+            resident = [s for s in range(self.B)
+                        if self.slots[s] is not None]
             live = {f"pages_live.{self.classes[0].name}": sum(
                 len(self._slot_owned[s]) + len(self._slot_shared[s])
-                for s in range(self.B) if self.slots[s] is not None)}
+                for s in resident)}
             live.update({f"pages_live.{x.cls.name}": x.live_pages()
                          for x in self._extra})
             live["context_tokens_live"] = sum(
-                self._next_query(s) for s in range(self.B)
-                if self.slots[s] is not None)
+                self._next_query(s) for s in resident)
+            if self._state is not None:
+                n = sum(map(self._state.slots_of, resident))
+                live["state_slots_live"] = n
+                live["state_bytes_live"] = n * self._state.cls.slot_bytes()
             for k, v in live.items():
                 self.stats[k] += v
             self._class_tick = live
@@ -1809,6 +2022,89 @@ class ServingEngine:
         if n_pf_rows:
             self.stats["prefills"] += 1
             self.stats["prefill_grid_tokens"] += n_pf_rows * qb
+
+    def _state_table(self, sched: list) -> np.ndarray:
+        """The state class's operand for the tick being built: per engine
+        row the slot its request's state is read from and the slot it is
+        written to (seam.py: the state class), the sink's row for idle
+        rows. A request whose processed length stands on a page boundary
+        at the end of this tick writes a snapshot slot, where one can be
+        had, and reads it from there next tick; every other writes its
+        live slot."""
+        st = self._state
+        tab = np.full((self.B + 1, 2), STATE_DUMP, np.int32)
+        ends: dict[int, int] = {}
+        for s, _kind, pos, m, _d in sched:
+            ends[s] = pos + m
+        for s, end in ends.items():
+            read, write = st.at[s], st.live(s)
+            if self._cache_on and end % self.bs == 0:
+                evictable = len(st.pool.evictable)
+                page = st.alloc()
+                self.stats["state_snapshots_evicted"] += (
+                    evictable - len(st.pool.evictable))
+                if page:
+                    if st.pending[s] is not None:   # never hashed: let go
+                        st.deferred_free.append(st.pending[s][0])
+                    st.pending[s] = (page, end // self.bs - 1)
+                    write = st.snap0 + page
+                    self.stats["state_snapshots_taken"] += 1
+                else:
+                    self.stats["state_snapshots_unavailable"] += 1
+            if st.private[s] and read != write:
+                # the tick moves the state off a snapshot of its own that
+                # the cache had no use for
+                st.deferred_free.append(st.private[s])
+                st.private[s] = 0
+            tab[s] = read, write
+            st.at[s] = write
+        return tab
+
+    def _publish_snapshots(self) -> None:
+        """Put the snapshots the tick in flight writes under their
+        hashes, once the host knows the tokens behind them (after the
+        harvest of the tick before: seam.py), and offer class 0's pages
+        up to them, which a hit on them needs. The request then holds
+        the new snapshot in place of the one it held."""
+        st = self._state
+        for s in range(self.B):
+            if st.pending[s] is None or self.slots[s] is None:
+                continue
+            page, j = st.pending[s]
+            hashes = self._extend_hashes(s)
+            if len(hashes) <= j:
+                continue
+            st.pending[s] = None
+            for i in range(self._slot_offered[s], j + 1):
+                pg = int(self._full_rows[s][i])
+                if self.pool.insert(hashes[i], pg):
+                    self._slot_owned[s].remove(pg)
+                    self._slot_shared[s].append(pg)
+            self._slot_offered[s] = max(self._slot_offered[s], j + 1)
+            if st.pool.insert(st.key(hashes[j]), page):
+                if st.held[s]:
+                    st.pool.decref([st.held[s]])
+                st.held[s] = page
+            else:
+                st.private[s] = page
+
+    def cached_snapshots(self) -> list:
+        """``(request, tokens, slot)`` for every snapshot of a live
+        request that stands under the cache's hashes, the one it holds
+        and the earlier ones not yet evicted: slot ``slot`` of the state
+        class's pools holds the request's state after exactly its first
+        ``tokens`` tokens (its prompt, then what was served). For checks
+        that read the state itself; nothing on the serving path calls
+        it."""
+        st, out = self._state, []
+        for s, req in enumerate(self.slots):
+            if st is None or req is None:
+                continue
+            for j, h in enumerate(self._slot_hashes[s]):
+                page = st.pool.cache.get(st.key(h))
+                if page is not None:
+                    out.append((req, (j + 1) * self.bs, st.snap0 + page))
+        return out
 
     def _harvest(self, inflight) -> None:
         """Fetch a completed step's row outputs (the only host sync of
@@ -1829,7 +2125,7 @@ class ServingEngine:
         self.pool.release(self._deferred_free)
         self._deferred_free = []
         self.pool.commit_evictable()
-        for x in self._extra:
+        for x in self._further:
             x.settle()
         now = _clock.now()
         for idx, s, req, kind, m, drafts in snap:
@@ -2457,10 +2753,10 @@ class ServingEngine:
             "in_flight": sum(len(h["staged"]) for h in self._adopting),
         }
         counts["total"] = sum(counts.values())
-        if self._extra:
+        if self._further:
             counts["classes"] = {
                 self.classes[0].name: dict(counts),
-                **{x.cls.name: x.accounting() for x in self._extra}}
+                **{x.cls.name: x.accounting() for x in self._further}}
         return counts
 
     def run(self, requests: list[Request]) -> dict:
@@ -2487,14 +2783,14 @@ class ServingEngine:
                 time.sleep(min(max(wait, 0.001), 0.05))
         wall = _clock.now() - t0
         if (self._deferred_free or self.pool.pending_evict
-                or self._extra):
+                or self._further):
             # nothing is in flight after the drive loop: settle deferred
             # frees (e.g. a final-step abort) so page_accounting sees
             # steady state
             self.pool.release(self._deferred_free)
             self._deferred_free = []
             self.pool.commit_evictable()
-            for x in self._extra:
+            for x in self._further:
                 x.settle()
         done = [r for r in requests if not r.aborted]
         lat = [r.t_done - (t0 + r.arrival) for r in done

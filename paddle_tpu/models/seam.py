@@ -16,11 +16,12 @@ inside a layer.  A *serving model* is any object with:
   the page's state, and a fresh page is zeros), counts it in a page's
   bytes, donates it to the step and keeps what the step returns.  ``spec.hash_tag`` joins
   the prefix hash, so pages of two formats never alias in the cache;
-- optionally ``cache_classes(page_size) -> (CacheClass, ...)``: a model
-  whose layers do not all keep a token equally long declares *cache
-  classes* (below).  A model without the method has one class, all its
-  layers under ``cache_spec``, and nothing in the step it traces says
-  otherwise;
+- optionally ``cache_classes(page_size) -> (CacheClass, ..., StateClass)``:
+  a model whose layers do not all keep a token equally long declares
+  *cache classes* (below), and one whose layers keep a state of fixed
+  size a request a *state class* behind them.  A model without the
+  method has one class, all its layers under ``cache_spec``, and nothing
+  in the step it traces says otherwise;
 - ``unsupported``: names of engine features this model does not serve
   (the engine fails with one error when asked for one);
 - ``embed(params, tokens, positions) -> (x, ctx)``: from the tick's
@@ -82,6 +83,46 @@ still read (``None``: all of them).  The rules, stated once:
 - admission needs room in every class; preemption, abort and finish
   release in every class; side planes are class 0's alone.
 
+**The state class.**  Layers whose cache does not grow with the context
+(a recurrence's state, a convolution's last inputs) form a *state
+class* (``StateClass``): its unit is a **slot**, per layer a tuple of
+planes of fixed shape, each of its own dtype, and its pool is one array
+a plane, ``[L_s, S, *shape]``.  A model declares at most one, behind its
+paged classes; the pools and a ``[B + 1, 2]`` table ride in the step as a
+further class's do, and ``apply`` gets as ``rows`` the ``[C, 2]`` slot ids
+of each row's request: where its state is READ at the start of the tick
+and where it is WRITTEN at its end (under the flattening of
+``serving._run_layer_groups``: add ``base``).  The model advances the
+state over the row's tokens, carrying it across the consecutive rows of
+one request, and writes nothing for an idle row, whose pair is
+``(STATE_DUMP, STATE_DUMP)``.  The rules, stated once:
+
+- slot ``STATE_ZERO`` is never written and reads as zeros; slot
+  ``STATE_DUMP`` is never read.  Slots ``2 .. B + 1`` are *live* slots,
+  one an engine row; the rest are *snapshot* slots;
+- a live request holds its live slot from admission to finish, abort or
+  preemption.  A new tenant's first tick reads ``STATE_ZERO`` (or the
+  snapshot its prefix hit) and writes its live slot: the model never
+  sees the last tenant's state;
+- snapshots are kept under the prefix chain's hashes (the class's
+  ``hash_tag`` joins them) with the refcount / evictable / LRU life that
+  cached pages have.  A snapshot under hash ``j`` is the state after
+  exactly ``(j + 1) * page_size`` tokens.  **A prefix hit of ``b`` tokens
+  needs, beside what the paged classes need, a snapshot at ``b``**;
+- when snapshots are taken is the engine's: a request's prefill is cut
+  so that a tick's last chunk ends on a page boundary where it can, and
+  whenever a request's processed length stands on a page boundary at the
+  end of a tick, that tick writes its state into a snapshot slot instead
+  of its live slot, and its next tick reads it from there.  No state is
+  ever copied: taking a snapshot, loading a hit and resetting a tenant
+  are choices of the two slot ids;
+- a request holds the newest snapshot it wrote or hit (its *rolling*
+  snapshot; the one before becomes an ordinary cached snapshot,
+  evictable once unreferenced), so a preempted request resumes from it
+  through the prefix cache, or from token 0 when it was evicted;
+- when no snapshot slot can be had the tick writes the live slot and a
+  counter says so; nothing waits on one.
+
 **The packed axis.**  A tick carries ``sum(m)`` tokens, row ``c`` of the
 grid ``m[c]`` of them (an idle row none), and the grid has ``C * qb``
 places whatever it carries.  The packed axis holds the tick's tokens in
@@ -101,7 +142,11 @@ import jax.numpy as jnp
 import numpy as np
 
 __all__ = ["CacheClass", "CachePlane", "CacheSpec", "LayerGroup",
-           "SidePlane", "TokenLayout", "cache_classes", "token_layout"]
+           "STATE_DUMP", "STATE_ZERO", "SidePlane", "StateClass",
+           "StatePlane", "TokenLayout", "cache_classes", "token_layout"]
+
+# the state class's two fixed slots (module docstring)
+STATE_ZERO, STATE_DUMP = 0, 1
 
 
 @dataclasses.dataclass(frozen=True)
@@ -148,16 +193,45 @@ class CacheClass:
             0, next_query - self.window + 1)
 
 
+@dataclasses.dataclass(frozen=True)
+class StatePlane:
+    name: str
+    shape: tuple               # one slot of one layer
+    dtype: Any
+
+
+@dataclasses.dataclass(frozen=True)
+class StateClass:
+    """Layers whose cache is a slot of fixed size a request (module
+    docstring: the state class)."""
+    name: str
+    n_layers: int
+    planes: tuple              # (StatePlane, StatePlane): the step's pair
+    hash_tag: bytes = b""      # the format's mark in the snapshots' hashes
+
+    def slot_bytes(self) -> int:
+        """Bytes one slot costs across the class's layers."""
+        return self.n_layers * sum(
+            np.dtype(p.dtype).itemsize * math.prod(p.shape)
+            for p in self.planes)
+
+
 def cache_classes(model, page_size: int) -> tuple:
     """The model's cache classes; one, over every layer, for a model
     that declares none."""
     if hasattr(model, "cache_classes"):
         classes = tuple(model.cache_classes(page_size))
-        if classes[0].window is not None or any(
-                c.spec.side for c in classes[1:]):
+        paged = [c for c in classes if isinstance(c, CacheClass)]
+        if (not paged or classes[:len(paged)] != tuple(paged)
+                or len(classes) - len(paged) > 1
+                or paged[0].window is not None
+                or any(c.spec.side for c in paged[1:])
+                or any(len(c.planes) != 2 for c in classes[len(paged):])):
             raise ValueError(
-                "class 0 reads everything and owns the side planes "
-                "(models/seam.py: cache classes)")
+                "class 0 is paged, reads everything and owns the side "
+                "planes; at most one state class, of two planes, follows "
+                "the paged classes (models/seam.py: cache classes, the "
+                "state class)")
         return classes
     return (CacheClass("global", model.n_layers,
                        model.cache_spec(page_size)),)
@@ -193,7 +267,20 @@ class TokenLayout:
 
     def to_packed(self, a):
         """``a [C, qb, ...]`` on the packed axis ``[T, ...]``."""
-        a = a.reshape((-1,) + a.shape[2:])
+        return self.from_rows(a.reshape((-1,) + a.shape[2:]))
+
+
+    def to_rows(self, a):
+        """``a [T, ...]`` as the grid's places row by row, ``[C * qb,
+        ...]``: ``to_grid`` with the two leading dims as one (a kernel
+        that takes its tokens two-dimensional is spared XLA's choice of
+        layout for a ``[C, qb, .]`` array)."""
+        if self.dst is None:
+            return a
+        return a.at[self.dst.reshape(-1)].get(mode="promise_in_bounds")
+
+    def from_rows(self, a):
+        """``a [C * qb, ...]`` on the packed axis ``[T, ...]``."""
         if self.src is None:
             return a
         return a.at[self.src].get(mode="promise_in_bounds")

@@ -24,6 +24,7 @@ from typing import Optional
 
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "SERVING_STATS_SCHEMA", "CACHE_CLASS_STATS_SCHEMA",
+           "STATE_CLASS_STATS_SCHEMA",
            "FLEET_STATS_SCHEMA", "TRAIN_STATS_SCHEMA"]
 
 
@@ -84,6 +85,34 @@ CACHE_CLASS_STATS_SCHEMA = {
     "prefill_window_lost_tokens": ("counter",
                                    "tokens of prefix hits class 0 had and "
                                    "a windowed class had lost"),
+}
+
+# and when it declares a state class (slots of fixed size a request, with
+# snapshots under the prefix chain's hashes)
+STATE_CLASS_STATS_SCHEMA = {
+    "state_slots_live": ("counter",
+                         "state slots live requests hold (a live slot each "
+                         "and the snapshots they hold), summed over ticks"),
+    "state_bytes_live": ("counter", "their bytes, summed over ticks"),
+    "state_snapshots_taken": ("counter",
+                              "ticks that left a request's state in a "
+                              "snapshot slot"),
+    "state_snapshots_hit": ("counter",
+                            "admissions that started from a snapshot"),
+    "state_snapshots_evicted": ("counter",
+                                "cached snapshots reclaimed for a new one"),
+    "state_snapshots_unavailable": ("counter",
+                                    "ticks that stood on a page boundary "
+                                    "and found no snapshot slot"),
+    "prefix_state_lost_tokens": ("counter",
+                                 "tokens of prefix hits the paged classes "
+                                 "had and no snapshot stood at"),
+    "admitted_with_cached_prefix": ("counter",
+                                    "admissions whose first page class 0 "
+                                    "had cached"),
+    "preempt_resumed_from_snapshot": ("counter",
+                                      "preempted requests re-admitted on a "
+                                      "snapshot"),
 }
 
 FLEET_STATS_SCHEMA = {

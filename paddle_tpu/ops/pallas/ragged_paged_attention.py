@@ -120,7 +120,16 @@ def ragged_paged_supported(kt_pages_shape, n_q_heads: int, qb: int,
     """Gate for the MXU unified-RPA kernel at ``pps`` pages a grid step:
     d-major pages with MXU-tileable blocks — a head's score dot is
     [qb*G, d] x [d, bs] a page and its value dot [qb*G, bs] x [bs, d] —
-    plus the VMEM bound on the step's working set (_vmem_bytes)."""
+    plus the VMEM bound on the step's working set (_vmem_bytes).
+
+    Head widths, and where each runs: 128 and 256 run the kernel as they
+    are (Mistral, command-a-plus).  A model whose heads are 64 wide does
+    not ask for 64 here: it pages its kv heads in pairs of 128 (a pair's
+    k rows one above the other, its v columns side by side) and sends
+    query heads 128 wide with zeros under the pair's other head
+    (models/granite_hybrid.py), which is the kernel at 128 with exact
+    scores; any other width (a toy's 16) takes the XLA arm, as does a
+    page size that is no multiple of 128."""
     _, nkv, d, bs = kt_pages_shape
     if n_q_heads % nkv:
         return False

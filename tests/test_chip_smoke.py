@@ -92,6 +92,16 @@ def test_latent_leg_tiny():
     assert all(c["rel_err"] <= c["tol"] for c in r["kernel_vs_xla"].values())
 
 
+def test_state_leg_tiny():
+    r = chip_smoke.state_leg(chip_smoke.StateLeg(
+        n_rows=9, qb=8, n_heads=8, head_dim=16, d_state=128, n_slots=12,
+        conv_dim=256))
+    assert set(r["kernel_vs_xla"]) == {
+        f"ragged_ssm_scan_kernel_h8_{what}" for what in ("y", "pool")} | {
+        "ragged_causal_conv_act", "ragged_causal_conv_pool"}
+    assert all(c["rel_err"] <= c["tol"] for c in r["kernel_vs_xla"].values())
+
+
 def test_failed_check_raises():
     with pytest.raises(chip_smoke.SmokeFailure, match="rel_err"):
         chip_smoke.check_close({}, "k", jnp.ones(4), 2 * jnp.ones(4), 2e-2)

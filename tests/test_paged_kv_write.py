@@ -209,3 +209,54 @@ def test_grouped_expert_matmul_compiled_for_v5e_at_the_routed_cells_shapes(
             stack if gated else None, shape(dtype=jnp.int32)).compile()
     assert "grouped_expert_matmul" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes < K * N * 2
+
+
+def test_hybrid_step_compiled_for_v5e_updates_every_pool_in_place(
+        one_chip, compiled_kernels, monkeypatch):
+    """granite-4.0-h-micro widths, one period of ten layers (nine
+    state-space, one attention), the cell's grid of 40 rows: the compiled
+    step aliases the page pools and both state planes' pools to its
+    outputs, holds the scan, the convolution, the write and the attention
+    kernels (the heads of 64 paged in pairs of 128), and nothing else
+    touches the
+    recurrence's pool: no second pool, no layer's slots copied."""
+    from paddle_tpu.inference.serving import ServingEngine
+    from paddle_tpu.models.granite_hybrid import (
+        GraniteHybridConfig, init_granite_hybrid_params)
+    from paddle_tpu.ops.pallas import ragged_ssm_scan as rss
+
+    monkeypatch.setattr(rss, "_tuned_impl", lambda *a, **k: "kernel_h32")
+    cfg = GraniteHybridConfig(
+        layer_types=("mamba",) * 5 + ("attention",) + ("mamba",) * 4,
+        max_seq_len=3072)
+    params = jax.eval_shape(lambda k: init_granite_hybrid_params(cfg, k),
+                            jax.random.PRNGKey(0))
+    engine = ServingEngine(cfg, params=params, max_batch=32, page_size=128,
+                           max_seq=3072, n_pages=64, prefill_budget=640,
+                           prefix_cache=True, qb=16,
+                           class_pages={"state": 2})
+    assert engine.rungs == (160, 640) and engine.n_rows == 40
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        engine.unified_arg_shapes())
+    compiled = engine._unified.lower(*args).compile()
+    text = compiled.as_text()
+    for kernel in ("ragged_ssm_scan", "ragged_causal_conv", "paged_kv_write",
+                   "ragged_paged_attention"):
+        assert kernel in text, kernel
+    st = engine._state
+    pools = sum(a.size * a.dtype.itemsize for a in (
+        engine.k_pages, engine.v_pages, st.k_pages, st.v_pages))
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= pools
+    # no op but the kernel makes anything of the recurrence's pool's
+    # shape (a copy, a fusion over it), and the temporaries are the
+    # rows' activations: under a twentieth of the cell's 5.6 GB pool
+    import re
+
+    L, S = st.v_pages.shape[:2]
+    dims = ",".join(map(str, (L * S,) + st.v_pages.shape[2:]))
+    makers = set(re.findall(rf"f32\[{dims}\]\S* ([a-z-]+)\(", text))
+    assert makers <= {"bitcast", "get-tuple-element", "parameter",
+                      "custom-call"}, makers
+    assert ma.temp_size_in_bytes < 100e6, ma

@@ -152,7 +152,9 @@ PALLAS_KERNEL_MODULES = (
     "paddle_tpu.ops.pallas.lora_matmul",
     "paddle_tpu.ops.pallas.mla_paged_attention",
     "paddle_tpu.ops.pallas.quant_matmul",
+    "paddle_tpu.ops.pallas.ragged_causal_conv",
     "paddle_tpu.ops.pallas.ragged_paged_attention",
+    "paddle_tpu.ops.pallas.ragged_ssm_scan",
 )
 
 # Known findings with rationales, keyed (entry, rule) — the shardcheck
