@@ -64,9 +64,23 @@ The kernel's grid is (nH / hb, C): a step is one row of ``hb`` heads,
 the state block ``[hb / P, N, P * hd]`` steered by the slot ids (scalar
 prefetch); a run of equal ``write`` keeps its output block in VMEM, so a
 request's state crosses HBM once each way a layer a tick however many
-chunks it has.  Which form runs is the autotune's choice among ``"xla"``
-(a ``lax.scan`` over the rows with the pool as its carry: what runs
-where nothing sweeps) and ``"kernel_h<hb>"``, by the static shapes alone.
+chunks it has.
+
+**Where the kernel's operands are made.**  XLA makes what is a value a
+head, at that size and no wider (``_scan_pallas``): ``L``'s running sum,
+``exp(L_last)`` and ``dt_0`` as ``[C * nH]`` scalars (SMEM: all a row of
+one token reads), ``exp(L_j)`` beside ``exp(L_last - L_i) dt_i`` as ``[C *
+qb, 2 * nH]`` (a lane a head: a row of several tokens fetches its block),
+the heads' causal masks ``[C * qb, nH * qb]``, B and C transposed, and the
+rows' first tokens ``x_0``.  The kernel spreads a head's value over the
+head's ``hd`` lanes itself (``_spread``: selects on a lane index, exact)
+and makes ``dt_0 x_0`` and ``exp(L_last - L_i) dt_i x_i`` from ``x``
+there: nothing of the grid's ``[C * qb, nH * hd]`` size is written for
+the kernel to read but ``x``.
+
+Which form runs is the autotune's choice among ``"xla"`` (a ``lax.scan``
+over the rows with the pool as its carry: what runs where nothing sweeps)
+and ``"kernel_h<hb>"``, by the static shapes alone.
 """
 
 from __future__ import annotations
@@ -198,18 +212,20 @@ def _scan_xla(pool, x, dt, A, Bm, Cm, read, write, n_valid, dump):
 # the kernel
 # ---------------------------------------------------------------------------
 
-def _vmem_bytes(hb: int, hd: int, N: int, qb: int, pool_item: int) -> int:
+def _vmem_bytes(hb: int, hd: int, N: int, qb: int, pool_item: int,
+                nH: int) -> int:
     """Double-buffered state blocks in and out, the row's blocks as VMEM
     pads them (a block's last dim to 128 lanes, the one before to 8
     sublanes), and a tile's fp32 temporaries."""
     lanes = lambda n: -(-n // _LANES) * _LANES                # noqa: E731
     W = lanes(hb * hd)
     state = hb * hd * N
-    per_row = (qb * W * (2 + 4 + 4 + 4)             # x, y, exp(L), w x
+    per_row = (qb * W * (2 + 4)                     # x, y
+               + qb * lanes(2 * nH) * 4             # exp(L) | w, a head
                + qb * lanes(hb * qb) * 4            # the heads' masks
-               + 2 * 8 * W * 4 + 2 * N * lanes(qb) * 2 + 2 * qb * lanes(N) * 2)
+               + 8 * W * 4 + 2 * N * lanes(qb) * 2 + 2 * qb * lanes(N) * 2)
     return (4 * state * pool_item + 2 * per_row
-            + 4 * (4 * N * _LANES + 6 * qb * _LANES))
+            + 4 * (4 * N * _LANES + 8 * qb * _LANES))
 
 
 def _tiles_a_group(hb: int, P: int, qb: int) -> int:
@@ -228,22 +244,36 @@ def _supported(pool_shape, hd: int, qb: int, pool_item: int, hb: int) -> bool:
     return (LW % _LANES == 0 and N % 8 == 0 and qb % 8 == 0
             and hb % P == 0 and (nT * P) % hb == 0 and pool_item in (2, 4)
             and hb % hg == 0 and (hg == hb or (hg * qb) % _LANES == 0)
-            and _vmem_bytes(hb, hd, N, qb, pool_item) <= _VMEM_BOUND)
+            and _vmem_bytes(hb, hd, N, qb, pool_item, nT * P) <= _VMEM_BOUND)
 
 
-def _scan_kernel(rd_ref, wr_ref, first_ref, kind_ref, big_ref, x_ref,
-                 el_ref, wx_ref, m_ref, elast_ref, x0_ref, c_ref, bt_ref,
-                 ct_ref, sin_ref, y_ref, so_ref, *, tb, tg, P, hd, qb):
+def _spread(heads, lane, hd: int):
+    """A lane tile's ``P`` values, one a head (scalars, or ``[qb, 1]``),
+    spread over the heads' ``hd`` lanes each: lane ``l`` takes ``heads[l //
+    hd]``, by selects on the lane index ``lane [1, P * hd]`` (exact, no
+    matrix unit)."""
+    out = heads[0]
+    for u in range(1, len(heads)):
+        out = jnp.where(lane >= u * hd, heads[u], out)
+    return out
+
+
+def _scan_kernel(rd_ref, wr_ref, first_ref, kind_ref, big_ref, elast_ref,
+                 dt0_ref, x_ref, ew_ref, m_ref, x0_ref, c_ref, bt_ref,
+                 ct_ref, sin_ref, y_ref, so_ref, *, tb, tg, P, hd, qb, nH):
     """One (head block, row) program over ``tb`` tiles of ``P`` heads.
     ``x_ref``, ``y_ref`` ``[qb, tb * LW]``: the row's tokens as the layers
-    have them; ``el_ref`` ``exp(L_j)`` and ``wx_ref`` ``exp(L_last - L_i)
-    dt_i x_i``, fp32, laid out alike; ``m_ref [qb, tb * P * qb]`` the heads'
-    masks ``(i <= j) exp(L_j - L_i) dt_i`` side by side, ``[j, (head,
-    i)]``, read ``hg`` heads (``tg`` tiles) at a time so that the slice
-    starts on a lane tile; ``elast_ref``, ``x0_ref`` ``[1, tb * LW]`` the
-    row's ``exp(L_last)`` and its first token's ``dt x``, a lane a channel;
-    ``c_ref`` ``[qb, N]``, and ``bt_ref``, ``ct_ref`` ``[N, qb]``: B and C
-    transposed.
+    have them.  What is a value a head reaches the kernel a head and is
+    spread over the head's ``hd`` lanes here (``_spread``): ``elast_ref``,
+    ``dt0_ref`` ``[C * nH]`` in SMEM, a row's ``exp(L_last)`` and its
+    first token's ``dt`` (all a row of one token reads); ``ew_ref [qb, 2 *
+    nH]`` fp32, ``exp(L_j)`` of every head and then ``exp(L_last - L_i)
+    dt_i`` of every head, a lane a head.  ``m_ref [qb, tb * P * qb]`` the
+    heads' masks ``(i <= j) exp(L_j - L_i) dt_i`` side by side, ``[j,
+    (head, i)]``, read ``hg`` heads (``tg`` tiles) at a time so that the
+    slice starts on a lane tile; ``x0_ref [1, tb * LW]`` the row's first
+    token's ``x`` in fp32; ``c_ref`` ``[qb, N]``, and ``bt_ref``,
+    ``ct_ref`` ``[N, qb]``: B and C transposed.
     ``kind_ref[c]``: 0 an idle row, 1 a row of one token (update and
     read-out on the VPU), 2 any other.  The output state block is the
     run's carry: its first row fills it from the input block, the later
@@ -255,6 +285,8 @@ def _scan_kernel(rd_ref, wr_ref, first_ref, kind_ref, big_ref, x_ref,
     LW, hg = P * hd, tg * P
     kind = kind_ref[c]
     fst = first_ref[c] == 1
+    h0 = pl.program_id(0) * (tb * P)           # the step's first head
+    lane = lax.broadcasted_iota(jnp.int32, (1, LW), 1)
 
     def tiles(live, make_body, group=1):
         """``body(g, [S0 of each of the group's tiles])`` (``make_body()``
@@ -284,7 +316,10 @@ def _scan_kernel(rd_ref, wr_ref, first_ref, kind_ref, big_ref, x_ref,
         def body(t, tile):
             S0, = tile                                     # [N, LW]
             lanes = pl.ds(pl.multiple_of(t * LW, LW), LW)
-            S1 = elast_ref[:, lanes] * S0 + b0 * x0_ref[:, lanes]
+            at = c * nH + h0 + t * P
+            e_last, dt0 = (_spread([ref[at + u] for u in range(P)], lane, hd)
+                           for ref in (elast_ref, dt0_ref))
+            S1 = e_last * S0 + b0 * (dt0 * x0_ref[:, lanes])
             so_ref[t] = S1.astype(so_ref.dtype)
             y_ref[0:1, lanes] = jnp.sum(S1 * c0, axis=0, keepdims=True)
 
@@ -297,6 +332,15 @@ def _scan_kernel(rd_ref, wr_ref, first_ref, kind_ref, big_ref, x_ref,
         BT = bt_ref[...].astype(f32)                       # [N, qb]
         # G[j, i] = C_j . B_i
         G = jnp.dot(Cm, BT, precision=_HI, preferred_element_type=f32)
+        ew = ew_ref[...]                                   # [qb, 2 * nH]
+        col = lax.broadcasted_iota(jnp.int32, ew.shape, 1)
+
+        def tile_of(at):
+            """Columns ``at .. at + P`` of ``ew``, a column a head, over
+            the lanes of the heads' tile ``[qb, LW]``."""
+            return _spread([jnp.sum(jnp.where(col == at + u, ew, 0.0),
+                                    axis=1, keepdims=True)
+                            for u in range(P)], lane, hd)
 
         def body(g, group):
             # the group's heads' masks: hg heads of [qb, qb] side by side
@@ -305,8 +349,10 @@ def _scan_kernel(rd_ref, wr_ref, first_ref, kind_ref, big_ref, x_ref,
             for k, S0 in enumerate(group):
                 t = g * tg + k
                 lanes = pl.ds(pl.multiple_of(t * LW, LW), LW)
-                y = el_ref[:, lanes] * jnp.dot(Cm, S0, precision=_HI,
-                                               preferred_element_type=f32)
+                el = tile_of(h0 + t * P)                   # exp(L_j)
+                w = tile_of(nH + h0 + t * P)     # exp(L_last - L_i) dt_i
+                y = el * jnp.dot(Cm, S0, precision=_HI,
+                                 preferred_element_type=f32)
                 x = x_ref[:, lanes].astype(f32)            # [qb, LW]
                 intra = [jnp.dot(
                     G * mg[:, (k * P + u) * qb:(k * P + u + 1) * qb],
@@ -314,8 +360,9 @@ def _scan_kernel(rd_ref, wr_ref, first_ref, kind_ref, big_ref, x_ref,
                     preferred_element_type=f32) for u in range(P)]
                 y_ref[:, lanes] = y + (intra[0] if P == 1
                                        else jnp.concatenate(intra, axis=1))
-                so_ref[t] = (elast_ref[:, lanes] * S0 + jnp.dot(
-                    BT, wx_ref[:, lanes], precision=_HI,
+                # a padding token adds 0 to L: the last place holds L_last
+                so_ref[t] = (el[qb - 1:qb] * S0 + jnp.dot(
+                    BT, w * x, precision=_HI,
                     preferred_element_type=f32)).astype(so_ref.dtype)
 
         return body
@@ -347,21 +394,19 @@ def _scan_pallas(pool, x, dt, A, Bm, Cm, read, write, n_valid, dump, *, hb):
     big = jnp.maximum(lax.cummax(jnp.where(
         kind == 2, jnp.arange(C, dtype=i32), -1)), 0)
     dt, L = _decays(dt, A, n_valid)
-    spread = jnp.repeat(jnp.eye(nH, dtype=f32), hd, axis=1)     # [nH, nH*hd]
-
-    def chan(a):
-        """``[R, nH]`` a value a head -> ``[R, nH * hd]`` a lane a channel:
-        a product with 0/1 (exact to fp32's last bits), which lands
-        lane-dense where a repeat along the lanes is a relayout."""
-        return jnp.dot(a, spread, precision=_HI, preferred_element_type=f32)
 
     def rows(a):
         """``[C, qb, k]`` -> ``[C * qb, k]``."""
         return a.reshape(C * qb, a.shape[2])
 
-    e_last = chan(jnp.exp(L[:, -1]))[:, None]                   # [C, 1, .]
-    wx = chan(rows(jnp.exp(L[:, -1:] - L) * dt)) * x.astype(f32)
-    x0 = wx[::qb][:, None]                                      # [C, 1, .]
+    # a value a head, as it is: the kernel spreads it over the head's
+    # channels.  A row of one token reads two scalars a head ...
+    e_last = jnp.exp(L[:, -1]).reshape(C * nH)
+    dt0 = dt[:, 0].reshape(C * nH)
+    # ... any other exp(L_j) and exp(L_last - L_i) dt_i, a lane a head
+    ew = rows(jnp.concatenate(
+        [jnp.exp(L), jnp.exp(L[:, -1:] - L) * dt], axis=2))     # [., 2 nH]
+    x0 = x[::qb].astype(f32)[:, None]                           # [C, 1, .]
     # the heads' masks, lane-dense: lane (h, i) of row j
     wide = jnp.repeat(jnp.eye(nH, dtype=f32), qb, axis=1)       # [nH, nH*qb]
     Li, dti = (jnp.transpose(a, (0, 2, 1)).reshape(C, 1, nH * qb)
@@ -372,8 +417,11 @@ def _scan_pallas(pool, x, dt, A, Bm, Cm, read, write, n_valid, dump, *, hb):
         causal, Lj.reshape(C, qb, nH * qb) - Li, 0.0)), 0.0) * dti)
     tg = _tiles_a_group(hb, P, qb)
 
-    def _tok(h, c, rd, wr, first, kind, big):
+    def _tok(h, c, rd, wr, first, kind, big, *_):
         return (big[c], h)
+
+    def _heads(h, c, rd, wr, first, kind, big, *_):
+        return (big[c], 0)
 
     def _out(h, c, *_):
         return (c, h)
@@ -394,15 +442,14 @@ def _scan_pallas(pool, x, dt, A, Bm, Cm, read, write, n_valid, dump, *, hb):
         return (wr[c], h, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=5,               # rd, wr, first, kind, big
+        # rd, wr, first, kind, big, exp(L_last), dt_0
+        num_scalar_prefetch=7,
         grid=(nH // hb, C),
         in_specs=[
             pl.BlockSpec((qb, W), _tok),                        # x
-            pl.BlockSpec((qb, W), _tok),                        # exp(L)
-            pl.BlockSpec((qb, W), _tok),                        # w x
+            pl.BlockSpec((qb, 2 * nH), _heads),                 # exp(L) | w
             pl.BlockSpec((qb, hb * qb), _tok),                  # masks
-            pl.BlockSpec((None, 1, W), _own),                   # exp(L_last)
-            pl.BlockSpec((None, 1, W), _own),                   # dt_0 x_0
+            pl.BlockSpec((None, 1, W), _own),                   # x_0
             pl.BlockSpec((qb, N), _all),                        # C
             pl.BlockSpec((None, N, qb), _all3),                 # B^T
             pl.BlockSpec((None, N, qb), _all3),                 # C^T
@@ -412,9 +459,10 @@ def _scan_pallas(pool, x, dt, A, Bm, Cm, read, write, n_valid, dump, *, hb):
                    pl.BlockSpec((None, tb, N, LW), _sout)],
     )
     interpret = _interpret_mode()
-    need = _vmem_bytes(hb, hd, N, qb, pool.dtype.itemsize)
+    need = _vmem_bytes(hb, hd, N, qb, pool.dtype.itemsize, nH)
     y, pool = pl.pallas_call(
-        functools.partial(_scan_kernel, tb=tb, tg=tg, P=P, hd=hd, qb=qb),
+        functools.partial(_scan_kernel, tb=tb, tg=tg, P=P, hd=hd, qb=qb,
+                          nH=nH),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((C * qb, nH * hd), f32),
                    jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
@@ -427,7 +475,7 @@ def _scan_pallas(pool, x, dt, A, Bm, Cm, read, write, n_valid, dump, *, hb):
         interpret=interpret,
         name="ragged_ssm_scan",
     )(rd.astype(i32), wr.astype(i32), first.astype(i32), kind.astype(i32),
-      big, x, chan(rows(jnp.exp(L))), wx, m, e_last, x0, Cm,
+      big, e_last, dt0, x, ew, m, x0, Cm,
       jnp.swapaxes(Bm.reshape(C, qb, N), 1, 2),
       jnp.swapaxes(Cm.reshape(C, qb, N), 1, 2), pool)
     return y, pool
@@ -447,7 +495,7 @@ def _autotune_source() -> str:
 
         _SRC = autotune.source_hash(_runs, _decays, _row_block, _unpack,
                                     _pack, _scan_xla, _tiles_a_group,
-                                    _scan_kernel, _scan_pallas)
+                                    _spread, _scan_kernel, _scan_pallas)
     return _SRC
 
 

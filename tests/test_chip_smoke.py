@@ -94,7 +94,7 @@ def test_latent_leg_tiny():
 
 def test_state_leg_tiny():
     r = chip_smoke.state_leg(chip_smoke.StateLeg(
-        n_rows=9, qb=8, n_heads=8, head_dim=16, d_state=128, n_slots=12,
+        n_rows=9, qb=8, n_heads=8, head_dim=16, d_state=128, n_slots=16,
         conv_dim=256))
     assert set(r["kernel_vs_xla"]) == {
         f"ragged_ssm_scan_kernel_h8_{what}" for what in ("y", "pool")} | {

@@ -257,6 +257,23 @@ def test_hybrid_step_compiled_for_v5e_updates_every_pool_in_place(
     L, S = st.v_pages.shape[:2]
     dims = ",".join(map(str, (L * S,) + st.v_pages.shape[2:]))
     makers = set(re.findall(rf"f32\[{dims}\]\S* ([a-z-]+)\(", text))
-    assert makers <= {"bitcast", "get-tuple-element", "parameter",
-                      "custom-call"}, makers
+    quiet = {"bitcast", "get-tuple-element", "parameter", "custom-call"}
+    assert makers <= quiet, makers
     assert ma.temp_size_in_bytes < 100e6, ma
+    # the same for the conv plane's pool: its states move inside
+    # ragged_causal_conv (no gather's or scatter's fusion over [L * S, K *
+    # Dc]), and the flattening of [L, S, .] is no copy.  This test's pool
+    # of 11 MB XLA keeps in the faster memory space over the layers' loop
+    # and brings back once (an asynchronous copy, no relayout); the
+    # cell's 75 MB it leaves in HBM
+    conv = f"{L * S},{st.k_pages.shape[2]}"
+    assert conv == f"{L * S},13056"
+    makers = set(re.findall(rf"bf16\[{conv}\]\S* ([a-z-]+)\(", text))
+    assert makers and makers <= quiet | {"copy-done"}, makers
+    assert not re.search(rf"scatter[^\n]*bf16\[{conv}\]", text)
+    # what is a value a head reaches the scan a head: no product spreads
+    # it over the grid's 640 places by 4096 channels
+    wide = re.findall(r"f32\[640,4096\]\S* (?:fusion|dot|convolution)\("
+                      r"[^\n]*", text)
+    assert not [op for op in wide if "kind=kOutput" in op
+                or "convolution(" in op or " dot(" in op], wide

@@ -124,6 +124,24 @@ def test_forms_match_the_per_token_loop(grid, impl):
     assert not pool[0].any()
 
 
+@pytest.mark.parametrize("heads", ["2x64", "8x16"])
+def test_heads_of_one_lane_tile_keep_their_own_dt(heads):
+    """Neighbours along a tile's lanes with ``dt`` of 1e-3 and 1e-1 (the
+    cell's two heads of 64, the toys' eight of 16): a spread that took
+    another head's value for a lane would read a decay, and a ``dt x``, a
+    hundred times off."""
+    nH, hd = (int(a) for a in heads.split("x"))
+    case = _case(*GRIDS["mix_of_twelve"], seed=11, nH=nH, hd=hd)
+    case["dt"] = (case["dt"] * 0 + np.where(np.arange(nH) % 2, 1e-1, 1e-3)
+                  ).astype(np.float32)
+    want_y, want_pool = _loop(**case)
+    swapped = dict(case, dt=case["dt"][..., ::-1].copy())
+    assert np.abs(_loop(**swapped)[1] - want_pool).max() > 0.1
+    y, pool = _run(case, f"kernel_h{nH}")
+    assert np.abs((y - want_y)[_held(case)]).max() < TOL
+    assert np.abs(np.delete(pool - want_pool, DUMP, 0)).max() < TOL
+
+
 def test_head_blocks_of_a_larger_model_agree():
     """16 heads in blocks of 8 and of 16: the grid's outer axis."""
     case = _case(*GRIDS["mix_of_nine"], seed=3, nH=16)
