@@ -13,6 +13,8 @@ open-loop convention — queueing delay counts against the scheduler):
   fraction plus the six waste buckets (queue-empty, admission-blocked,
   prefill, overrun, spec-rejected, preempted) sum to 1 by construction,
   so a drop in occupancy always carries its cause.
+  ``occ_waste_prefill_wait`` splits the prefill and preempted buckets:
+  the slot-tokens of prompts the tick gave no row.
 """
 
 from __future__ import annotations
@@ -76,6 +78,10 @@ def _aggregate(requests, st: dict, hits: int, misses: int,
             st["waste_spec_rejected_slot_tokens"] / slot_tok, 3),
         "occ_waste_preempted": round(
             st.get("waste_preempted_slot_tokens", 0) / slot_tok, 3),
+        # of occ_waste_prefill and occ_waste_preempted, the prompts that
+        # waited for a row: a split, not a seventh bucket
+        "occ_waste_prefill_wait": round(
+            st.get("waste_prefill_wait_slot_tokens", 0) / slot_tok, 3),
         "preemption_rate": round(
             st.get("preemptions", 0) / max(1, len(requests)), 3),
         "n_preemptions": st.get("preemptions", 0),

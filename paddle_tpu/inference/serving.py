@@ -53,7 +53,7 @@ import dataclasses
 import hashlib
 import time
 import zlib
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -547,6 +547,21 @@ class _StateSlots:
         return counts
 
 
+class _Tick(NamedTuple):
+    """One tick as ``ServingEngine._schedule_tick`` built it: its rows,
+    what harvest and the bookkeeping need of them, and the operands'
+    host arrays."""
+    snap: list           # (row, slot, req, kind, m, drafts), a busy row
+    decoding: list       # slots given a decode row
+    pref_entry: set      # slots mid-prefill when the tick was built
+    pref_touched: dict   # slot given prefill rows -> its position after
+    fin_slots: set       # slots whose prompt the tick finishes
+    started: list        # slots whose admission gets its first row
+    rung: int            # the step size: places the dense layers compute
+    n_tok: int           # the tokens the tick carries
+    host: dict           # operand -> numpy array(s)
+
+
 class ServingEngine:
     """Continuous-batching serving over paged KV, for any model behind
     the seam (models/seam.py).
@@ -842,9 +857,8 @@ class ServingEngine:
         # could read them
         self._commit_pending: list[dict] = []
         self.stats = {
-            "unified_steps": 0, "decode_steps": 0, "prefills": 0,
-            "prefill_tokens": 0, "prefill_grid_tokens": 0,
-            "prefill_cached_tokens": 0,
+            "unified_steps": 0, "decode_steps": 0,
+            "prefill_tokens": 0, "prefill_cached_tokens": 0,
             "decode_slot_tokens": 0, "decode_active_tokens": 0,
             # slot_occupancy decomposition (all in slot-token units, so
             # active + the six waste buckets == decode_slot_tokens):
@@ -854,6 +868,9 @@ class ServingEngine:
             "waste_overrun_slot_tokens": 0,        # aborted/over-produced
             "waste_spec_rejected_slot_tokens": 0,  # rejected draft tokens
             "waste_preempted_slot_tokens": 0,      # re-prefill after preempt
+            # of the mid-prefill and re-prefill buckets, the slot-tokens
+            # of prompts the tick gave no row (a split, not a bucket)
+            "waste_prefill_wait_slot_tokens": 0,
             "spec_proposed_tokens": 0, "spec_accepted_tokens": 0,
             "preemptions": 0,
             # what the dense layers computed against what the ticks
@@ -901,6 +918,9 @@ class ServingEngine:
                    "preempt_resumed_from_snapshot"], 0))
         # a dispatched tick's share of them, for engine.step's end
         self._class_tick: dict = {}
+        # the tick's slots, those mid-prefill and those of them given no
+        # row, for engine.step's end (_schedule_tick)
+        self._slot_tick: dict = {}
 
     def _require(self, feature: str) -> None:
         """One error for every engine feature the served model's seam
@@ -1463,7 +1483,8 @@ class ServingEngine:
             n_shared = len(shared)
             self.slots[slot] = req
             _obs.lifecycle(req.rid, "admit", engine=self.engine_id,
-                           slot=slot)
+                           slot=slot, prompt_tokens=T,
+                           cached_tokens=n_shared * self.bs)
             self._slot_shared[slot] = shared
             self._slot_owned[slot] = pages
             self._slot_hashes[slot] = hashes
@@ -1664,14 +1685,16 @@ class ServingEngine:
         with _obs.span("engine.dispatch", engine=self.engine_id):
             self._dispatch_unified(now)
         # what this tick put on the device, recorded on engine.step's
-        # end: decode and prefill rows of the grid, requests left waiting
-        # and the step size it ran at against the tokens it carried
+        # end: decode and prefill rows of the grid, requests left waiting,
+        # the step size it ran at against the tokens it carried, and the
+        # slots mid-prefill and how many of them got no row
         _, rows, _, places = (self._inflight if self._inflight is not prev
                               else (None, (), None, 0))
         n_dec = sum(1 for r in rows if r[3] == "dec")
         tick = dict(rows_decode=n_dec, rows_prefill=len(rows) - n_dec,
                     queued=len(self.queue), places=places,
-                    tokens=sum(r[4] for r in rows), **self._class_tick)
+                    tokens=sum(r[4] for r in rows), **self._slot_tick,
+                    **self._class_tick)
         sp.set(**tick)
         # synchronous modes (spec, constrained): drafts and vocab masks
         # are host state derived from the previous step's tokens, so each
@@ -1778,173 +1801,80 @@ class ServingEngine:
 
     def _dispatch_unified(self, now: float = 0.0) -> None:
         """Build and dispatch one unified step for the CURRENT slot
-        state; does not block. Row assignment: every decoding slot gets
-        one row (1 input token + up to spec_k drafts), remaining rows
-        carry qb-token prefill slices (FIFO over admission order), the
-        rest idle against the sink. Charges the occupancy ledger one
-        slot-token per engaged slot (m for a speculative row) — the
-        decode/spec split is classified at harvest."""
-        if self._commit_pending:
-            # deferred adoption commits land HERE, between programs: the
-            # scatter chains after the in-flight step's donated output
-            # and before this dispatch, so the program about to read the
-            # adopted pages sees committed bytes
-            self._flush_commits()
-        C, qb = self.n_rows, self.qb
-        pref_entry = set(self._prefilling)
-        decoding = [s for s in range(self.B) if self.slots[s] is not None
-                    and s not in pref_entry]
-        if self.prefill_only:
-            # pool role: this engine never dispatches a decode row — a
-            # slot past its prefill flip idles until the export sweep
-            # ships its pages and releases it (same step, post-harvest)
-            decoding = []
-        # previous dispatch's token-bearing rows, for in-program chaining
-        prev_rows: dict[int, int] = {}
-        if self._inflight is not None:
-            for idx, s, req, kind, m, _dr in self._inflight[1]:
-                if kind != "mid" and self.slots[s] is req:
-                    prev_rows[s] = idx
-        sched = []                         # (slot, kind, pos0, m, drafts)
-        for s in decoding:
-            req = self.slots[s]
-            pending = 1 if s in prev_rows else 0
-            remaining = req.max_new_tokens - len(req.out_tokens) - pending
-            drafts: list = []
-            if self.spec_k and remaining > 1:
-                hist = req.prompt.tolist() + req.out_tokens
-                drafts = self._proposer.propose(
-                    hist, min(self.spec_k, remaining - 1))
-            sched.append((s, "dec", int(self.seq_lens[s]),
-                          1 + len(drafts), drafts))
-        fin_slots = set()
-        pref_touched: dict[int, int] = {}
-        for slot in list(self._prefilling):
-            if len(sched) >= C:
-                break
-            T = len(self._slot_prompt[slot])   # prompt (+ resumed history)
-            pos = self._prefilling[slot]
-            end = T
-            if self._state is not None and self._cache_on:
-                # a tick's last chunk ends on a page boundary where it
-                # can: the state is snapshotted there (seam.py)
-                hi = min(T, pos + (C - len(sched)) * qb)
-                cut = hi // self.bs * self.bs
-                end = cut if hi % self.bs and cut > pos else hi
-            while pos < end and len(sched) < C:
-                n = min(qb, end - pos)
-                sched.append((slot, "fin" if pos + n >= T else "mid",
-                              pos, n, None))
-                pos += n
-            self._prefilling[slot] = pos
-            pref_touched[slot] = pos
-        if not sched:
+        state; does not block. Two spans on ``engine.dispatch``'s track:
+        ``.schedule`` from entry until the operands' host arrays exist
+        (``_schedule_tick``), ``.launch`` over their uploads and the
+        program call(s); the bookkeeping after them is the dispatch's
+        own. Charges the occupancy ledger one slot-token per engaged
+        slot (m for a speculative row) — the decode/spec split is
+        classified at harvest."""
+        with _obs.span("engine.dispatch.schedule", engine=self.engine_id):
+            if self._commit_pending:
+                # deferred adoption commits land HERE, between programs:
+                # the scatter chains after the in-flight step's donated
+                # output and before this dispatch, so the program about
+                # to read the adopted pages sees committed bytes
+                self._flush_commits()
+            tick = self._schedule_tick()
+        if tick is None:
             return
-        if self._extra:
-            self._grow_classes(sched)
-        stab = self._state_table(sched) if self._state is not None else None
-        tokens = np.zeros((C, qb), np.int32)
-        rs = np.full((C,), self.B, np.int32)       # idle rows -> sink row
-        p0 = np.zeros((C,), np.int32)
-        nv = np.ones((C,), np.int32)
-        tt = np.zeros((C,), np.float32)
-        tp = np.ones((C,), np.float32)
-        tsd = np.zeros((C,), np.int32)
-        cmask = np.zeros((C,), bool)
-        crow = np.zeros((C,), np.int32)
-        if self._lora_on:
-            aidv = np.zeros((C,), np.int32)    # idle rows -> identity slot
-        if self._constr_on:
-            vm = np.ones((C, self.cfg.vocab_size), bool)
-        snap = []
-        n_pf_rows = 0
-        for idx, (s, kind, pos, m, drafts) in enumerate(sched):
-            req = self.slots[s]
-            rs[idx] = s
-            p0[idx] = pos
-            nv[idx] = m
+        host, rung = tick.host, tick.rung
+        with _obs.span("engine.dispatch.launch", engine=self.engine_id):
+            prev_out = self._prev_out_dev
+            if prev_out is None:
+                prev_out = jnp.zeros(
+                    (self.n_rows, self.qb if self.spec_k else 1), jnp.int32)
+            # tpu-lint TPL002 audit: the program below is dispatched
+            # asynchronously while the scheduler keeps mutating its numpy
+            # state — every operand is a fresh array of this tick, but
+            # jnp.array (copying) keeps the handoff alias-free by
+            # construction.
+            extra = []          # multi-tenant varargs, behind the side planes
             if self._lora_on:
-                aidv[idx] = self._slot_aslot[s]
-            if kind == "dec":
-                if s in prev_rows:
-                    cmask[idx] = True
-                    crow[idx] = prev_rows[s]
-                else:
-                    tokens[idx, 0] = self.cur_tok[s]
-                if drafts:
-                    tokens[idx, 1:m] = drafts
-            else:
-                n_pf_rows += 1
-                tokens[idx, :m] = self._slot_prompt[s][pos:pos + m]
-                if kind == "fin":
-                    fin_slots.add(s)
-            if kind != "mid":
-                tt[idx] = req.temperature
-                tp[idx] = req.top_p
-                tsd[idx] = req.seed
-                if self._constr_on and req.constraint is not None:
-                    vm[idx] = req.constraint.mask()
-            snap.append((idx, s, req, kind, m, drafts))
-        ptab = np.concatenate(
-            [self._full_rows, np.zeros((1, self.max_blocks), np.int32)])
-        prev_out = self._prev_out_dev
-        if prev_out is None:
-            prev_out = jnp.zeros((C, qb if self.spec_k else 1), jnp.int32)
-        # tpu-lint TPL002 audit: the program below is dispatched
-        # asynchronously while the scheduler keeps mutating its numpy
-        # state — every operand is a fresh local array here, but
-        # jnp.array (copying) keeps the handoff alias-free by
-        # construction.
-        extra = []              # multi-tenant varargs, behind the side planes
-        if self._lora_on:
-            extra += [jnp.array(aidv), self.adapters.stacks()]
-        if self._constr_on:
-            extra.append(jnp.array(vm))
-        fixed = (jnp.array(tokens), prev_out, jnp.array(cmask),
-                 jnp.array(crow), jnp.array(ptab))
-        # each further class's table, as class 0's: the slots' rows and
-        # the sink's row for idle rows
-        xtabs = [jnp.array(np.concatenate(
-            [x.full_rows, np.zeros((1, self.max_blocks), np.int32)]))
-            for x in self._extra]
-        if stab is not None:
-            xtabs.append(jnp.array(stab))
-        per_row = (jnp.array(p0), jnp.array(nv), jnp.array(tt),
-                   jnp.array(tp), jnp.array(tsd))
+                extra += [jnp.array(host["aidv"]), self.adapters.stacks()]
+            if self._constr_on:
+                extra.append(jnp.array(host["vm"]))
+            fixed = (jnp.array(host["tokens"]), prev_out,
+                     jnp.array(host["cmask"]), jnp.array(host["crow"]),
+                     jnp.array(host["ptab"]))
+            xtabs = [jnp.array(t) for t in host["xtabs"]]
+            per_row = tuple(jnp.array(a) for a in host["per_row"])
 
-        def launch(row_slot, rung):
-            out, self.k_pages, self.v_pages, ys, side, further = (
-                self._unified(
-                    self.params, self.k_pages, self.v_pages, *fixed,
-                    row_slot, *per_row, *self.side_planes.values(),
-                    *(a for x, t in zip(self._further, xtabs)
-                      for a in (x.k_pages, x.v_pages, t)),
-                    *extra, self._places[rung]))
-            self.side_planes = dict(zip(self.side_planes, side))
-            for i, x in enumerate(self._further):
-                x.k_pages, x.v_pages = further[2 * i:2 * i + 2]
-            return out, ys
+            def launch(row_slot, rung):
+                out, self.k_pages, self.v_pages, ys, side, further = (
+                    self._unified(
+                        self.params, self.k_pages, self.v_pages, *fixed,
+                        row_slot, *per_row, *self.side_planes.values(),
+                        *(a for x, t in zip(self._further, xtabs)
+                          for a in (x.k_pages, x.v_pages, t)),
+                        *extra, self._places[rung]))
+                self.side_planes = dict(zip(self.side_planes, side))
+                for i, x in enumerate(self._further):
+                    x.k_pages, x.v_pages = further[2 * i:2 * i + 2]
+                return out, ys
 
-        # the smallest step size that holds the tick's tokens
-        n_tok = sum(m for _s, _k, _p, m, _d in sched)
-        rung = next(r for r in self.rungs if r >= n_tok)
-        if not self._places:
-            # the first dispatch compiles every size, so that no later
-            # tick does: each other size runs once with every row the
-            # sink's, which writes what idle rows write and nothing else
-            self._places = {r: jnp.arange(r, dtype=jnp.int32)
-                            for r in self.rungs}
-            for r in self.rungs:
-                if r != rung:
-                    launch(jnp.full((C,), self.B, jnp.int32), r)
-        out, ys = launch(jnp.array(rs), rung)
+            if not self._places:
+                # the first dispatch compiles every size, so that no later
+                # tick does: each other size runs once with every row the
+                # sink's, which writes what idle rows write and nothing else
+                self._places = {r: jnp.arange(r, dtype=jnp.int32)
+                                for r in self.rungs}
+                for r in self.rungs:
+                    if r != rung:
+                        launch(jnp.full((self.n_rows,), self.B, jnp.int32), r)
+            out, ys = launch(jnp.array(host["rs"]), rung)
+        for s in tick.started:
+            # the tick carries the first row of this admission's prompt
+            _obs.lifecycle(self.slots[s].rid, "prefill-start",
+                           engine=self.engine_id)
         self.stats["token_places"] += rung
-        self.stats["tokens_packed"] += n_tok
+        self.stats["tokens_packed"] += tick.n_tok
+        snap, decoding, pref_entry = tick.snap, tick.decoding, tick.pref_entry
         self._inflight = (out, snap, ys, rung)
         self._prev_out_dev = out
         # post-dispatch bookkeeping: prefix-cache offers for pages this
         # step completed, prefill flips, decode position advance
-        for slot, pos_new in pref_touched.items():
+        for slot, pos_new in tick.pref_touched.items():
             hashes = self._slot_hashes[slot]
             j1 = min(pos_new // self.bs, len(hashes))
             for j in range(self._slot_offered[slot], j1):
@@ -1987,16 +1917,20 @@ class ServingEngine:
         # a resumed (previously preempted) request's mid-prefill slot-
         # tokens are the price of preemption, not of admission latency —
         # charge them to their own bucket (0 with serving_priorities off)
-        mid_slots = [s for s in pref_entry if s not in fin_slots]
+        mid_slots = [s for s in pref_entry if s not in tick.fin_slots]
         n_mid_pre = sum(
             1 for s in mid_slots
             if self.slots[s] is not None and self.slots[s].n_preempted)
         self.stats["waste_preempted_slot_tokens"] += n_mid_pre
         self.stats["waste_prefill_slot_tokens"] += len(mid_slots) - n_mid_pre
+        # of those, the slots the tick gave no row: already charged above,
+        # counted again only here
+        self.stats["waste_prefill_wait_slot_tokens"] += (
+            self._slot_tick["slots_prefill_waiting"])
         n_mid_slots = len(mid_slots)
         self.stats["decode_slot_tokens"] += (
-            sum(m for _s, kind, _p, m, _d in sched if kind == "dec")
-            + len(fin_slots) + n_mid_slots + n_idle)
+            sum(m for _i, _s, _r, kind, m, _d in snap if kind == "dec")
+            + len(tick.fin_slots) + n_mid_slots + n_idle)
         self.stats["unified_steps"] += 1
         if self._further:
             # what live requests hold in each class against the context
@@ -2019,9 +1953,130 @@ class ServingEngine:
             self._class_tick = live
         if decoding:
             self.stats["decode_steps"] += 1
-        if n_pf_rows:
-            self.stats["prefills"] += 1
-            self.stats["prefill_grid_tokens"] += n_pf_rows * qb
+
+    def _schedule_tick(self) -> Optional[_Tick]:
+        """The tick's rows and its operands' host arrays (the span
+        ``engine.dispatch.schedule``). Row assignment: every decoding
+        slot gets one row (1 input token + up to spec_k drafts),
+        remaining rows carry qb-token prefill slices (FIFO over
+        admission order), the rest idle against the sink. Leaves the
+        slot ledger for engine.step's end in ``_slot_tick``; None when
+        no row is busy."""
+        C, qb = self.n_rows, self.qb
+        pref_entry = set(self._prefilling)
+        decoding = [s for s in range(self.B) if self.slots[s] is not None
+                    and s not in pref_entry]
+        if self.prefill_only:
+            # pool role: this engine never dispatches a decode row — a
+            # slot past its prefill flip idles until the export sweep
+            # ships its pages and releases it (same step, post-harvest)
+            decoding = []
+        # previous dispatch's token-bearing rows, for in-program chaining
+        prev_rows: dict[int, int] = {}
+        if self._inflight is not None:
+            for idx, s, req, kind, m, _dr in self._inflight[1]:
+                if kind != "mid" and self.slots[s] is req:
+                    prev_rows[s] = idx
+        sched = []                         # (slot, kind, pos0, m, drafts)
+        for s in decoding:
+            req = self.slots[s]
+            pending = 1 if s in prev_rows else 0
+            remaining = req.max_new_tokens - len(req.out_tokens) - pending
+            drafts: list = []
+            if self.spec_k and remaining > 1:
+                hist = req.prompt.tolist() + req.out_tokens
+                drafts = self._proposer.propose(
+                    hist, min(self.spec_k, remaining - 1))
+            sched.append((s, "dec", int(self.seq_lens[s]),
+                          1 + len(drafts), drafts))
+        fin_slots = set()
+        pref_touched: dict[int, int] = {}
+        started = []
+        for slot in list(self._prefilling):
+            if len(sched) >= C:
+                break
+            T = len(self._slot_prompt[slot])   # prompt (+ resumed history)
+            pos = self._prefilling[slot]
+            if pos == self._slot_nshared[slot] * self.bs:
+                started.append(slot)           # its first row since admit
+            end = T
+            if self._state is not None and self._cache_on:
+                # a tick's last chunk ends on a page boundary where it
+                # can: the state is snapshotted there (seam.py)
+                hi = min(T, pos + (C - len(sched)) * qb)
+                cut = hi // self.bs * self.bs
+                end = cut if hi % self.bs and cut > pos else hi
+            while pos < end and len(sched) < C:
+                n = min(qb, end - pos)
+                sched.append((slot, "fin" if pos + n >= T else "mid",
+                              pos, n, None))
+                pos += n
+            self._prefilling[slot] = pos
+            pref_touched[slot] = pos
+        # every slot the loop reached got a row; those it did not wait
+        self._slot_tick = dict(
+            slots=self.B, slots_prefilling=len(pref_entry),
+            slots_prefill_waiting=len(pref_entry) - len(pref_touched))
+        if not sched:
+            return None
+        if self._extra:
+            self._grow_classes(sched)
+        stab = self._state_table(sched) if self._state is not None else None
+        tokens = np.zeros((C, qb), np.int32)
+        rs = np.full((C,), self.B, np.int32)       # idle rows -> sink row
+        p0 = np.zeros((C,), np.int32)
+        nv = np.ones((C,), np.int32)
+        tt = np.zeros((C,), np.float32)
+        tp = np.ones((C,), np.float32)
+        tsd = np.zeros((C,), np.int32)
+        cmask = np.zeros((C,), bool)
+        crow = np.zeros((C,), np.int32)
+        host = {}
+        if self._lora_on:
+            aidv = host["aidv"] = np.zeros((C,), np.int32)  # idle: identity
+        if self._constr_on:
+            vm = host["vm"] = np.ones((C, self.cfg.vocab_size), bool)
+        snap = []
+        for idx, (s, kind, pos, m, drafts) in enumerate(sched):
+            req = self.slots[s]
+            rs[idx] = s
+            p0[idx] = pos
+            nv[idx] = m
+            if self._lora_on:
+                aidv[idx] = self._slot_aslot[s]
+            if kind == "dec":
+                if s in prev_rows:
+                    cmask[idx] = True
+                    crow[idx] = prev_rows[s]
+                else:
+                    tokens[idx, 0] = self.cur_tok[s]
+                if drafts:
+                    tokens[idx, 1:m] = drafts
+            else:
+                tokens[idx, :m] = self._slot_prompt[s][pos:pos + m]
+                if kind == "fin":
+                    fin_slots.add(s)
+            if kind != "mid":
+                tt[idx] = req.temperature
+                tp[idx] = req.top_p
+                tsd[idx] = req.seed
+                if self._constr_on and req.constraint is not None:
+                    vm[idx] = req.constraint.mask()
+            snap.append((idx, s, req, kind, m, drafts))
+        sink = np.zeros((1, self.max_blocks), np.int32)
+        # each further class's table, as class 0's: the slots' rows and
+        # the sink's row for idle rows
+        xtabs = [np.concatenate([x.full_rows, sink]) for x in self._extra]
+        if stab is not None:
+            xtabs.append(stab)
+        host.update(tokens=tokens, cmask=cmask, crow=crow, rs=rs,
+                    ptab=np.concatenate([self._full_rows, sink]),
+                    xtabs=xtabs, per_row=(p0, nv, tt, tp, tsd))
+        # the smallest step size that holds the tick's tokens
+        n_tok = sum(m for _s, _k, _p, m, _d in sched)
+        rung = next(r for r in self.rungs if r >= n_tok)
+        return _Tick(snap, decoding, pref_entry, pref_touched, fin_slots,
+                     started, rung, n_tok, host)
 
     def _state_table(self, sched: list) -> np.ndarray:
         """The state class's operand for the tick being built: per engine
@@ -2835,9 +2890,11 @@ class ServingEngine:
                 st["spec_accepted_tokens"]
                 / st["spec_proposed_tokens"], 3)
             if st["spec_proposed_tokens"] else 0.0,
+            # token places the ticks computed that carried no token
+            # (the tick is packed: its rows are not padded to qb)
             "prefill_padding_frac": round(
-                1.0 - st["prefill_tokens"]
-                / max(1, st["prefill_grid_tokens"]), 3),
+                1.0 - st["tokens_packed"]
+                / max(1, st["token_places"]), 3),
             "prefix_cache_hit_rate": round(
                 hits / (hits + misses), 3) if hits + misses else 0.0,
             "prefix_cache_hits": hits,

@@ -169,7 +169,8 @@ _LIFECYCLE_PH = {"arrival": "b", "done": "e"}
 def lifecycle(rid: int, event: str, engine=None, **attrs) -> None:
     """One request-lifecycle event: ``arrival`` opens the async flow
     (ph ``b``), ``done`` closes it (ph ``e``), everything between
-    (admit, first-token, preempt, migrate, ship, adopt, ...) is an
+    (admit, prefill-start, first-token, preempt, migrate, ship, adopt,
+    ...) is an
     async instant (ph ``n``) — all sharing ``id=rid`` so Perfetto
     stitches the flow across engine tracks."""
     st = _armed

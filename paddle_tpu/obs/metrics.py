@@ -36,9 +36,7 @@ __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
 SERVING_STATS_SCHEMA = {
     "unified_steps": ("counter", "unified scheduler steps executed"),
     "decode_steps": ("counter", "steps that ran a decode program"),
-    "prefills": ("counter", "steps that ran a prefill grid"),
     "prefill_tokens": ("counter", "prompt tokens prefilled (useful)"),
-    "prefill_grid_tokens": ("counter", "prefill grid slots launched"),
     "prefill_cached_tokens": ("counter",
                               "prompt tokens served from the prefix "
                               "cache instead of the grid"),
@@ -60,6 +58,9 @@ SERVING_STATS_SCHEMA = {
     "waste_preempted_slot_tokens": ("counter",
                                     "slot-tokens re-prefilled after "
                                     "preemption"),
+    "waste_prefill_wait_slot_tokens": ("counter",
+                                       "of the mid-prefill and re-prefill "
+                                       "slot-tokens, those given no row"),
     "spec_proposed_tokens": ("counter", "speculative tokens proposed"),
     "spec_accepted_tokens": ("counter", "speculative tokens accepted"),
     "preemptions": ("counter", "requests preempted for pages"),

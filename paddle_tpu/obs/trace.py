@@ -11,9 +11,9 @@ loadable in Perfetto / ``chrome://tracing``:
   ``ph "X"`` with ``dur`` for a span reported after the fact;
 - instants: ``ph "i"`` (thread-scoped);
 - request lifecycle: async ``ph "b"`` (arrival) / ``"n"`` (admit,
-  first-token, preempt, migrate, ship, adopt) / ``"e"`` (done) events
-  sharing ``cat="req"`` and ``id=<rid>``, stitched fleet-wide across
-  engine tracks;
+  prefill-start, first-token, preempt, migrate, ship, adopt) / ``"e"``
+  (done) events sharing ``cat="req"`` and ``id=<rid>``, stitched
+  fleet-wide across engine tracks;
 - ``ph "M"`` metadata naming the process and one thread track per
   engine (track 0 is the host/fleet track).
 

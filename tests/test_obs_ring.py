@@ -106,7 +106,8 @@ def test_engine_tick_spans_nest_and_lifecycles_are_ordered(ring):
     harvested = 0
     for _, a, b, attrs in steps:
         assert set(attrs) == {"rows_decode", "rows_prefill", "queued",
-                              "places", "tokens"}
+                              "places", "tokens", "slots",
+                              "slots_prefilling", "slots_prefill_waiting"}
         assert attrs["places"] in (0,) + eng.rungs
         assert attrs["tokens"] <= attrs["places"]
         assert len(inside("engine.admit", a, b)) == 1
@@ -123,7 +124,9 @@ def test_engine_tick_spans_nest_and_lifecycles_are_ordered(ring):
     assert sum(s[3]["rows_prefill"] for s in steps) > 0
     assert sum(s[3]["rows_decode"] for s in steps) > 0
     assert steps[-1][3] == {"rows_decode": 0, "rows_prefill": 0,
-                            "queued": 0, "places": 0, "tokens": 0}
+                            "queued": 0, "places": 0, "tokens": 0,
+                            "slots": 2, "slots_prefilling": 0,
+                            "slots_prefill_waiting": 0}
     assert sum(s[3]["tokens"] for s in steps) == eng.stats["tokens_packed"]
 
     at = {}
