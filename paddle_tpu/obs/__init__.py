@@ -154,7 +154,8 @@ def span(name: str, engine=None, **attrs):
     return st.tracer.span(name, tid=_tid(engine), attrs=attrs or None)
 
 
-def instant(name: str, engine=None, **attrs) -> None:
+def instant(name: str, /, engine=None, **attrs) -> None:
+    # ``name`` positional-only, so that an attribute may be called name
     st = _armed
     if st is None:
         return

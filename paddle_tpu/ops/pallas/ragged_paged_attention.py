@@ -51,8 +51,13 @@ Returns o [C, qb, nH, d].  Callers read rows < n_valid (the engine
 samples at offset n_valid - 1, or at every offset when verifying
 speculative drafts).
 
-The kernel's grid is (C, max_blocks / pps): a step is one row's group
-of ``pps`` pages across ALL kv heads.  The pool rides as ``pps`` k and
+The kernel has two grids.  Without a window it is (C, max_blocks / pps):
+step (c, j) is row c's group j of ``pps`` pages across ALL kv heads.
+Under a ``window`` it is (C, n) with n = min(max_blocks / pps,
+ceil((window + qb - 1) / (pps * bs)) + 1), the most groups a row's
+window can reach: step (c, j) is row c's group first(c) + j, first(c)
+the group that holds the first key the row's first query sees, so no
+step lies behind the window (_group).  The pool rides as ``pps`` k and
 ``pps`` v operands of the same two buffers, each with its own
 table-steered block of a whole page ([nKV, d, bs] / [nKV, bs, d], both
 contiguous in the pool).  Inside, three passes over the kv heads: every
@@ -60,13 +65,12 @@ head's scores against the group's live pages, side by side
 [qb*G, pps*bs]; one mask, one running-max update and one rescale of the
 accumulator a head a group; every head's value dots.  A group past the
 chunk's last valid position computes nothing and names the next row's
-first blocks, so it moves no byte of its own and the next row's pages
-arrive early (_steer); a dead page slot inside a live group repeats a
-block the row already named (_slot).  With a window the groups wholly
-behind it are dead the same way: they compute nothing and name the row's
-first live group, whose blocks are then there when it starts.  A decode
-row (n_valid == 1) runs each head's first G query rows alone (padded to
-the sublane tile) with small accumulators of its own.
+first (live) blocks, so it moves no byte of its own and the next row's
+pages arrive early (_steer); a dead page slot inside a live group
+repeats a block the row already named (_slot), and a slot of the first
+live group that lies behind the window names the row's first live page.
+A decode row (n_valid == 1) runs each head's first G query rows alone
+(padded to the sublane tile) with small accumulators of its own.
 
 Which form runs is the autotune's choice among ``"kernel_p4"``,
 ``"kernel_p2"``, ``"kernel_p1"`` (those that divide max_blocks and whose
@@ -175,29 +179,50 @@ def _first_page(pos0, window: int, bs: int):
     return jax.lax.div(jnp.maximum(pos0 - window + 1, 0), bs)
 
 
+def _group(c, j, pos0_ref, pps: int, bs: int, window=None):
+    """The group of row ``c``'s block table that grid step ``j`` of the
+    row stands for: ``j`` itself, or under a ``window`` the row's first
+    live group (the one holding its first visible key) + ``j``, which
+    may lie past the table (a row near the longest context): such a step
+    is dead and _slot clamps its slots."""
+    if window is None:
+        return j
+    return jax.lax.div(_first_page(pos0_ref[c], window, bs), pps) + j
+
+
 def _steer(c, j, pos0_ref, nval_ref, n_rows: int, pps: int, bs: int,
            window=None):
     """(row, group, last live page of that row) whose blocks step (c, j)
-    names.  A live group names its own.  A group past the chunk's last
-    valid position computes nothing, so it names the NEXT row's first
-    group: that row's pages arrive while this row's last live group is
-    still computing (the pipeline fetches a step's blocks during the
-    step before), every further dead step and the next row's first step
-    find the same blocks named and issue no copy.  The last row's dead
-    groups repeat its own last blocks.  So a dead step moves no byte
-    that a live step would not have moved, whatever the table holds.
-    With a ``window`` a row's first LIVE group is the one that holds the
-    first key its first query sees: the groups before it are dead too
-    and name it, and a row ahead is entered at its first live group."""
+    names; the group is absolute, a column of the block table (_group).
+    A live group names its own.  A group past the chunk's last valid
+    position computes nothing, so it names the NEXT row's first group:
+    that row's pages arrive while this row's last live group is still
+    computing (the pipeline fetches a step's blocks during the step
+    before), every further dead step and the next row's first step find
+    the same blocks named and issue no copy.  The last row's dead groups
+    repeat its own last blocks.  So a dead step moves no byte that a
+    live step would not have moved, whatever the table holds.  Under a
+    ``window`` a row's first step is its first LIVE group, the one that
+    holds the first key its first query sees, and a row ahead is
+    entered there."""
     last_page = jax.lax.div(pos0_ref[c] + nval_ref[c] - 1, bs)
-    ahead = jnp.logical_and(j * pps > last_page, c + 1 < n_rows)
+    g = _group(c, j, pos0_ref, pps, bs, window)
+    ahead = jnp.logical_and(g * pps > last_page, c + 1 < n_rows)
     cc = jnp.where(ahead, c + 1, c)
     last_cc = jnp.where(ahead, jax.lax.div(pos0_ref[cc] + nval_ref[cc] - 1,
                                            bs), last_page)
+    return (cc, jnp.where(ahead, _group(cc, 0, pos0_ref, pps, bs, window), g),
+            last_cc)
+
+
+def _grid_steps(mb: int, pps: int, bs: int, qb: int, window=None) -> int:
+    """A row's grid steps: every group of the table, or under a
+    ``window`` the most groups that the ``window + qb - 1`` keys a
+    chunk's queries can see may straddle (one more than they fill)."""
+    n = mb // pps
     if window is None:
-        return cc, jnp.where(ahead, 0, j), last_cc
-    first = jax.lax.div(_first_page(pos0_ref[cc], window, bs), pps)
-    return cc, jnp.where(ahead, first, jnp.maximum(j, first)), last_cc
+        return n
+    return min(n, -(-(window + qb - 1) // (pps * bs)) + 1)
 
 
 def _decode_rows(G: int, rows: int, q_itemsize: int) -> int:
@@ -220,11 +245,14 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
     exp(-1e30 - m) == 0 in fp32, so skipping is exact, not an
     approximation.  A decode row (n_valid == 1) runs on each head's
     first ``rd`` query rows and its own small accumulators.  Under a
-    ``window`` the groups and slots wholly behind the first query's
-    window are skipped as well; a later query row may find a live
-    group's keys all behind ITS window, and what it accumulates from
-    them under the running max's initial -1e30 is wiped by the rescale
-    (alpha == 0) at its first real key, which always comes: its own.
+    ``window`` step j is the table's group ``g`` = the row's first live
+    group + j (_group): no step lies behind the first query's window,
+    and the slots of the first group that do are skipped; a step whose
+    ``g`` runs past the row's last page is dead as above.  A later
+    query row may find a live group's keys all behind ITS window, and
+    what it accumulates from them under the running max's initial -1e30
+    is wiped by the rescale (alpha == 0) at its first real key, which
+    always comes: its own.
 
     ``quant``: int8 pages — two extra scalar-prefetch refs carry the
     flattened [P * nKV] scale planes; the k/v tiles are dequantized in
@@ -239,7 +267,8 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
     (o_ref, m_sc, l_sc, acc_sc, m1_sc, l1_sc, acc1_sc, s_sc,
      p_sc) = refs[1 + 2 * pps:]
     c = pl.program_id(0)
-    j = pl.program_id(1)
+    j = pl.program_id(1)                            # the row's step
+    g = _group(c, j, pos0_ref, pps, bs, window)     # the table's group
     n = nval_ref[c]
     p0 = pos0_ref[c]
     last = p0 + n - 1                               # last valid position
@@ -277,14 +306,14 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
                 if i == 0 and window is None:
                     fn(i)
                 else:
-                    pl.when(live(j * pps + i, j * pps + i + 1))(
+                    pl.when(live(g * pps + i, g * pps + i + 1))(
                         functools.partial(fn, i))
 
         def page(ref, sc_ref, i, h):
             """Head h's tile of live page slot i, dequantized."""
             x = ref[h]
             if quant:   # by the page id the block was steered by
-                pg = rows_ref[c * mb + j * pps + i]
+                pg = rows_ref[c * mb + g * pps + i]
                 x = (x.astype(jnp.float32)
                      * sc_ref[pg * nkv + h]).astype(q_ref.dtype)
             return x
@@ -292,7 +321,7 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
         # the chunk's first page is never skipped (0 <= last always since
         # n_valid >= 1), so every query row keeps >= 1 real key and l
         # never normalizes junk
-        @pl.when(live(j * pps, (j + 1) * pps))
+        @pl.when(live(g * pps, (g + 1) * pps))
         def _pages():
             # three passes, each over every head, so that the MXU runs
             # the heads' independent dots back to back instead of
@@ -309,7 +338,7 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
             shape = (R, pps * bs)
             tok = jax.lax.div(
                 jax.lax.broadcasted_iota(jnp.int32, shape, 0), G)
-            kpos = j * pps * bs + jax.lax.broadcasted_iota(
+            kpos = g * pps * bs + jax.lax.broadcasted_iota(
                 jnp.int32, shape, 1)
             # a dead slot's scores are whatever the scratch held: every
             # key position of it is past ``last``, so the select drops it
@@ -382,9 +411,13 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
     the per-page scale planes as two extra scalar-prefetch operands
     (flattened [P * nKV]) riding next to the block-table rows.  With a
     ``window`` the call carries a name of its own, so that a trace tells
-    the two forms apart."""
+    the two forms apart; under it a row's grid walks only the groups its
+    window can reach (_grid_steps).  Tracing the call leaves one instant
+    ``ragged_paged_attention.grid`` in the ring: which grid it took."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
+
+    from ... import obs as _obs
 
     C, qb, nH, d = q.shape
     nkv = kt_pages.shape[1]
@@ -394,7 +427,11 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
     if mb % pps:
         raise ValueError(f"pages per step {pps} does not divide the block "
                          f"table's {mb} pages")
-    n_steps = mb // pps
+    n_steps = _grid_steps(mb, pps, bs, qb, window)
+    name = ("ragged_paged_attention" if window is None
+            else "ragged_paged_attention_window")
+    _obs.instant("ragged_paged_attention.grid", name=name, rows=C,
+                 steps=n_steps, pps=pps, window=window)
     quant = k_scales is not None
     R = qb * G
     rd = _decode_rows(G, R, q.dtype.itemsize)
@@ -454,8 +491,7 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name=("ragged_paged_attention" if window is None
-              else "ragged_paged_attention_window"),
+        name=name,
     )
     pre = (rows_flat, pos0.astype(jnp.int32), n_valid.astype(jnp.int32))
     if quant:
@@ -523,8 +559,8 @@ def _autotune_source() -> str:
     if _SRC is None:
         from . import autotune
 
-        _SRC = autotune.source_hash(_slot, _first_page, _steer, _decode_rows,
-                                    _rpa_kernel,
+        _SRC = autotune.source_hash(_slot, _first_page, _group, _steer,
+                                    _grid_steps, _decode_rows, _rpa_kernel,
                                     ragged_paged_attention_kernel,
                                     _ragged_paged_xla)
     return _SRC

@@ -370,12 +370,14 @@ def test_default_without_a_sweep_is_the_largest_group(monkeypatch):
                                cands) == want
 
 
-@pytest.mark.parametrize("nH,mb,window", [
-    (32, 24, None), (32, 16, None), (128, 196, None), (128, 196, 4096)])
-def test_committed_table_serves_the_cells_geometry(nH, mb, window):
-    """The Mistral cells (32 heads, mb 24), chip_smoke.py (mb 16) and
-    the command-a-plus cell (128 heads, mb 196: its global layer and,
-    under the window, its window layers) must find their form in the
+@pytest.mark.parametrize("C,nH,nkv,mb,window", [
+    (32, 32, 8, 24, None), (32, 32, 8, 16, None), (32, 128, 8, 196, None),
+    (32, 128, 8, 196, 4096), (40, 32, 4, 24, None)])
+def test_committed_table_serves_the_cells_geometry(C, nH, nkv, mb, window):
+    """The Mistral cells (32 heads, mb 24), chip_smoke.py (mb 16), the
+    command-a-plus cell (128 heads, mb 196: its global layer and, under
+    the window, its window layers) and the granite cell (40 rows, heads
+    of 64 paged in pairs of 128: 32 over 4) must find their form in the
     tracked table under the kernel's CURRENT source hash: a stale entry
     is a clean miss, every run's set-up then sweeps, and the XLA arm's
     temporaries set the run's memory peak (at mb 196 they do not fit
@@ -390,10 +392,10 @@ def test_committed_table_serves_the_cells_geometry(nH, mb, window):
 
     entries = json.load(open(autotune.COMMITTED_PATH))["entries"]
     entry = entries["ragged_paged_attention|TPU v5 lite|"
-                    f"c32_qb16_h{nH}_d128_kv8_mb{mb}_bs128"
+                    f"c{C}_qb16_h{nH}_d128_kv{nkv}_mb{mb}_bs128"
                     + (f"_w{window}" if window else "") + "|bfloat16"]
     assert entry["source"] == _autotune_source()
-    assert entry["config"] in candidates_for((448, 8, 128, 128), nH, 16,
+    assert entry["config"] in candidates_for((448, nkv, 128, 128), nH, 16,
                                              mb)[:-1]
 
 

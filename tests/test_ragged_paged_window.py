@@ -2,7 +2,11 @@
 (interpret mode) against the XLA arm against a dense oracle, for 16 and 4
 query heads a kv head, windows that are and are not multiples of the
 page, a window longer than the context, garbage ids behind the window,
-and the steering of the groups the window leaves dead."""
+rows at the table's end, under the window, idle and straddling a group,
+int8 pages, the windowed grid's extent and its steering, and the instant
+that says which grid a traced call took."""
+
+import math
 
 import numpy as np
 import pytest
@@ -12,6 +16,7 @@ import jax.numpy as jnp
 
 from paddle_tpu.ops.pallas.ragged_paged_attention import (
     _first_page,
+    _grid_steps,
     _ragged_paged_xla,
     _slot,
     _steer,
@@ -67,16 +72,78 @@ def _live_table(rows, pos0, n_valid, window):
     return np.where(live, rows, 0)
 
 
-@pytest.mark.parametrize("window", [128, 256, 200, 77, 1, 640])
-@pytest.mark.parametrize("G,pps", [(16, 1), (16, 2), (4, 2), (4, 4)])
-def test_kernel_xla_and_oracle_agree_under_a_window(G, pps, window):
+def _edge_rows(kind, window, qb=4):
+    """(pos0, n_valid) of one kind of row the windowed grid must get
+    right: windows that end on the table's last page (the walk runs past
+    the table), rows wholly under the window, idle sink rows between
+    live ones, and chunks that straddle a group boundary (of 1, 2 and 4
+    pages)."""
+    last = MB * BS - 1
+    if kind == "table_end":
+        pos0 = [last - qb + 1, last, last - 200, MB * BS - BS, 5, last - 1]
+        n_valid = [qb, 1, qb, 1, qb, 2]
+    elif kind == "under_window":
+        pos0 = [0, 3, min(window - 1, last), max(window - qb, 0),
+                window // 2, 1]
+        n_valid = [1, qb, 1, qb, 2, 1]
+    elif kind == "sink":
+        pos0 = [0, 700, 0, 0, 300, 0, 1021, 0]
+        n_valid = [1, qb, 1, 1, 3, 1, 1, 1]
+    else:                                           # "straddle"
+        pos0 = [BS - 2, 2 * BS - 2, 4 * BS - 3, 6 * BS - 1, 3 * BS - 2,
+                7 * BS - 2]
+        n_valid = [qb, qb, qb, 2, qb, qb]
+    return np.array(pos0, np.int32), np.array(n_valid, np.int32)
+
+
+def _int8_pages(seed, nkv=2):
+    """int8 pages with per-page, per-kv-head scales, and the fp32 pages
+    they stand for (what the oracle and the fp XLA arm read)."""
+    from paddle_tpu.ops.quant import dequantize_int8
+
+    rng = np.random.default_rng(seed)
+    kq = jnp.asarray(rng.integers(-127, 128, size=(P, nkv, D, BS)), jnp.int8)
+    vq = jnp.asarray(rng.integers(-127, 128, size=(P, nkv, BS, D)), jnp.int8)
+    ks = jnp.asarray(rng.uniform(0.005, 0.02, size=(P, nkv)), jnp.float32)
+    vs = jnp.asarray(rng.uniform(0.005, 0.02, size=(P, nkv)), jnp.float32)
+    return (kq, vq, ks, vs, dequantize_int8(kq, ks[:, :, None, None]),
+            dequantize_int8(vq, vs[:, :, None, None]))
+
+
+_AGREE = (
+    [(G, pps, window, "mixed", False)
+     for window in (128, 256, 200, 77, 1, 640)
+     for G, pps in ((16, 1), (16, 2), (4, 2), (4, 4))]
+    + [(4, pps, window, kind, False)
+       for kind in ("table_end", "under_window", "sink", "straddle")
+       for pps in (1, 2, 4) for window in (200, 640)]
+    + [(4, pps, 200, kind, True) for kind in ("mixed", "table_end")
+       for pps in (1, 2, 4)])
+
+
+@pytest.mark.parametrize("G,pps,window,kind,int8", _AGREE)
+def test_kernel_xla_and_oracle_agree_under_a_window(G, pps, window, kind,
+                                                    int8):
     q, kp, vp, rows, pos0, n_valid = _case(G)
+    if kind != "mixed":
+        pos0, n_valid = _edge_rows(kind, window)
+        rng = np.random.default_rng(11)
+        rows = rng.integers(1, P, size=(len(pos0), MB)).astype(np.int32)
+        # idle rows name the sink, as the engine's do
+        rows[(pos0 == 0) & (n_valid == 1)] = 0
+        q = jnp.asarray(rng.normal(size=(len(pos0),) + q.shape[1:]),
+                        jnp.float32)
+    scales = ()
+    if int8:
+        kq, vq, ks, vs, kp, vp = _int8_pages(seed=13)
+        scales = (ks, vs)
     args = (jnp.asarray(pos0), jnp.asarray(n_valid), 0.3)
-    got = ragged_paged_attention_kernel(q, kp, vp, jnp.asarray(rows), *args,
-                                        pps=pps, window=window)
-    xla = _ragged_paged_xla(
-        q, kp, vp, jnp.asarray(_live_table(rows, pos0, n_valid, window)),
-        *args, "d_major", window=window)
+    got = ragged_paged_attention_kernel(
+        q, *((kq, vq) if int8 else (kp, vp)), jnp.asarray(rows), *args,
+        *scales, pps=pps, window=window)
+    live = jnp.asarray(_live_table(rows, pos0, n_valid, window))
+    xla = _ragged_paged_xla(q, *((kq, vq) if int8 else (kp, vp)), live,
+                            *args, "d_major", *scales, window=window)
     ref = _dense(q, kp, vp, rows, pos0, n_valid, 0.3, window)
     # fp32 operands; the oracle is fp64: what is left is the order of
     # the sums (online softmax over groups against one softmax)
@@ -135,38 +202,45 @@ def test_garbage_ids_behind_the_window_are_never_read(pps):
 @pytest.mark.parametrize("window", [128, 200, 384])
 @pytest.mark.parametrize("pps", [1, 2, 4])
 def test_groups_behind_the_window_name_the_first_live_group(pps, window):
-    """A group wholly behind the window computes nothing and names the
-    row's first live group, a row ahead is entered at its first live
-    group, and a slot of that group that lies behind the window names
-    the first live page: over the whole grid no block is copied but a
-    row's live pages (and the dead slots of its first live group)."""
+    """The windowed grid walks ``_grid_steps`` steps a row, the first at
+    the row's first live group: no step lies behind the window.  A step
+    past the row's last page names the next row's first live group (the
+    next row's step 0), the last row's repeat its own blocks, a slot of
+    the first live group that lies behind the window names the first
+    live page, and no slot names a page outside the row's
+    [first page, last page], even where the walk runs past the table.
+    Over the whole grid no block is copied but a row's live pages (and
+    the dead slots of its first live group)."""
+    qb = 4
     pos0 = jnp.asarray([0, 300, 1023, 5, 640, 900], jnp.int32)
     nv = jnp.asarray([1, 4, 1, 1, 3, 4], jnp.int32)
     C = len(pos0)
     last = [int((pos0[c] + nv[c] - 1) // BS) for c in range(C)]
     first = [int(max(int(pos0[c]) - window + 1, 0) // BS) for c in range(C)]
     table = np.arange(1, C * MB + 1).reshape(C, MB)
+    n_steps = _grid_steps(MB, pps, BS, qb, window)
 
     def named(c, j):
-        cc, jj, lp = (int(x) for x in _steer(c, j, pos0, nv, C, pps, BS,
-                                             window))
+        cc, g, lp = (int(x) for x in _steer(c, j, pos0, nv, C, pps, BS,
+                                            window))
         fp = int(_first_page(pos0[cc], window, BS))
-        return cc, jj, [table[cc, max(int(_slot(jj, i, pps, lp)), fp)]
-                        for i in range(pps)]
+        return cc, g, [table[cc, max(int(_slot(g, i, pps, lp)), fp)]
+                       for i in range(pps)]
 
     copies, held = 0, [None] * pps
     for c in range(C):
-        for j in range(MB // pps):
-            cc, jj, blocks = named(c, j)
-            if j * pps > last[c]:                       # past the end
+        # the grid reaches every live group of the row
+        assert last[c] // pps - first[c] // pps < n_steps
+        for j in range(n_steps):
+            cc, g, blocks = named(c, j)
+            if (first[c] // pps + j) * pps > last[c]:   # past the end
                 if c + 1 < C:
-                    assert (cc, jj) == (c + 1, first[c + 1] // pps)
+                    assert (cc, g) == (c + 1, first[c + 1] // pps)
+                    assert blocks == named(c + 1, 0)[2]
                 else:
                     assert cc == c and blocks == held
-            elif (j + 1) * pps * BS <= int(pos0[c]) - window + 1:
-                assert (cc, jj) == (c, first[c] // pps)  # behind
             else:
-                assert (cc, jj) == (c, j)
+                assert (cc, g) == (c, first[c] // pps + j)
             assert all(table[cc, first[cc]] <= b <= table[cc, last[cc]]
                        for b in blocks)
             copies += sum(b != h for b, h in zip(blocks, held))
@@ -179,6 +253,81 @@ def test_groups_behind_the_window_name_the_first_live_group(pps, window):
         dead = (first[c] - g0) + max(0, g0 + pps - 1 - last[c])
         want += last[c] - first[c] + 1 + dead
     assert copies == want
+
+
+def _grids(fn, *args):
+    """The grid of every pallas_call in ``fn``'s traced program."""
+    def walk(jaxpr):
+        for e in jaxpr.eqns:
+            if e.primitive.name == "pallas_call":
+                yield e.params["grid_mapping"].grid
+            for v in e.params.values():
+                for sub in (v if isinstance(v, (list, tuple)) else [v]):
+                    if hasattr(sub, "eqns"):
+                        yield from walk(sub)
+                    elif hasattr(sub, "jaxpr"):
+                        yield from walk(getattr(sub.jaxpr, "jaxpr",
+                                                sub.jaxpr))
+
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+def _shapes(C, qb, mb, nH=4, nkv=2, pages=24):
+    S = jax.ShapeDtypeStruct
+    return (S((C, qb, nH, D), jnp.bfloat16),
+            S((pages, nkv, D, BS), jnp.bfloat16),
+            S((pages, nkv, BS, D), jnp.bfloat16), S((C, mb), jnp.int32),
+            S((C,), jnp.int32), S((C,), jnp.int32))
+
+
+@pytest.mark.parametrize("window,qb,pps,mb", [
+    (4096, 16, 2, 196),         # the command-a-plus cell: 18 against 98
+    (4096, 16, 1, 196),
+    (4096, 16, 4, 196),
+    (200, 4, 2, 8),
+    (128, 4, 4, 8),
+    (256, 16, 1, 24),
+    (77, 1, 1, 8),
+    (1, 1, 2, 8),
+    (640, 16, 2, 24),
+    (2000, 4, 1, 8),            # longer than the table: every group
+    (MB * BS, 16, 4, 8),
+])
+def test_the_windowed_grid_has_the_windows_steps(window, qb, pps, mb):
+    """Under a window a row walks the most groups its window + qb - 1
+    keys can straddle, never more than the table has; without one,
+    every group of the table (the parent's grid, to the step)."""
+    a = _shapes(2, qb, mb)
+    want = min(mb // pps, math.ceil((window + qb - 1) / (pps * BS)) + 1)
+    assert _grids(lambda *x: ragged_paged_attention_kernel(
+        *x, 0.3, pps=pps, window=window), *a) == [(2, want)]
+    assert _grids(lambda *x: ragged_paged_attention_kernel(
+        *x, 0.3, pps=pps), *a) == [(2, mb // pps)]
+
+
+def test_tracing_leaves_one_grid_instant_a_form():
+    """Tracing the kernel leaves one ``ragged_paged_attention.grid``
+    instant in the ring, with the call's name and its steps a row: 18
+    for the window layers of the command-a-plus cell, 98 for its global
+    layer (shapes only)."""
+    from paddle_tpu import obs
+
+    a = _shapes(32, 16, 196, nH=128, nkv=8, pages=1024)
+    ring = obs.arm().tracer
+    try:
+        ragged_paged_attention_kernel.clear_cache()   # trace anew
+        for window in (4096, None):
+            jax.make_jaxpr(lambda *x: ragged_paged_attention_kernel(
+                *x, 0.088, pps=2, window=window))(*a)
+        got = [e["args"] for e in ring.snapshot()[0]
+               if e["name"] == "ragged_paged_attention.grid"]
+    finally:
+        obs.arm()
+    assert got == [
+        {"name": "ragged_paged_attention_window", "rows": 32, "steps": 18,
+         "pps": 2, "window": 4096},
+        {"name": "ragged_paged_attention", "rows": 32, "steps": 98,
+         "pps": 2, "window": None}]
 
 
 def test_the_windowed_call_has_a_name_of_its_own():
