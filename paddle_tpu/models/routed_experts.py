@@ -10,10 +10,10 @@ router keeps its full width, the weights are normalised over all
 chosen, and the sum runs over the chosen experts that are *held*
 (``Routing.held = (first, count)``); the shared experts, their matrices
 side by side as one SwiGLU of their summed width (which is their sum),
-are always computed.  On one chip of an expert-parallel deployment this
-is the chip's part of the layer, without the exchange.  What differs
-between families is data: ``Routing`` and whether ``lp`` has a
-``router_bias``.
+are always computed, in a family that has them.  On one chip of an
+expert-parallel deployment this is the chip's part of the layer, without
+the exchange.  What differs between families is data: ``Routing`` and
+whether ``lp`` has a ``router_bias`` and the shared experts' ``ws_*``.
 """
 
 from __future__ import annotations
@@ -87,7 +87,8 @@ def _gate_up_arm(n_places: int, we, r: Routing) -> str:
 
 def moe_ffn(h, lp, r: Routing, valid=None, stack=None):
     """The expert layer's feed-forward on normed ``h [N, H]``: this
-    chip's part (module docstring).  ``valid [N]`` marks the places that
+    chip's part (module docstring; the shared experts where ``lp`` has
+    ``ws_gate``).  ``valid [N]`` marks the places that
     hold a token; padding is routed nowhere.  Returns ``(y [N, H], tokens
     per held expert [count] int32)``.
 
@@ -129,6 +130,8 @@ def moe_ffn(h, lp, r: Routing, valid=None, stack=None):
             jnp.arange(N * k, dtype=jnp.int32))
         rows = out[back].reshape(N, k, H).astype(f32)
         y = jnp.where(held[..., None], rows * w[..., None], 0.0).sum(1)
+    if "ws_gate" not in lp:                  # a family without shared experts
+        return y.astype(r.dtype), sizes
     with jax.named_scope("layer/shared_expert"):
         y = y.astype(r.dtype)
         shared = swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"],

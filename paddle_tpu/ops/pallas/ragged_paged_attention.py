@@ -47,7 +47,17 @@ window of the chunk's FIRST query are never read by the kernel and may
 hold any id, as future slots may (the XLA arm gathers them, so there
 they must name pages of the pool: the engine keeps the sink there).
 
-Returns o [C, qb, nH, d].  Callers read rows < n_valid (the engine
+Two widths: q and the k pages may be ``dk`` wide and the v pages ``dv``
+(k_pages [P, nKV, dk, bs], v_pages [P, nKV, bs, dv], q [C, qb, nH, dk]);
+the output is then [C, qb, nH, dv].  Pages hold their widths exactly.
+
+``sinks`` (None or [nH] fp32) adds a learned logit a query head with no
+value behind it: the head's softmax runs over its keys and the sink,
+``exp(s_j - m) / (exp(sink - m) + sum exp(s_j' - m))``.  Both arms start
+every row's online softmax at ``m = sink``, ``l = 1``, ``acc = 0``,
+which is that formula exactly; padding rows are still zeros.
+
+Returns o [C, qb, nH, dv].  Callers read rows < n_valid (the engine
 samples at offset n_valid - 1, or at every offset when verifying
 speculative drafts).
 
@@ -70,7 +80,10 @@ pages arrive early (_steer); a dead page slot inside a live group
 repeats a block the row already named (_slot), and a slot of the first
 live group that lies behind the window names the row's first live page.
 A decode row (n_valid == 1) runs each head's first G query rows alone
-(padded to the sublane tile) with small accumulators of its own.
+(padded to the sublane tile) with small accumulators of its own.  A call
+whose widths differ or that has sinks is named
+``ragged_paged_attention_split`` (``..._split_window`` under a window),
+so that a trace tells those forms from the one-width forms.
 
 Which form runs is the autotune's choice among ``"kernel_p4"``,
 ``"kernel_p2"``, ``"kernel_p1"`` (those that divide max_blocks and whose
@@ -104,23 +117,34 @@ _PPS = (4, 2, 1)                    # pages a grid step, largest first
 
 
 def _vmem_bytes(nkv: int, rows: int, d: int, bs: int, itemsize: int,
-                pps: int) -> int:
+                pps: int, dv=None) -> int:
     """The kernel's VMEM working set at ``pps`` pages a step: the
     double-buffered k and v pages (all kv heads), the double-buffered q
     and o blocks, the fp32 accumulator, the lane-replicated softmax
     state, every head's fp32 scores and probabilities of the group (the
     probabilities counted at four bytes, the widest q), and one head's
-    score tile with its mask and its exp in flight."""
-    return (2 * pps * 2 * nkv * d * bs * itemsize
-            + 2 * 2 * nkv * rows * d * itemsize
-            + nkv * rows * d * 4
+    score tile with its mask and its exp in flight.  ``d`` is the width
+    of q and k, ``dv`` that of v and o (``d`` where None)."""
+    dv = d if dv is None else dv
+    return (2 * pps * nkv * (d + dv) * bs * itemsize
+            + 2 * nkv * rows * (d + dv) * itemsize
+            + nkv * rows * dv * 4
             + 2 * nkv * rows * 128 * 4
             + nkv * rows * pps * bs * (4 + 4)
             + 3 * rows * pps * bs * 4)
 
 
+def _widths_ok(d: int, dv) -> bool:
+    """Head widths the kernel runs: 128 or 256 for q, k and v alike, or
+    q and k 128, 192 or 256 wide beside values of 128 or 256."""
+    if dv is None or dv == d:
+        return d in (128, 256)
+    return d in (128, 192, 256) and dv in (128, 256)
+
+
 def ragged_paged_supported(kt_pages_shape, n_q_heads: int, qb: int,
-                           itemsize: int = 2, pps: int = 1) -> bool:
+                           itemsize: int = 2, pps: int = 1,
+                           dv=None) -> bool:
     """Gate for the MXU unified-RPA kernel at ``pps`` pages a grid step:
     d-major pages with MXU-tileable blocks — a head's score dot is
     [qb*G, d] x [d, bs] a page and its value dot [qb*G, bs] x [bs, d] —
@@ -132,21 +156,23 @@ def ragged_paged_supported(kt_pages_shape, n_q_heads: int, qb: int,
     k rows one above the other, its v columns side by side) and sends
     query heads 128 wide with zeros under the pair's other head
     (models/granite_hybrid.py), which is the kernel at 128 with exact
-    scores; any other width (a toy's 16) takes the XLA arm, as does a
-    page size that is no multiple of 128."""
+    scores.  Keys wider than values (``dv``, the v pages' width) run at
+    q and k 192 beside v 128 (models/mimo_v2.py), _widths_ok; any other
+    width (a toy's 16) takes the XLA arm, as does a page size that is no
+    multiple of 128."""
     _, nkv, d, bs = kt_pages_shape
     if n_q_heads % nkv:
         return False
     G = n_q_heads // nkv
     if (qb * G) % 8:                                # sublane-tileable rows
         return False
-    if _vmem_bytes(nkv, qb * G, d, bs, itemsize, pps) > _VMEM_BOUND:
+    if _vmem_bytes(nkv, qb * G, d, bs, itemsize, pps, dv) > _VMEM_BOUND:
         return False
-    return d in (128, 256) and bs % 128 == 0
+    return _widths_ok(d, dv) and bs % 128 == 0
 
 
 def candidates_for(kt_pages_shape, n_q_heads: int, qb: int, mb: int,
-                   itemsize: int = 2) -> list:
+                   itemsize: int = 2, dv=None) -> list:
     """The forms this geometry may run, in the order the autotune takes
     them: ``"kernel_p<n>"`` for each n of 4, 2, 1 pages a grid step that
     divides the block table's length and fits VMEM, largest first (more
@@ -154,7 +180,7 @@ def candidates_for(kt_pages_shape, n_q_heads: int, qb: int, mb: int,
     the XLA gather path.  Only ``["xla"]`` where no form fits."""
     return [f"kernel_p{n}" for n in _PPS
             if mb % n == 0 and ragged_paged_supported(
-                kt_pages_shape, n_q_heads, qb, itemsize, n)] + ["xla"]
+                kt_pages_shape, n_q_heads, qb, itemsize, n, dv)] + ["xla"]
 
 
 def _slot(j, i: int, pps: int, last_page):
@@ -233,7 +259,7 @@ def _decode_rows(G: int, rows: int, q_itemsize: int) -> int:
 
 
 def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
-                pps, sm_scale, quant, mb, nkv, rd, window=None):
+                pps, sm_scale, quant, mb, nkv, rd, window=None, sink=False):
     """One (chunk, page-group) program: every kv head's qb*G query rows
     (row r = query token r//G, group head r%G) against ``pps``
     table-selected pages at once — a head's scores of the group's live
@@ -257,12 +283,19 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
     ``quant``: int8 pages — two extra scalar-prefetch refs carry the
     flattened [P * nKV] scale planes; the k/v tiles are dequantized in
     VMEM (fp32 multiply, cast to the q dtype) before the dots, the same
-    op order as the XLA arm."""
+    op order as the XLA arm.
+
+    ``sink``: one more scalar-prefetch ref carries the [nH] sink logits;
+    each head's softmax state starts at ``m = sink``, ``l = 1`` (row r
+    of kv head h is query head h * G + r % G), and a key that the mask
+    hides then adds exp(-1e30 - sink) == 0."""
     import jax.experimental.pallas as pl
 
-    ksc_ref = vsc_ref = None
+    ksc_ref = vsc_ref = sk_ref = None
     if quant:
         ksc_ref, vsc_ref, *refs = refs
+    if sink:
+        sk_ref, *refs = refs
     q_ref, k_refs, v_refs = refs[0], refs[1:1 + pps], refs[1 + pps:1 + 2 * pps]
     (o_ref, m_sc, l_sc, acc_sc, m1_sc, l1_sc, acc1_sc, s_sc,
      p_sc) = refs[1 + 2 * pps:]
@@ -293,8 +326,21 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
 
         @pl.when(j == 0)
         def _init():
-            m_ref[...] = jnp.full_like(m_ref[...], -1e30)
-            l_ref[...] = jnp.zeros_like(l_ref[...])
+            if sink:
+                group = jax.lax.rem(jax.lax.broadcasted_iota(
+                    jnp.int32, m_ref.shape[1:], 0), G)
+
+                def start(h):
+                    m = jnp.zeros(m_ref.shape[1:], jnp.float32)
+                    for g in range(G):
+                        m = jnp.where(group == g, sk_ref[h * G + g], m)
+                    m_ref[h] = m
+
+                heads(start)
+                l_ref[...] = jnp.ones_like(l_ref[...])
+            else:
+                m_ref[...] = jnp.full_like(m_ref[...], -1e30)
+                l_ref[...] = jnp.zeros_like(l_ref[...])
             acc_ref[...] = jnp.zeros_like(acc_ref[...])
 
         def live_pages(fn):
@@ -398,11 +444,18 @@ def _rpa_kernel(rows_ref, pos0_ref, nval_ref, *refs, qb, bs, G, n_steps,
         tier(qb * G, m_sc, l_sc, acc_sc)
 
 
+def _name(split: bool, window) -> str:
+    """The call's name in a trace: one form for each of (one width and
+    no sinks, or not) x (no window, or a window)."""
+    return ("ragged_paged_attention" + ("_split" if split else "")
+            + ("" if window is None else "_window"))
+
+
 @functools.partial(jax.jit, static_argnames=("sm_scale", "pps", "window"))
 def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
                                   n_valid, sm_scale: float,
                                   k_scales=None, v_scales=None,
-                                  pps: int = 1, window=None):
+                                  pps: int = 1, window=None, sinks=None):
     """MXU unified-RPA kernel (d-major k pages).  See module docstring
     for the contract; gate with ragged_paged_supported().  ``pps`` pages
     a grid step, a divisor of max_blocks: the pool rides as ``pps`` k
@@ -412,8 +465,12 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
     (flattened [P * nKV]) riding next to the block-table rows.  With a
     ``window`` the call carries a name of its own, so that a trace tells
     the two forms apart; under it a row's grid walks only the groups its
-    window can reach (_grid_steps).  Tracing the call leaves one instant
-    ``ragged_paged_attention.grid`` in the ring: which grid it took."""
+    window can reach (_grid_steps).  Values narrower than keys or
+    ``sinks`` name the call ``..._split`` (_name); the sinks ride the
+    scalar-prefetch path behind the rest.  Tracing the call leaves one
+    instant ``ragged_paged_attention.grid`` in the ring: which grid it
+    took (and for a ``_split`` form, its widths and whether it has
+    sinks)."""
     import jax.experimental.pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -424,14 +481,16 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
     G = nH // nkv
     mb = rows.shape[1]
     bs = kt_pages.shape[3]
+    dv = v_pages.shape[3]
     if mb % pps:
         raise ValueError(f"pages per step {pps} does not divide the block "
                          f"table's {mb} pages")
     n_steps = _grid_steps(mb, pps, bs, qb, window)
-    name = ("ragged_paged_attention" if window is None
-            else "ragged_paged_attention_window")
-    _obs.instant("ragged_paged_attention.grid", name=name, rows=C,
-                 steps=n_steps, pps=pps, window=window)
+    sink = sinks is not None
+    split = dv != d or sink
+    _obs.instant("ragged_paged_attention.grid", name=_name(split, window),
+                 rows=C, steps=n_steps, pps=pps, window=window,
+                 **(dict(dk=d, dv=dv, sink=sink) if split else {}))
     quant = k_scales is not None
     R = qb * G
     rd = _decode_rows(G, R, q.dtype.itemsize)
@@ -464,20 +523,21 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
     f32 = jnp.float32
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # rows_flat, pos0, n_valid (+ k/v scale planes when quantized)
-        num_scalar_prefetch=5 if quant else 3,
+        # (+ the sinks)
+        num_scalar_prefetch=3 + 2 * quant + sink,
         grid=(C, n_steps),
         in_specs=([pl.BlockSpec((None, nkv, R, d), _qmap)]
                   + [pl.BlockSpec((None, nkv, d, bs), _pmap(i))
                      for i in range(pps)]
-                  + [pl.BlockSpec((None, nkv, bs, d), _pmap(i))
+                  + [pl.BlockSpec((None, nkv, bs, dv), _pmap(i))
                      for i in range(pps)]),
-        out_specs=pl.BlockSpec((None, nkv, R, d), _omap),
+        out_specs=pl.BlockSpec((None, nkv, R, dv), _omap),
         scratch_shapes=[pltpu.VMEM((nkv, R, 128), f32),
                         pltpu.VMEM((nkv, R, 128), f32),
-                        pltpu.VMEM((nkv, R, d), f32),
+                        pltpu.VMEM((nkv, R, dv), f32),
                         pltpu.VMEM((nkv, rd, 128), f32),
                         pltpu.VMEM((nkv, rd, 128), f32),
-                        pltpu.VMEM((nkv, rd, d), f32),
+                        pltpu.VMEM((nkv, rd, dv), f32),
                         pltpu.VMEM((nkv, R, pps * bs), f32),
                         pltpu.VMEM((nkv, R, pps * bs), q.dtype)],
     )
@@ -485,37 +545,42 @@ def ragged_paged_attention_kernel(q, kt_pages, v_pages, rows, pos0,
     call = pl.pallas_call(
         functools.partial(_rpa_kernel, qb=qb, bs=bs, G=G, n_steps=n_steps,
                           pps=pps, sm_scale=sm_scale, quant=quant, mb=mb,
-                          nkv=nkv, rd=rd, window=window),
+                          nkv=nkv, rd=rd, window=window, sink=sink),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((C, nkv, R, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((C, nkv, R, dv), q.dtype),
         compiler_params=None if interpret else pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-        name=name,
+        name=_name(split, window),
     )
     pre = (rows_flat, pos0.astype(jnp.int32), n_valid.astype(jnp.int32))
     if quant:
         pre = pre + (k_scales.reshape(-1).astype(jnp.float32),
                      v_scales.reshape(-1).astype(jnp.float32))
+    if sink:
+        pre = pre + (sinks.astype(jnp.float32),)
     out = call(*pre, qg, *([kt_pages] * pps), *([v_pages] * pps))
-    return out.reshape(C, nkv, qb, G, d).transpose(0, 2, 1, 3, 4).reshape(
-        C, qb, nH, d)
+    return out.reshape(C, nkv, qb, G, dv).transpose(0, 2, 1, 3, 4).reshape(
+        C, qb, nH, dv)
 
 
 def _ragged_paged_xla(q, k_pages, v_pages, rows, pos0, n_valid, sm_scale,
-                      k_layout, k_scales=None, v_scales=None, window=None):
+                      k_layout, k_scales=None, v_scales=None, window=None,
+                      sinks=None):
     """XLA gather fallback (and the kernel's numerics reference): gather
     each chunk's pages, one masked softmax over the flattened context.
     The same mask as the kernel, and the same zeros in padding rows.
     int8 pages gather their per-page scales alongside and dequantize
     exactly as the kernel does (fp32 multiply, cast to the q dtype, then
-    the dots)."""
+    the dots).  Values may be narrower than keys; ``sinks`` [nH] join
+    each head's max and sum as the kernel's start does."""
     from ..quant import dequantize_int8
 
     C, qb, nH, d = q.shape
     nkv = k_pages.shape[1]
     G = nH // nkv
     mb = rows.shape[1]
+    dv = v_pages.shape[3]
     bs = k_pages.shape[3] if k_layout == "d_major" else k_pages.shape[2]
     kg = jnp.take(k_pages, rows, axis=0)            # [C, mb, nkv, ., .]
     if k_scales is not None:
@@ -528,7 +593,7 @@ def _ragged_paged_xla(q, k_pages, v_pages, rows, pos0, n_valid, sm_scale,
         vg = dequantize_int8(
             vg, jnp.take(v_scales, rows, axis=0)[..., None, None], q.dtype)
     kg = jnp.swapaxes(kg, 1, 2).reshape(C, nkv, mb * bs, d)
-    vg = jnp.swapaxes(vg, 1, 2).reshape(C, nkv, mb * bs, d)
+    vg = jnp.swapaxes(vg, 1, 2).reshape(C, nkv, mb * bs, dv)
     qg = q.reshape(C, qb, nkv, G, d)
     s = jnp.einsum("cqhgd,chsd->chgqs", qg, kg,
                    preferred_element_type=jnp.float32) * sm_scale
@@ -543,11 +608,17 @@ def _ragged_paged_xla(q, k_pages, v_pages, rows, pos0, n_valid, sm_scale,
     # max-subtracted exp/sum (not jax.nn.softmax) to mirror the kernel's
     # online-softmax epilogue: acc / max(l, 1e-30)
     m = jnp.max(s, axis=-1, keepdims=True)
+    if sinks is not None:
+        sk = sinks.astype(jnp.float32).reshape(1, nkv, G, 1, 1)
+        m = jnp.maximum(m, sk)
     p = jnp.exp(s - m)
-    l = jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-30)
+    l = jnp.sum(p, axis=-1, keepdims=True)
+    if sinks is not None:
+        l = l + jnp.exp(sk - m)
+    l = jnp.maximum(l, 1e-30)
     o = jnp.einsum("chgqs,chsd->cqhgd", (p / l).astype(vg.dtype), vg)
     valid = off[None, :] < n_valid[:, None]
-    return jnp.where(valid[:, :, None, None], o.reshape(C, qb, nH, d),
+    return jnp.where(valid[:, :, None, None], o.reshape(C, qb, nH, dv),
                      0.0).astype(q.dtype)
 
 
@@ -561,14 +632,15 @@ def _autotune_source() -> str:
 
         _SRC = autotune.source_hash(_slot, _first_page, _group, _steer,
                                     _grid_steps, _decode_rows, _rpa_kernel,
-                                    ragged_paged_attention_kernel,
+                                    _name, ragged_paged_attention_kernel,
                                     _ragged_paged_xla)
     return _SRC
 
 
 def _tuned_impl(C: int, qb: int, nH: int, d: int, nkv: int, mb: int,
                 bs: int, dtype, candidates: list,
-                quant: bool = False, window=None) -> str:
+                quant: bool = False, window=None, dv=None,
+                sink: bool = False) -> str:
     """Impl choice via the autotune registry: the kernel at 4, 2 or 1
     pages a grid step (what ``candidates_for`` admits, the largest
     first, so a backend that never sweeps runs the largest group) or the
@@ -578,35 +650,43 @@ def _tuned_impl(C: int, qb: int, nH: int, d: int, nkv: int, mb: int,
     sweeps.  Quantized pages tune their own bucket — dequant shifts the
     arms' cost balance (the kernel dequantizes per VMEM tile, the XLA
     arm on the full gathered context), and so does a window, which
-    skips most of a long context's groups."""
+    skips most of a long context's groups.  Values narrower than keys
+    (``dv``) and sinks name buckets of their own too."""
     from . import autotune
+
+    dv = d if dv is None else dv
 
     def measure(impl):
         pdt = jnp.int8 if quant else dtype
         qz = jnp.zeros((C, qb, nH, d), dtype)
         ktz = jnp.zeros((mb + 1, nkv, d, bs), pdt)
-        vz = jnp.zeros((mb + 1, nkv, bs, d), pdt)
+        vz = jnp.zeros((mb + 1, nkv, bs, dv), pdt)
         rz = jnp.tile(jnp.arange(1, mb + 1, dtype=jnp.int32), (C, 1))
         pz = jnp.full((C,), mb * bs // 2, jnp.int32)
         nz = jnp.where(jnp.arange(C) % 2 == 0, 1, qb).astype(jnp.int32)
         sc = jnp.ones((mb + 1, nkv), jnp.float32) if quant else None
+        sk = jnp.zeros((nH,), jnp.float32) if sink else None
         if impl == "xla":
             arm = lambda x: _ragged_paged_xla(x, ktz, vz, rz, pz, nz,  # noqa: E731
                                               1.0, "d_major", sc, sc,
-                                              window)
+                                              window, sk)
         else:
             arm = lambda x: ragged_paged_attention_kernel(  # noqa: E731
                 x, ktz, vz, rz, pz, nz, 1.0, sc, sc,
-                pps=int(impl.split("_p")[1]), window=window)
+                pps=int(impl.split("_p")[1]), window=window, sinks=sk)
         # eight calls chained in one program: one call alone is under
-        # the host's dispatch floor, where every form reads alike
+        # the host's dispatch floor, where every form reads alike (an
+        # output narrower than q is fed back as its first columns)
+        step = arm if dv == d else (
+            lambda y: y.at[..., :dv].set(arm(y)))  # noqa: E731
         chain = jax.jit(lambda x: jax.lax.fori_loop(
-            0, 8, lambda _, y: arm(y), x))
+            0, 8, lambda _, y: step(y), x))
         return autotune.time_candidate(lambda: chain(qz)) / 8
 
     return str(autotune.tuned(
         "ragged_paged_attention",
         f"c{C}_qb{qb}_h{nH}_d{d}_kv{nkv}_mb{mb}_bs{bs}"
+        + (f"_dv{dv}" if dv != d else "") + ("_sink" if sink else "")
         + ("_q8" if quant else "") + (f"_w{window}" if window else ""),
         str(jnp.dtype(dtype)), candidates,
         measure=measure, source=_autotune_source()))
@@ -614,26 +694,35 @@ def _tuned_impl(C: int, qb: int, nH: int, d: int, nkv: int, mb: int,
 
 def ragged_paged_attention(q, k_pages, v_pages, rows, pos0, n_valid,
                            sm_scale: float, k_layout: str = "d_major",
-                           k_scales=None, v_scales=None, window=None):
+                           k_scales=None, v_scales=None, window=None,
+                           sinks=None):
     """Unified ragged-paged attention: dispatches the MXU Pallas kernel
     when the page geometry supports it, else the XLA gather path.  See
     module docstring for shapes; int8 pages require both scale planes;
-    ``window`` (static) is the sliding window in tokens, None for all."""
+    ``window`` (static) is the sliding window in tokens, None for all;
+    ``sinks`` [nH] fp32 the heads' sink logits, None for none."""
     quant = k_pages.dtype == jnp.int8
     if quant and (k_scales is None or v_scales is None):
         raise ValueError("int8 KV pages need k_scales and v_scales "
                          "([P, nKV] fp32 per-page scale planes)")
     C, qb, nH, d = q.shape
     mb = rows.shape[1]
+    dv = v_pages.shape[3]
     cands = ["xla"] if k_layout != "d_major" else candidates_for(
-        k_pages.shape, nH, qb, mb, k_pages.dtype.itemsize)
+        k_pages.shape, nH, qb, mb, k_pages.dtype.itemsize, dv)
     if len(cands) > 1:
+        # the split forms' own keys, named only where they apply
+        split = ({} if dv == d and sinks is None
+                 else dict(dv=dv, sink=sinks is not None))
         impl = _tuned_impl(C, qb, nH, d, k_pages.shape[1], mb,
-                           k_pages.shape[3], q.dtype, cands, quant, window)
+                           k_pages.shape[3], q.dtype, cands, quant, window,
+                           **split)
         if impl != "xla":
             return ragged_paged_attention_kernel(
                 q, k_pages, v_pages, rows, pos0, n_valid, sm_scale,
                 k_scales, v_scales, pps=int(impl.split("_p")[1]),
-                window=window)
+                window=window, **({} if sinks is None
+                                  else dict(sinks=sinks)))
     return _ragged_paged_xla(q, k_pages, v_pages, rows, pos0, n_valid,
-                             sm_scale, k_layout, k_scales, v_scales, window)
+                             sm_scale, k_layout, k_scales, v_scales, window,
+                             sinks)
