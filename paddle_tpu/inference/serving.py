@@ -35,7 +35,9 @@ for the paged cache). TPU design:
   class the pages a query at ``b`` can still read, and (3) the state
   class, where the model has one, a snapshot taken at exactly ``b``;
   admission takes the longest ``b`` every class can honour
-  (``_usable_hit``) and counts what class 0 had beyond it.
+  (``_usable_hit``) and counts what class 0 had beyond it. So a
+  windowed class evicts its idle pages deep inside a prompt before
+  those near a prompt's end (``_WindowPool``); class 0 evicts LRU.
 - Continuous batching: the scheduler admits queued requests into free
   slots every step (admission is page-pool-bound only — no prompt
   buckets), and a pool-blocked large request is skipped (with an aging
@@ -365,13 +367,62 @@ class _PagePool:
         """Reclaim up to ``n`` LRU evictable pages into the free list."""
         done = 0
         while done < n and self.evictable:
-            p = next(iter(self.evictable))
+            p = self._next_out()
             del self.evictable[p]
             del self.cache[self.hash_of.pop(p)]
             del self.ref[p]
             self.free.append(p)
             done += 1
         return done
+
+    def _next_out(self) -> int:
+        return next(iter(self.evictable))
+
+
+class _WindowPool(_PagePool):
+    """A further cache class's pool: its idle pages leave in two tiers.
+    A hit claims in a windowed class only the blocks a query at the
+    hit's end can read (_usable_hit), so a page deep inside a prompt
+    serves no later hit, while one near a prompt's end is what the next
+    question over the same document needs. ``decref(..., interior=True)``
+    marks a page of the first kind (_ClassPages.keep_from); eviction
+    takes every interior page before any other (a *tail*), least
+    recently released first within each tier, and a page claimed again
+    is classed anew when it is next released. ``evictable`` stays every
+    idle page in release order; ``interior`` is its first tier;
+    ``evicted`` counts the pages each tier gave up."""
+
+    def __init__(self, n_pages: int, cache_limit: int = 0):
+        super().__init__(n_pages, cache_limit)
+        self.interior: dict[int, None] = {}
+        self._pending_interior: set[int] = set()
+        self.evicted = {"interior": 0, "tail": 0}
+
+    def lookup(self, hashes: list[bytes]) -> list[int]:
+        out = super().lookup(hashes)
+        for p in out:
+            self.interior.pop(p, None)
+            self._pending_interior.discard(p)
+        return out
+
+    def decref(self, pages: list[int], interior: bool = False) -> None:
+        if interior:
+            self._pending_interior.update(p for p in pages
+                                          if self.ref[p] == 1)
+        super().decref(pages)
+
+    def commit_evictable(self) -> None:
+        for p in self.pending_evict:
+            if p in self._pending_interior:
+                self.interior[p] = None
+        self._pending_interior.clear()
+        super().commit_evictable()
+
+    def _next_out(self) -> int:
+        self.evicted["interior" if self.interior else "tail"] += 1
+        p = next(iter(self.interior or self.evictable))
+        self.interior.pop(p, None)
+        return p
 
 
 class _ClassPages:
@@ -380,21 +431,27 @@ class _ClassPages:
     own ``k_pages``, ``pool``, ``_full_rows``, ``_slot_owned`` ...). A
     request holds pages here only for the blocks its layers can still
     read: ``owned`` / ``shared`` map a slot's logical blocks to pages,
-    ``tail[slot]`` is its first block not yet let go, and ``peak[slot]``
-    what it may hold at once (admission reserves that much)."""
+    ``tail[slot]`` is its first block not yet let go, ``peak[slot]``
+    what it may hold at once (admission reserves that much), and
+    ``keep_from[slot]`` its first *tail* block (_WindowPool): ``claims``
+    is the most blocks a hit claims here, so a hit that ends at a page
+    boundary within one window of the prompt's last full page claims
+    from ``L - 2 * claims`` on, ``L`` the prompt's full pages."""
 
     def __init__(self, cls, n_pages: int, B: int, max_blocks: int,
-                 cache_limit: int):
+                 cache_limit: int, bs: int):
         self.cls, self.n_pages = cls, n_pages
         self.k_pages, self.v_pages = (
             jnp.zeros((cls.n_layers, n_pages) + plane.page_shape,
                       cls.spec.dtype) for plane in cls.spec.planes)
-        self.pool = _PagePool(n_pages, cache_limit=cache_limit)
+        self.pool = _WindowPool(n_pages, cache_limit=cache_limit)
+        self.claims = max_blocks - cls.live_from(max_blocks * bs) // bs
         self.full_rows = np.zeros((B, max_blocks), np.int32)
         self.owned: list[dict] = [{} for _ in range(B)]
         self.shared: list[dict] = [{} for _ in range(B)]
         self.tail = [0] * B
         self.peak = [0] * B
+        self.keep_from = [0] * B
         self.deferred_free: list[int] = []
 
     def room(self) -> int:
@@ -431,12 +488,13 @@ class _ClassPages:
             return True
         page = self.shared[slot].pop(blk, None)
         if page is not None:
-            self.pool.decref([page])
+            self.pool.decref([page], interior=blk < self.keep_from[slot])
         return page is not None
 
     def release_slot(self, slot: int, defer: bool) -> None:
         owned = list(self.owned[slot].values())
-        self.pool.decref(list(self.shared[slot].values()))
+        for blk, page in self.shared[slot].items():
+            self.pool.decref([page], interior=blk < self.keep_from[slot])
         self.owned[slot], self.shared[slot] = {}, {}
         self.full_rows[slot] = 0
         self.tail[slot] = self.peak[slot] = 0
@@ -445,12 +503,17 @@ class _ClassPages:
             self.settle()
 
     def accounting(self) -> dict:
+        """Where the pool's pages are; the idle cached ones by where
+        they wait: for a harvest, or evictable in either tier."""
+        pool = self.pool
         counts = {
-            "free": len(self.pool.free),
+            "free": len(pool.free),
             "slot_owned": sum(map(len, self.owned)),
             "slot_shared": len({p for d in self.shared
                                 for p in d.values()}),
-            "cache_idle": sum(1 for r in self.pool.ref.values() if r == 0),
+            "cache_pending": len(pool.pending_evict),
+            "cache_interior": len(pool.interior),
+            "cache_tail": len(pool.evictable) - len(pool.interior),
             "deferred_free": len(self.deferred_free)}
         counts["total"] = sum(counts.values())
         return counts
@@ -756,7 +819,7 @@ class ServingEngine:
         self._extra = [
             _ClassPages(c, int((class_pages or {}).get(c.name,
                                                        self.n_pages)),
-                        self.B, self.max_blocks, prefix_cache_pages)
+                        self.B, self.max_blocks, prefix_cache_pages, self.bs)
             for c in self.classes[1:] if isinstance(c, CacheClass)]
         self._state = None
         for i, c in enumerate(self.classes):
@@ -892,12 +955,16 @@ class ServingEngine:
             # requests hold in each class and the context tokens they
             # stand for (bytes a context token: the benchmark's
             # kv_live_bytes_per_context_token), pages the windows let
-            # go, and tokens of prefix hits that class 0 had and a
-            # windowed class had lost
+            # go, tokens of prefix hits that class 0 had and a windowed
+            # class had lost, and the idle pages the classes evicted for
+            # room, by tier (_WindowPool: a tail is a page a hit could
+            # still have claimed)
             self.stats.update(dict.fromkeys(
                 [f"pages_live.{c.name}" for c in self.classes]
                 + ["context_tokens_live", "pages_released_by_window",
-                   "prefill_window_lost_tokens"], 0))
+                   "prefill_window_lost_tokens",
+                   "window_pages_evicted_interior",
+                   "window_pages_evicted_tail"], 0))
         if self._state is not None:
             # what the state class does: slots (and their bytes) live
             # requests hold, summed over ticks as the pages are;
@@ -1513,6 +1580,7 @@ class ServingEngine:
                 x.full_rows[slot, first:n_shared] = got
                 x.tail[slot] = first
                 x.peak[slot] = self._class_peak(x, n_blk)
+                x.keep_from[slot] = T // self.bs - 2 * x.claims
             self.table[slot] = 0           # decode view: sink until flip
             self.seq_lens[slot] = 0
             self.cur_tok[slot] = 0
@@ -1717,6 +1785,11 @@ class ServingEngine:
             # and what the windows let go of behind the tick
             sp.set(**tick, pages_released_by_window=(
                 self._release_behind_windows()))
+            # and what the classes evicted since the last step
+            for x in self._extra:
+                for tier, n in x.pool.evicted.items():
+                    self.stats[f"window_pages_evicted_{tier}"] += n
+                x.pool.evicted = dict.fromkeys(x.pool.evicted, 0)
         if self._inflight is None and (
                 self._deferred_free or self.pool.pending_evict or any(
                     x.deferred_free or x.pool.pending_evict

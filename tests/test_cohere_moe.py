@@ -294,6 +294,7 @@ def test_engine_serves_through_both_classes_with_hits_and_resume():
     assert eng.kv_bytes_per_token() == 1 * 256
     assert eng.kv_bytes_per_token(1) == 3 * 256
     assert eng.stats["pages_released_by_window"] > 0
+    _assert_ledger(_window_state(eng))
 
 
 # -- the allocator ------------------------------------------------------------
@@ -301,6 +302,21 @@ def test_engine_serves_through_both_classes_with_hits_and_resume():
 def _window_state(eng):
     x, = eng._extra
     return x
+
+
+def _assert_ledger(x):
+    """Every page of a windowed class but the sink is in exactly one
+    place: free, a slot's own, a slot's shared mapping, waiting for a
+    harvest (let go, or idle and pending), or idle in one of the two
+    tiers (interior, tail)."""
+    pool = x.pool
+    assert set(pool.interior) <= set(pool.evictable)
+    parts = [set(pool.free), {p for d in x.owned for p in d.values()},
+             {p for d in x.shared for p in d.values()}, set(x.deferred_free),
+             set(pool.pending_evict), set(pool.interior),
+             set(pool.evictable) - set(pool.interior)]
+    acc = x.accounting()
+    assert acc["total"] == len(set().union(*parts)) == x.n_pages - 1, acc
 
 
 def test_pages_go_only_behind_the_window_and_never_under_a_tick_in_flight():
@@ -317,6 +333,7 @@ def test_pages_go_only_behind_the_window_and_never_under_a_tick_in_flight():
     waiting_seen = 0
     while True:
         busy = watch.step()
+        _assert_ledger(x)
         waiting = set(x.deferred_free) | set(x.pool.pending_evict)
         waiting_seen += len(waiting)
         assert not waiting & set(x.pool.free)
@@ -365,10 +382,11 @@ def test_a_hit_needs_the_windows_tail():
     assert eng.stats["prefill_cached_tokens"] == 52
     assert eng.stats["prefill_window_lost_tokens"] == 0
 
-    # the window class loses the document's last page
+    # the window class loses the document's last page (a tail, which
+    # the pool keeps past every interior page: put it first in line)
     page = x.pool.cache[hashes[12]]
-    x.pool.evictable = {page: None, **{p: None for p in x.pool.evictable
-                                       if p != page}}
+    assert page in x.pool.evictable and page not in x.pool.interior
+    x.pool.interior = {page: None, **x.pool.interior}
     assert x.pool.evict(1) == 1 and hashes[12] not in x.pool.cache
     shorter = ask(2, 9)
     watch.serve(shorter)
@@ -403,6 +421,7 @@ def test_admission_needs_room_in_every_class():
     eng.submit(b)
     together = 0
     while watch.step():
+        _assert_ledger(x)
         together += sum(s is not None for s in eng.slots) == 2
         assert x.live_pages() <= 21
     assert not together
@@ -426,8 +445,10 @@ def test_abort_and_preemption_free_every_class():
     eng.submit(b)
     for _ in range(6):
         watch.step()
+        _assert_ledger(x)
     assert x.live_pages() and a.out_tokens and b.out_tokens
     assert eng.abort(0)
+    _assert_ledger(x)
     admit = eng._admit
 
     def admit_then_preempt(now):
@@ -439,10 +460,11 @@ def test_abort_and_preemption_free_every_class():
         assert x.live_pages() == 0 and not x.full_rows.any()
         assert x.peak == [0, 0, 0] and x.tail == [0, 0, 0]
         assert x.deferred_free and not set(x.deferred_free) & set(x.pool.free)
+        _assert_ledger(x)
 
     eng._admit = admit_then_preempt
     while watch.step():
-        pass
+        _assert_ledger(x)
     assert len(b.out_tokens) == 30 and b.n_preempted == 1
     assert watch.worst(model, key, b) < TOL
     for ledger in eng.page_accounting()["classes"].values():
